@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 from typing import Dict, List, Optional, Sequence
 
 from plenum_tpu.common.constants import (
@@ -199,6 +200,26 @@ def client_ha_from_pool_genesis(base_dir: str, name: str):
 
 # ----------------------------------------------------------------- start
 
+def settle_device_ownership(config) -> None:
+    """One process per chip, decided from the node's OWN configuration
+    before JAX is imported: a node whose ``VERIFIER_PROVIDER`` is
+    "remote" runs beside a verify daemon that owns the accelerator, so
+    this process is pinned to the CPU backend — its merkle/state
+    engines must not take the chip the daemon needs (a node started by
+    hand on the chip host used to do exactly that, and the daemon then
+    landed on the CPU). Any other provider leaves JAX's own platform
+    choice alone: that node owns its chip. Also turns on the persistent
+    compile cache (the one setter) — the node compiles SHA-256/SHA3/trie
+    kernels at boot."""
+    if getattr(config, "VERIFIER_PROVIDER", None) == "remote":
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        jax = sys.modules.get("jax")
+        if jax is not None:     # imported (not initialised) already
+            jax.config.update("jax_platforms", "cpu")
+    from plenum_tpu.ops import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
+
+
 def build_networked_node(name: str, base_dir: str, config=None):
     """Construct a NetworkedNode from on-disk keys + genesis, with
     durable file-backed stores under <base>/<name>/data/. Config is
@@ -207,6 +228,7 @@ def build_networked_node(name: str, base_dir: str, config=None):
     if config is None:
         from plenum_tpu.common.config import Config
         config = Config.load(base_dir)
+    settle_device_ownership(config)
     from plenum_tpu.server.networked_node import NetworkedNode
     from plenum_tpu.storage import kv_native
     from plenum_tpu.storage.kv_file import KeyValueStorageFile

@@ -238,9 +238,9 @@ class CoalescingVerifierHub:
     host process) into ONE device launch.
 
     The verify kernel is latency-bound — the 256-bit scalar-mult ladder
-    is a long sequential dependency chain, so a 512-item launch costs
-    ~1/3 of an 8192-item launch (118 ms vs 344 ms on one chip) — which
-    makes k small concurrent launches cost ~k× one fused launch. The
+    is a long sequential dependency chain, so a small launch costs
+    nearly as much as a full one — which makes k small concurrent
+    launches cost ~k× one fused launch. The
     hub queues dispatch() calls and launches the union the first time
     any participant harvests; per-dispatch slices keep results isolated.
 

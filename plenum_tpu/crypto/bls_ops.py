@@ -107,6 +107,15 @@ else:
 BLS_TOWER_ENV = "PLENUM_TPU_BLS_TOWER"
 
 
+def _step_down(what: str, exc: Exception) -> None:
+    import logging
+    from plenum_tpu.ops import mesh
+    mesh.disable_pallas_backend(BLS_TOWER_ENV)
+    logging.getLogger(__name__).warning(
+        "device BLS %s failed at run time (%s); stepped down to the "
+        "host path permanently", what, exc)
+
+
 def pairing_device_ready(n_jobs: int) -> bool:
     """True when a batch of ``n_jobs`` pairing-product checks should
     take the device kernel: batch clears Config.BLS_PAIRING_DEVICE_MIN,
@@ -161,21 +170,20 @@ def multi_pairing_is_one_jobs(jobs) -> list:
     if not jobs:
         return []
     if pairing_device_ready(len(jobs)):
+        from plenum_tpu.ops import bls381_pairing as _bp
+        # trace/lowering/compile failures raise from the dispatch:
+        # program bugs, not something to serve around
+        handles = _bp.pairing_dispatch(jobs)
         try:
-            from plenum_tpu.ops import bls381_pairing as _bp
-            verdict, _ok = _bp.pairing_jobs(jobs)
+            verdict, _ok = _bp.pairing_collect(handles)
             return [bool(v) for v in verdict]
         except Exception as e:  # pragma: no cover  # plenum-lint: disable=PT006
-            # any device-side failure (OOM, compile, runtime) must step
-            # the family down and serve host verdicts, never crash a
-            # verify path — same contract as the sha256/ed25519 Pallas
-            # fallbacks
-            import logging
-            from plenum_tpu.ops import mesh
-            mesh.disable_pallas_backend(BLS_TOWER_ENV)
-            logging.getLogger(__name__).warning(
-                "device BLS pairing failed (%s); stepped down to the "
-                "host path permanently", e)
+            # serving-path robustness: a launched kernel that dies on
+            # the device (OOM, runtime) steps the family down and this
+            # batch is served by the host — COUNTED
+            # (mesh.step_down_counts), never crash a verify path; same
+            # contract as the sha256/ed25519 Pallas step-downs
+            _step_down("pairing", e)
     return [pairing_job_host(j) for j in jobs]
 
 
@@ -202,23 +210,18 @@ def g1_msm(points: Sequence[bytes], scalars: Sequence[int]):
         except ImportError:  # pragma: no cover - jax-less deployment
             use_device = False
     if use_device:
+        from plenum_tpu.ops import bls381_pairing as _bp
+        handles = _bp.msm_dispatch(points, scalars)   # compile raises
         try:
-            from plenum_tpu.ops import bls381_pairing as _bp
-            point, ok = _bp.msm_g1(points, scalars)
+            point, ok = _bp.msm_result(handles)
+        except Exception as e:  # pragma: no cover  # plenum-lint: disable=PT006
+            # counted step-down, not crash: the host double-and-add
+            # below serves every MSM the device path would have
+            _step_down("MSM", e)
+        else:
             if not ok:
                 raise ValueError("undecodable point in MSM input")
             return point
-        except ValueError:
-            raise
-        except Exception as e:  # pragma: no cover  # plenum-lint: disable=PT006
-            # step-down, not crash: the host double-and-add below
-            # serves every MSM the device path would have
-            import logging
-            from plenum_tpu.ops import mesh
-            mesh.disable_pallas_backend(BLS_TOWER_ENV)
-            logging.getLogger(__name__).warning(
-                "device BLS MSM failed (%s); stepped down to the host "
-                "path permanently", e)
     agg = None
     for raw, s in zip(points, scalars):
         p = g1_decompress(bytes(raw))

@@ -3,7 +3,8 @@ verify daemon (server/verify_daemon.py) over a local socket.
 
 Same dispatch()/collect() interface as the in-process providers
 (crypto/batch_verifier.py), plus ready(): the node's prod loop polls it
-so the daemon round trip (device launch + tunnel RTT) overlaps consensus
+so the daemon round trip (socket + coalescing window + device launch)
+overlaps consensus
 work instead of blocking a tick. The socket is plain blocking TCP used
 non-blockingly for reads; frames are length-prefixed msgpack (see the
 daemon's protocol doc).

@@ -304,11 +304,10 @@ class CompactMerkleTree:
             self._device_pipeline_depth = pipeline_depth
         if warm and self._size and not isinstance(self.hash_store,
                                                   NullHashStore):
-            try:
-                self._device_sync()
-            except Exception:
-                logger.warning("device engine warm-up failed; it will "
-                               "retry lazily", exc_info=True)
+            # under the same breaker as serving: a broken backend must
+            # not fail bootstrap (the first batch retries lazily), but
+            # the failure is COUNTED like any other host-served call
+            self._device_breaker.run(self._device_sync, "warm-up")
         return engine
 
     def _device_sync(self) -> bool:

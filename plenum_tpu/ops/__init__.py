@@ -48,17 +48,33 @@ def scatter_ragged_rows(msgs, width: int):
     return out, lens
 
 
-def enable_persistent_compilation_cache(path: str = None) -> str:
-    """Point XLA's persistent compilation cache at `path` (default:
-    <repo>/.jax_cache). The big verify buckets take 30-110s to compile;
-    with the cache, every process after the first loads them in
-    milliseconds. Must use jax.config (the JAX_COMPILATION_CACHE_DIR
-    env var alone does not activate the cache on all backends)."""
+def enable_persistent_compilation_cache() -> str:
+    """Turn on XLA's persistent compilation cache — THE one setter:
+    every entry point that can compile (verify daemon, node start,
+    bench.py, chip_smoke.py, the test suite, __graft_entry__) calls
+    this and nothing else places the cache. The directory comes from
+    outside: ``JAX_COMPILATION_CACHE_DIR`` when set, else the fixed
+    ``<checkout>/.jax_cache`` — never a temporary, pid- or time-derived
+    path (the path is part of the cache key, so a directory that moves
+    never hits). The ed25519 buckets take minutes to compile (the
+    4,096-signature Pallas block ~3.5 min); with the cache every process
+    after the first loads them in seconds. Goes through jax.config
+    because the env var alone does not activate the cache on every
+    backend. → the directory in use.
+
+    Also keeps the CALLER's stack out of op locations: a Pallas
+    kernel's Mosaic payload embeds its ops' source locations inside an
+    opaque string the cache-key canonicalisation cannot strip, and with
+    full tracebacks those locations carry the frames of whoever called
+    the kernel — so the verify daemon, a node and chip_smoke.py each
+    got a different key for the SAME ed25519 kernel and each paid its
+    ~4 min compile (seen on the chip, PR 22). With the innermost frame
+    only, the key depends on the kernel's own source."""
     import jax
-    if path is None:
-        path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), ".jax_cache")
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
     return path

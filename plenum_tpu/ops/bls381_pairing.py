@@ -370,9 +370,6 @@ def _g2_aggregate_kernel(c1_std, c0_std, sign_big, is_inf, valid_in):
 
 # ----------------------------------------------- dispatch / collect
 
-_VALIDATED = set()            # bucket shapes whose execution completed
-
-
 def _pack_pair_arrays(jobs, Bp: int, Pp: int):
     g1raw = np.zeros((Bp, Pp, 48), dtype=np.uint8)
     g1raw[:, :, 0] = 0xC0
@@ -421,14 +418,6 @@ def pairing_dispatch(jobs: Sequence[Sequence[Tuple[bytes, bytes]]]):
             outs = _pairing_kernel(*(jnp.asarray(a) for a in arrays))
     if Bp != B:
         outs = tuple(o[:B] for o in outs)
-    # validate-once per bucket shape: JAX dispatch is async, so a
-    # runtime failure at an untested shape would otherwise surface at
-    # the caller's np.asarray outside any except and the step-down
-    # would never engage (sha256_blocks_routed precedent)
-    shape = ("pair", Bp, Pp)
-    if shape not in _VALIDATED:
-        outs[0].block_until_ready()  # plenum-lint: disable=PT002
-        _VALIDATED.add(shape)
     return outs
 
 
@@ -481,10 +470,6 @@ def msm_dispatch(points: Sequence[bytes], scalars: Sequence[int]):
         outs = _msm_kernel(jnp.asarray(limbs), jnp.asarray(sign_big),
                            jnp.asarray(is_inf), jnp.asarray(valid),
                            jnp.asarray(digits))
-    shape = ("msm", Np)
-    if shape not in _VALIDATED:
-        outs[3].block_until_ready()  # plenum-lint: disable=PT002
-        _VALIDATED.add(shape)
     return outs
 
 
@@ -504,13 +489,19 @@ def msm_collect(handles) -> Optional[Tuple[int, int]]:
     return _proj_to_affine(xi, yi, zi)
 
 
+def msm_result(handles):
+    """Await an `msm_dispatch` handle → (affine point | None,
+    decode_ok) — the blocking half crypto/bls_ops guards (a run-time
+    device failure surfaces here, the compile in msm_dispatch)."""
+    ok = bool(np.asarray(handles[3]))
+    return msm_collect(handles), ok
+
+
 def msm_g1(points: Sequence[bytes], scalars: Sequence[int]):
     """Synchronous MSM: (affine point | None, decode_ok)."""
     if len(points) == 0:
         return None, True
-    outs = msm_dispatch(points, scalars)
-    ok = bool(np.asarray(outs[3]))
-    return msm_collect(outs), ok
+    return msm_result(msm_dispatch(points, scalars))
 
 
 def g2_aggregate_dispatch(jobs: Sequence[Sequence[bytes]], n: int):
@@ -536,10 +527,6 @@ def g2_aggregate_dispatch(jobs: Sequence[Sequence[bytes]], n: int):
     outs = _g2_aggregate_kernel(*(jnp.asarray(a) for a in arrays))
     if Bp != B:
         outs = tuple(o[:B] for o in outs)
-    shape = ("g2agg", Bp, n)
-    if shape not in _VALIDATED:
-        outs[3].block_until_ready()  # plenum-lint: disable=PT002
-        _VALIDATED.add(shape)
     return outs
 
 

@@ -712,9 +712,8 @@ def verify_batch_async(msgs: Sequence[bytes], sigs: Sequence[bytes],
 
 # Backend selection: the Pallas whole-verify kernel (its VMEM-resident
 # limb registers avoid the per-fmul HBM round trips) for any batch
-# filling a block on a TPU — ~2x the XLA expression at every block
-# count, see _dispatch_kernel; the XLA kernel otherwise (smaller
-# batches, CPU tests, or any Pallas failure → permanent fallback).
+# filling a block on a TPU; the XLA kernel otherwise (smaller batches,
+# CPU tests, or after a counted run-time step-down).
 _ED25519_PALLAS_ENV = "PLENUM_TPU_ED25519_BACKEND"
 
 
@@ -728,48 +727,18 @@ def _pallas_available() -> bool:
     return mesh_mod.pallas_backend_enabled(_ED25519_PALLAS_ENV)
 
 
-_PALLAS_VALIDATED = set()      # grid sizes whose execution has completed
-
-
 def _dispatch_kernel(ay, asign, ry, rsign, s_words, k_words):
     from plenum_tpu.ops import ed25519_pallas as edp
-    # at R=32 blocks the pallas kernel wins from ONE block up (4096:
-    # 99ms vs 190ms XLA; 16384: 236ms vs 518ms); below a block the XLA
-    # kernel serves (small batches don't fill the tile grid)
-    while _pallas_available() and ay.shape[0] >= edp.BLOCK:
+    from plenum_tpu.ops import mesh as mesh_mod
+    # the pallas kernel serves from ONE block (4,096 signatures) up;
+    # below a block the XLA kernel serves (small batches don't fill
+    # the tile grid)
+    if _pallas_available() and ay.shape[0] >= edp.BLOCK:
+        # a kernel the compiler refuses raises here (program bug); a
+        # launch that dies on the device is a counted step-down
+        ok = edp.verify_kernel(ay, asign, ry, rsign, s_words, k_words)
         n_blocks = -(-ay.shape[0] // edp.BLOCK)
-        try:
-            ok = edp.verify_kernel(ay, asign, ry, rsign,
-                                   s_words, k_words)
-            if n_blocks not in _PALLAS_VALIDATED:
-                # JAX dispatch is async: runtime failures (VMEM/OOM at
-                # an untested grid size) would otherwise surface at the
-                # caller's np.asarray, outside this except, and the
-                # fallback would never engage. Block ONCE per grid size
-                # to prove execution; later calls stay fully async.
-                # deliberate ONE-TIME sync per grid size to prove
-                # execution; later calls with this grid stay fully async
-                ok.block_until_ready()  # plenum-lint: disable=PT002
-                _PALLAS_VALIDATED.add(n_blocks)
+        if mesh_mod.launch_survives(_ED25519_PALLAS_ENV, n_blocks, ok,
+                                    "pallas ed25519 verify"):
             return ok
-        except Exception:  # pragma: no cover  # plenum-lint: disable=PT006
-            # the fallback engine itself: ANY Pallas failure (VMEM,
-            # lowering, runtime) must step down to the XLA kernel,
-            # never crash a verify
-            logger = __import__("logging").getLogger(__name__)
-            if edp.BLOCK_R > 16:
-                # R=32 needs ~26MB VMEM: a smaller-VMEM TPU generation
-                # should step down to the R=16 kernel (fits the 16MB
-                # default) before giving up on Pallas entirely
-                edp.BLOCK_R //= 2
-                edp.BLOCK = edp.BLOCK_R * edp.BLOCK_L
-                edp._build_verify.cache_clear()
-                _PALLAS_VALIDATED.clear()
-                logger.exception(
-                    "pallas verify failed; retrying with BLOCK_R=%d",
-                    edp.BLOCK_R)
-                continue
-            logger.exception("pallas verify failed; falling back to XLA")
-            from plenum_tpu.ops import mesh as mesh_mod
-            mesh_mod.disable_pallas_backend(_ED25519_PALLAS_ENV)
     return _verify_kernel(ay, asign, ry, rsign, s_words, k_words)

@@ -28,10 +28,12 @@ This module is the ONE production seam for that axis:
  - `MeshPipeline` double-buffers dispatch/collect across batches (the
    shape ``ops/merkle.ProofPipeline`` uses), keeping every chip's next
    batch enqueued while the host drains the previous download.
- - `probe_platform` / `is_accelerator` are the ONE lazy,
-   exception-guarded "am I on a real accelerator?" probe — modules must
-   route capability questions here instead of touching
-   ``jax.devices()[0]`` directly (which force-initializes the backend).
+ - `probe_platform` / `is_accelerator` / `device_facts` are the ONE
+   lazy "which device did this process get?" probe — modules must route
+   capability questions here instead of touching ``jax.devices()[0]``
+   directly (which force-initializes the backend). A backend that
+   cannot be initialised RAISES: a lost or busy chip is an error, never
+   a silent CPU run.
 
 Import of this module NEVER initializes JAX: server code (node
 bootstrap, validator-info dumps) reads configuration and stats without
@@ -43,6 +45,7 @@ batched aggregate path, and ``ops/merkle`` builds + proof gathers.
 """
 from __future__ import annotations
 
+import logging
 import os
 import threading
 from typing import Callable, List, Optional, Sequence
@@ -52,30 +55,46 @@ import numpy as np
 from plenum_tpu.observability.tracing import CAT_DEVICE, NullTracer
 from plenum_tpu.observability import telemetry as _telemetry
 
+logger = logging.getLogger(__name__)
+
 # --------------------------------------------------------- capability probe
 
 _PROBE_LOCK = threading.Lock()
-_PROBE = {"platform": None, "device_count": None}
+_PROBE = {"platform": None, "device_count": None, "device_kind": None}
 
 
-def probe_platform(default: str = "cpu") -> str:
-    """Platform of device 0 ("cpu" / "tpu" / "gpu"), probed lazily and
-    exception-guarded: a missing or broken backend reads as `default`
-    instead of raising at import/dispatch time. First call initializes
-    the JAX backend; every later call is a dict read."""
+def _note_devices_locked(devs) -> None:
+    """Record device facts from an enumeration (caller holds
+    _PROBE_LOCK); first writer wins."""
+    if _PROBE["platform"] is None and devs:
+        _PROBE["platform"] = devs[0].platform
+        _PROBE["device_kind"] = devs[0].device_kind
+        _PROBE["device_count"] = len(devs)
+
+
+def probe_platform() -> str:
+    """Platform of device 0 ("cpu" / "tpu" / "gpu"), probed lazily.
+    First call initializes the JAX backend; every later call is a dict
+    read. A backend that cannot be initialised RAISES (the chip is held
+    by another process, the runtime is broken): the caller asked for a
+    device, and reading that as "cpu" would let a run that lost its
+    chip print numbers under device names. An explicit
+    ``JAX_PLATFORMS=cpu`` still reads "cpu" — JAX succeeds there."""
     with _PROBE_LOCK:
         if _PROBE["platform"] is None:
-            try:
-                import jax
-                devs = jax.devices()
-                _PROBE["platform"] = devs[0].platform
-                _PROBE["device_count"] = len(devs)
-            except Exception:  # plenum-lint: disable=PT006 — this IS
-                # the package's designed guard: ANY broken/missing
-                # backend must read as `default`, never raise
-                _PROBE["platform"] = default
-                _PROBE["device_count"] = 1
+            import jax
+            _note_devices_locked(jax.devices())
         return _PROBE["platform"]
+
+
+def device_facts() -> dict:
+    """``{"platform", "kind", "count"}`` of this process's devices as
+    JAX reports them — what the verify daemon states in its ready file
+    and chip_smoke.py prints. Initializes the backend (and raises if it
+    cannot), like probe_platform."""
+    probe_platform()
+    return {"platform": _PROBE["platform"], "kind": _PROBE["device_kind"],
+            "count": _PROBE["device_count"]}
 
 
 def is_accelerator() -> bool:
@@ -99,6 +118,7 @@ def _reset_probe() -> None:
     on a real TPU."""
     with _PROBE_LOCK:
         _PROBE["platform"] = None
+        _PROBE["device_kind"] = None
         _PROBE["device_count"] = None
         _PALLAS_BACKENDS.clear()
 
@@ -108,6 +128,10 @@ def _reset_probe() -> None:
 # env-var name -> bool; ONE probe-backed decision per kernel family
 # (ed25519, sha256). Guarded by _PROBE_LOCK like the probe itself.
 _PALLAS_BACKENDS = {}
+# env-var name -> lifetime count of run-time step-downs (never cleared,
+# not even by _reset_probe): what chip_smoke.py and the benchmark read
+# to refuse a run in which a kernel family silently left the device
+_STEP_DOWNS = {}
 
 
 def pallas_backend_enabled(env_var: str) -> bool:
@@ -145,11 +169,67 @@ def xla_backend_enabled(env_var: str) -> bool:
 
 def disable_pallas_backend(env_var: str) -> None:
     """Permanent step-down for one kernel family — the fallback engine
-    (ops/ed25519_jax._dispatch_kernel, ops/sha256 routing) calls this
-    after an unrecoverable Pallas failure so every later dispatch goes
-    straight to the XLA expression."""
+    (launch_survives for the Pallas seams, crypto/bls_ops for the BLS
+    tower) calls this after a RUN-TIME failure of a launched kernel so
+    every later dispatch goes straight to the fallback path. Counted
+    (step_down_counts): a serving process keeps answering, but no run
+    may report device results without saying this happened."""
     with _PROBE_LOCK:
         _PALLAS_BACKENDS[env_var] = False
+        _STEP_DOWNS[env_var] = _STEP_DOWNS.get(env_var, 0) + 1
+
+
+# (env-var name, shape key) of launches whose execution has completed
+_PROVEN = set()
+
+
+def launch_survives(env_var: str, key, outputs, what: str) -> bool:
+    """Prove ONCE per (kernel family, shape key) that a launched kernel
+    really executes — THE run-time half of the failure policy every
+    Pallas seam shares (ed25519 verify, SHA-256 routing, merkle
+    builds). JAX dispatch is async: a run-time failure at an untested
+    shape would otherwise surface at the caller's np.asarray, where
+    nothing can serve around it. So the first launch per key blocks
+    until ready; later launches with that key stay fully async.
+
+    → True when the launch is (or was already) proven. → False after a
+    run-time failure: logged, and the family is stepped down for the
+    life of the process — COUNTED (step_down_counts) — so the caller
+    serves this call from its XLA expression. Trace, lowering and
+    compile failures never reach here: they raise from the launch
+    itself, because a kernel the installed compiler refuses is a
+    program bug, not something to serve around."""
+    with _PROBE_LOCK:
+        if (env_var, key) in _PROVEN:
+            return True
+    try:
+        import jax
+        # deliberate ONE-TIME sync per shape key
+        jax.block_until_ready(outputs)  # plenum-lint: disable=PT002
+    except Exception:  # pragma: no cover  # plenum-lint: disable=PT006
+        # serving-path robustness: the process keeps answering from
+        # the fallback path, and the count says so
+        logger.exception("%s failed at run time; stepping down to XLA",
+                         what)
+        disable_pallas_backend(env_var)
+        return False
+    with _PROBE_LOCK:
+        _PROVEN.add((env_var, key))
+    return True
+
+
+def step_down_counts() -> dict:
+    """env-var name -> how many times that kernel family stepped down
+    in this process ({} = every family still on its device path)."""
+    with _PROBE_LOCK:
+        return dict(_STEP_DOWNS)
+
+
+def kernel_backends() -> dict:
+    """env-var name -> current availability decision of every kernel
+    family that has been consulted (True = device/Pallas path)."""
+    with _PROBE_LOCK:
+        return dict(_PALLAS_BACKENDS)
 
 
 def default_device():
@@ -160,9 +240,7 @@ def default_device():
     import jax
     devs = jax.devices()
     with _PROBE_LOCK:
-        if _PROBE["platform"] is None and devs:
-            _PROBE["platform"] = devs[0].platform
-            _PROBE["device_count"] = len(devs)
+        _note_devices_locked(devs)
     return devs[0]
 
 
@@ -235,16 +313,12 @@ class DeviceMesh:
     def _init_devices_locked(self) -> None:
         if self._devices is not None:
             return
-        try:
-            import jax
-            devs = list(jax.devices())
-            with _PROBE_LOCK:
-                if _PROBE["platform"] is None and devs:
-                    _PROBE["platform"] = devs[0].platform
-                    _PROBE["device_count"] = len(devs)
-        except Exception:  # plenum-lint: disable=PT006 — same designed
-            # guard as probe_platform: no backend reads as one device
-            devs = []
+        # a backend that cannot be initialised raises, exactly like
+        # probe_platform: "no backend" must never read as "one device"
+        import jax
+        devs = list(jax.devices())
+        with _PROBE_LOCK:
+            _note_devices_locked(devs)
         cap = self.max_devices if self.max_devices else len(devs)
         n = max(1, min(len(devs), cap))
         # power-of-two device counts keep per-device buckets divisible
@@ -384,6 +458,7 @@ class DeviceMesh:
             out["n_devices"] = len(self._devices)
         if probed():
             out["platform"] = _PROBE["platform"]
+            out["device_kind"] = _PROBE["device_kind"]
             out["host_device_count"] = _PROBE["device_count"]
         return out
 
@@ -479,7 +554,10 @@ def configure_from(config) -> DeviceMesh:
 
 def mesh_stats() -> dict:
     """Stats for status dumps; safe to call from paths that must never
-    initialize a device runtime."""
+    initialize a device runtime. Always carries the process's kernel
+    step-down counts — the one thing a status reader must not miss."""
     with _MESH_LOCK:
         m = _MESH
-    return m.stats() if m is not None else {"enabled": None}
+    out = m.stats() if m is not None else {"enabled": None}
+    out["step_downs"] = step_down_counts()
+    return out

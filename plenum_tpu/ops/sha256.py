@@ -30,7 +30,6 @@ whichever backend the caller selected.
 from __future__ import annotations
 
 import functools
-import logging
 from typing import List, Sequence, Tuple
 
 import numpy as np
@@ -41,8 +40,6 @@ from jax import lax
 
 from plenum_tpu.observability import telemetry as _tmy
 from plenum_tpu.ops import pow2_at_least, scatter_ragged_rows
-
-logger = logging.getLogger(__name__)
 
 _IV = np.array([
     0x6a09e667, 0xbb67ae85, 0x3c6ef372, 0xa54ff53a,
@@ -207,50 +204,31 @@ def compress_blocks(blocks, nvalid, nblocks: int, backend: str = "plain"):
     return _sha256_blocks(blocks, nvalid, nblocks)
 
 
-_ROUTED_VALIDATED = set()     # (backend, nblocks) whose execution completed
-
-
 def sha256_blocks_routed(blocks, nvalid, nblocks: int):
-    """Standalone dispatch half with backend routing + the Pallas
-    fallback chain: pick the backend for this batch size, launch, and
-    prove execution ONCE per (backend, nblocks) — JAX dispatch is
-    async, so a runtime failure at an untested shape would otherwise
-    surface at the caller's np.asarray, outside any except, and the
-    fallback would never engage (ed25519_jax._dispatch_kernel
-    precedent). Any Pallas failure steps down to the XLA expression
-    permanently (shared probe registry)."""
-    backend = select_backend(int(blocks.shape[0]))
-    while True:
-        tile = _config_tile()
-        b = int(blocks.shape[0])
-        pad = (-b) % tile if backend == "tiled" else 0
-        try:
-            if pad:
-                bl = jnp.pad(blocks, ((0, pad), (0, 0), (0, 0)))
-                nv = jnp.pad(nvalid, (0, pad), constant_values=1)
-                out = compress_blocks(bl, nv, nblocks, backend)
-            else:
-                out = compress_blocks(blocks, nvalid, nblocks, backend)
-            if backend.startswith("pallas") \
-                    and (backend, nblocks) not in _ROUTED_VALIDATED:
-                # deliberate ONE-TIME sync per shape family to prove
-                # execution; later calls stay fully async
-                out.block_until_ready()  # plenum-lint: disable=PT002
-                _ROUTED_VALIDATED.add((backend, nblocks))
-            return out[:b] if pad else out
-        except Exception:  # pragma: no cover  # plenum-lint: disable=PT006
-            # the fallback engine itself: ANY Pallas failure (VMEM,
-            # lowering, runtime) must step down to the XLA expression,
-            # never crash a hash path
-            if not backend.startswith("pallas"):
-                raise
-            logger.exception("pallas sha256 failed; falling back to XLA")
-            from plenum_tpu.ops import mesh as mesh_mod
-            from plenum_tpu.ops import sha256_pallas as sp
-            mesh_mod.disable_pallas_backend(sp.PALLAS_ENV)
-            backend = select_backend(b)
-            if backend.startswith("pallas"):
-                backend = "plain"
+    """Standalone dispatch half with backend routing: pick the backend
+    for this batch size and launch. A Pallas kernel the compiler
+    refuses raises (program bug); its execution is proven once per
+    nblocks, and a launch that dies on the device is a counted
+    step-down served by the XLA expression
+    (ops/mesh.launch_survives — the policy every Pallas seam shares)."""
+    b = int(blocks.shape[0])
+    backend = select_backend(b)
+    pad = (-b) % _config_tile() if backend == "tiled" else 0
+    if pad:
+        out = compress_blocks(
+            jnp.pad(blocks, ((0, pad), (0, 0), (0, 0))),
+            jnp.pad(nvalid, (0, pad), constant_values=1),
+            nblocks, backend)[:b]
+    else:
+        out = compress_blocks(blocks, nvalid, nblocks, backend)
+    if backend == "pallas":
+        from plenum_tpu.ops import mesh as mesh_mod
+        from plenum_tpu.ops import sha256_pallas as sp
+        if not mesh_mod.launch_survives(sp.PALLAS_ENV, ("routed", nblocks),
+                                        out, "pallas sha256"):
+            # re-route: select_backend now skips the stepped-down family
+            return sha256_blocks_routed(blocks, nvalid, nblocks)
+    return out
 
 
 def pad_messages(msgs: Sequence[bytes], nblocks: int = None

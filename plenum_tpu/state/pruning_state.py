@@ -140,13 +140,11 @@ class PruningState(State):
         if batch_min is not None:
             self._engine_batch_min = batch_min
         if warm:
-            try:
-                engine.warm()
-            except Exception:  # plenum-lint: disable=PT006 — warm-up is
-                # best-effort: a broken backend must not fail bootstrap;
-                # the first real batch retries and the breaker detaches
-                logger.warning("state engine warm-up failed; it will "
-                               "retry lazily", exc_info=True)
+            # warm-up runs under the same breaker as serving: a broken
+            # backend must not fail bootstrap (the first real batch
+            # retries), but the failure is COUNTED like any other call
+            # the host had to serve
+            self._engine_breaker.run(engine.warm, "warm-up")
         return engine
 
     def _engine_call(self, fn, label: str):

@@ -23,7 +23,7 @@ the cooldown is a single probe: success closes the breaker and the
 engine serves again; failure re-trips quietly (debug log) for another
 cooldown. The seams therefore keep the engine ATTACHED across trips —
 "re-attach" is the breaker closing again, never a new attach call, so
-a transient device outage (driver restart, tunnel hiccup) heals
+a transient device outage (driver or runtime restart) heals
 without operator intervention.
 """
 from __future__ import annotations
@@ -58,7 +58,12 @@ class DeviceCircuitBreaker:
         self.fail_count = 0
         # monotonic deadline of the current OPEN window; None = CLOSED
         self._open_until = None
-        # observability: lifetime trip / successful-probe counts
+        # observability: lifetime counts — every call the host served
+        # because the engine raised (`failures`, never reset: what
+        # chip_smoke.py and the benchmark read to refuse a run whose
+        # "device" results came from the host), trips and successful
+        # probes
+        self.failures = 0
         self.trips = 0
         self.recoveries = 0
 
@@ -108,6 +113,7 @@ class DeviceCircuitBreaker:
             except Exception:  # plenum-lint: disable=PT006 — this IS
                 # the designed host-fallback boundary: ANY engine/device
                 # failure must degrade to the host path, never crash
+                self.failures += 1
                 self._trip(quiet=True)
                 return False, None
             self._open_until = None
@@ -123,6 +129,7 @@ class DeviceCircuitBreaker:
         except Exception:  # plenum-lint: disable=PT006 — this IS the
             # designed host-fallback boundary: ANY engine/device
             # failure must degrade to the host path, never crash
+            self.failures += 1
             self.fail_count += 1
             if self.fail_count >= self.max_failures:
                 self._trip(quiet=False)

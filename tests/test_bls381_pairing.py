@@ -108,25 +108,40 @@ def test_threshold_and_env_gate(monkeypatch):
     assert bls.pairing_device_ready(100) is False
 
 
-def test_device_failure_steps_down_to_host(monkeypatch, tower_on):
-    """A device-side exception must serve host verdicts AND disable the
-    family permanently (the sha256/ed25519 step-down contract)."""
+@pytest.mark.parametrize("stage", ["run", "compile"])
+def test_device_failure_policy(monkeypatch, tower_on, stage):
+    """A kernel that dies at RUN time (surfacing at collect) must serve
+    host verdicts AND step the family down permanently — counted, so no
+    run can pass host verdicts off as device ones (the sha256/ed25519
+    contract). A failure in the dispatch half (trace / lowering /
+    compile) is a program bug and raises."""
     import sys
     import types
     from plenum_tpu.ops import mesh as mesh_mod
 
     fake = types.ModuleType("plenum_tpu.ops.bls381_pairing")
 
-    def _boom(jobs):
-        raise RuntimeError("induced device failure")
-    fake.pairing_jobs = _boom
+    def _boom(*_a):
+        raise RuntimeError("induced %s failure" % stage)
+    fake.pairing_dispatch = _boom if stage == "compile" \
+        else (lambda jobs: "handles")
+    fake.pairing_collect = _boom
     monkeypatch.setitem(sys.modules, "plenum_tpu.ops.bls381_pairing",
                         fake)
     jobs = [_good_pair_job(sk=k) for k in (2, 3, 4, 5)]
     jobs.append([(b"\x00" * 48, g2_compress(G2_GEN))])
+    before = mesh_mod.step_down_counts().get(bls.BLS_TOWER_ENV, 0)
+    if stage == "compile":
+        with pytest.raises(RuntimeError, match="induced compile"):
+            bls.multi_pairing_is_one_jobs(jobs)
+        assert mesh_mod.xla_backend_enabled(bls.BLS_TOWER_ENV) is True
+        assert mesh_mod.step_down_counts().get(
+            bls.BLS_TOWER_ENV, 0) == before
+        return
     got = bls.multi_pairing_is_one_jobs(jobs)
     assert got == [True, True, True, True, False]
     assert mesh_mod.xla_backend_enabled(bls.BLS_TOWER_ENV) is False
+    assert mesh_mod.step_down_counts()[bls.BLS_TOWER_ENV] == before + 1
     # the step-down sticks: later batches go host without retrying
     assert bls.pairing_device_ready(len(jobs)) is False
 

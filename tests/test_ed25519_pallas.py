@@ -4,9 +4,11 @@ The field/point helpers are plain array expressions, so they are unit-
 tested here against python-int ground truth with numpy standing in for
 jnp — no XLA, no device, every limb-discipline subtlety (carry wraps,
 the finalize-after-add/sub invariant, fcanon's multi-p handling)
-pinned down exactly. The full-kernel TPU cross-check against the XLA
-kernel runs only when a real accelerator is present (the suite forces
-JAX_PLATFORMS=cpu); bench.py exercises it on every TPU run.
+pinned down exactly. The WHOLE kernel body runs the same way
+(test_whole_kernel_body_matches_reference): refs are numpy arrays, the
+ladder's fori_loop a Python loop. The compiled kernel is checked where
+it can be: tests/test_tpu_compile.py compiles it for a described v5e,
+and chip_smoke.py compares its verdicts with OpenSSL on the chip.
 """
 import functools
 import random
@@ -123,15 +125,61 @@ def test_decompress_recovers_x(numpy_field):
         assert _value(x2, j) == (P - pts_lane[j][0]) % P
 
 
-@pytest.mark.skipif(
-    True, reason="full-kernel TPU cross-check needs a real accelerator; "
-                 "the suite pins JAX_PLATFORMS=cpu (bench.py covers it)")
-def test_pallas_matches_xla_on_device():      # pragma: no cover
+class _NpRef:
+    """Stand-in for a Pallas ref over a numpy array: integer indexing,
+    pl.ds(start, size) slices, and the output store."""
+
+    def __init__(self, arr):
+        self.arr = arr
+
+    def __getitem__(self, idx):
+        if hasattr(idx, "start") and hasattr(idx, "size"):     # pl.ds
+            return self.arr[int(idx.start):int(idx.start) + int(idx.size)]
+        return self.arr[idx]
+
+    def __setitem__(self, idx, val):
+        self.arr[idx] = val
+
+
+def test_whole_kernel_body_matches_reference(numpy_field, monkeypatch):
+    """The ENTIRE kernel body (_verify_kernel_pallas: both
+    decompressions, the per-signature window table, the 64-window
+    double-scalar ladder, the final compare) on one small tile, against
+    the RFC 8032 reference — valid signatures, a flipped signature bit,
+    a wrong message, a wrong key and an off-curve R. (The compiled
+    kernel's cross-check runs on the chip: chip_smoke.py.)"""
+    import jax.lax
+    from plenum_tpu.crypto import ed25519 as ref
     from plenum_tpu.crypto.fixtures import make_signed_batch
-    msgs, sigs, vks = make_signed_batch(edp.BLOCK, seed=5, unique=64)
-    sigs = list(sigs)
-    sigs[3] = sigs[3][:10] + bytes([sigs[3][10] ^ 1]) + sigs[3][11:]
+
+    def python_fori(lo, hi, body, init):
+        st = init
+        for i in range(lo, hi):
+            st = body(i, st)
+        return st
+
+    monkeypatch.setattr(jax.lax, "fori_loop", python_fori)
+    n = 8
+    msgs, sigs, vks = (list(x) for x in make_signed_batch(n, seed=5))
+    sigs[1] = sigs[1][:10] + bytes([sigs[1][10] ^ 1]) + sigs[1][11:]
+    msgs[2] = msgs[2] + b"!"
+    vks[3] = vks[4]
+    # R.y = 2 is not on the curve: u/v has no square root
+    sigs[5] = (2).to_bytes(32, "little") + sigs[5][32:]
+    want = [ref.verify(m, s, v) for m, s, v in zip(msgs, sigs, vks)]
+    assert want == [True, False, False, False, True, False, True, True]
+
     arrays, valid = edj.host_pack(msgs, sigs, vks)
-    want = np.asarray(edj._verify_kernel(*arrays)) & valid
-    got = np.asarray(edp.verify_kernel(*arrays)) & valid
-    assert (want == got).all()
+    ay, asign, ry, rsign, s_words, k_words = (np.asarray(a) for a in arrays)
+
+    def tiles(x_bt):                  # [B, K] -> [K, 1, B], as to_blocks
+        return np.ascontiguousarray(x_bt.T.reshape(x_bt.shape[1], 1, n))
+
+    ok = np.zeros((1, 1, n), dtype=np.int32)
+    edp._verify_kernel_pallas(
+        _NpRef(tiles(ay)), _NpRef(tiles(asign[:, None])),
+        _NpRef(tiles(ry)), _NpRef(tiles(rsign[:, None])),
+        _NpRef(tiles(np.asarray(edj._digits4(s_words)))),
+        _NpRef(tiles(np.asarray(edj._digits4(k_words)))), _NpRef(ok))
+    got = [bool(o) and bool(v) for o, v in zip(ok[0, 0], valid)]
+    assert got == want

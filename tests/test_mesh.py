@@ -44,6 +44,40 @@ def test_enumerates_forced_cpu_mesh(mesh):
     assert not mesh_mod.is_accelerator()
 
 
+@pytest.mark.parametrize("reader", ["probe_platform", "device_facts",
+                                    "is_accelerator", "mesh_devices"])
+def test_backend_error_propagates(mesh, monkeypatch, reader):
+    """A backend that cannot be initialised (the chip is held by
+    another process, the runtime is broken) RAISES from every probe —
+    it used to read as "cpu" / "one device", which let a run that lost
+    its chip go on under device names. An explicit CPU pin (this suite)
+    still reads "cpu" afterwards: JAX succeeds there."""
+    import jax
+
+    def unavailable(*_a, **_k):
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    mesh_mod._reset_probe()
+    mesh.reset_devices()
+    monkeypatch.setattr(jax, "devices", unavailable)
+    try:
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            if reader == "mesh_devices":
+                mesh.n_devices
+            else:
+                getattr(mesh_mod, reader)()
+        assert not mesh_mod.probed()      # the failure is not cached
+    finally:
+        monkeypatch.undo()
+        mesh_mod._reset_probe()
+        mesh.reset_devices()
+    assert mesh_mod.probe_platform() == "cpu"
+    assert mesh_mod.device_facts() == {
+        "platform": "cpu", "kind": jax.devices()[0].device_kind,
+        "count": 8}
+    assert mesh.n_devices == 8
+
+
 def test_max_devices_cap_rounds_down_to_pow2(mesh):
     mesh_mod.configure(max_devices=6)
     assert mesh.n_devices == 4
