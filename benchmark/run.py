@@ -1,0 +1,392 @@
+#!/usr/bin/env python3
+"""One cell, once:
+
+    python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Starts the deployment the cell's configuration names (verify daemon on
+the chip, n node processes, this process as the client), warms up,
+drives the cell's traffic for --seconds, waits for every answer due,
+stops everything, checks the answers against the plain reference and
+prints one JSON line last. See README.md for the layout and for the
+builder-only modes (--tiny, --sweep, --seeds).
+
+This process never initialises a JAX backend: the daemon owns the chip.
+"""
+import time
+T_PROCESS = time.perf_counter()
+
+import argparse  # noqa: E402
+import asyncio  # noqa: E402
+import importlib  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
+import tempfile  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+sys.path.insert(1, ROOT)
+
+import check  # noqa: E402
+import generators  # noqa: E402
+import operations  # noqa: E402
+import trace_reduce  # noqa: E402
+import traffic  # noqa: E402
+import window  # noqa: E402
+from client import Client, Op  # noqa: E402
+from pool import (  # noqa: E402
+    Daemon, Pool, Procs, in_thread, jax_backend_untouched, log,
+    native_modules, tail)
+
+SETUP_BUDGET_S = 1100     # a first run compiles: 1200 s in all
+DRAIN_BUDGET_S = 60
+
+
+class NoResult(Exception):
+    """The run cannot give a result line (no chip, no program)."""
+
+
+def load_json(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+class Cell:
+    """One entry of BENCHMARK.json's workloads, with its files."""
+
+    def __init__(self, name: str):
+        self.bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+        cells = {w["name"]: w for w in self.bench["workloads"]}
+        if name not in cells:
+            raise NoResult("no cell %r in BENCHMARK.json (have %s)"
+                           % (name, sorted(cells)))
+        self.entry = cells[name]
+        self.name = name
+        self.chips = self.entry["chips"]
+        conf = {c["name"]: c for c in self.bench["configs"]}[
+            self.entry["config"]]
+        self.config = load_json(os.path.join(ROOT, conf["file"]))
+        self.traffic = load_json(os.path.join(
+            HERE, "workloads", self.entry["traffic"] + ".json"))
+
+    def metrics(self, group: str):
+        """The metrics of `end_to_end` or `per_layer` this cell reports:
+        those without a `workloads` key, and those that list it."""
+        return [m for m in self.bench[group]
+                if "workloads" not in m or self.name in m["workloads"]]
+
+
+# ------------------------------------------------------------ one run
+
+def make_ops(seed: int, plan: dict, traffic_file: dict):
+    count = plan["max_ops"] if plan["kind"] == "closed" \
+        else len(plan["due"])
+    made = operations.make(seed, count + 1, traffic_file["operations"])
+    return [Op(req, Client.wire(req), valid) for req, valid in made]
+
+
+async def drive(pool, daemon, ops, plan, seconds, traced, marks_out,
+                deadline):
+    """Connect, probe, window, drain → the window's record."""
+    client = Client(pool.names, pool.f)
+    for op in ops:
+        client.register(op)
+    try:
+        await client.connect(pool.base_dir, deadline)
+        log("client connected to %d nodes" % len(pool.names))
+        # the first valid write is the probe; the window's start there
+        probe_op = next(op for op in ops if op.valid)
+        await window.probe(client, probe_op, deadline)
+        log("probe write ordered")
+        rest = [op for op in ops if op is not probe_op]
+        before = {"reports": pool.wait_reports(
+            len(pool.genesis_domain_txns()) + 1, time.monotonic() + 10),
+            "cpu_s": pool.cpu_seconds()}
+        marks = []
+        if traced:
+            half = min(2.5, seconds / 4.0)
+            marks = [(seconds / 2.0 - half, "profile_start"),
+                     (seconds / 2.0 + half, "profile_stop")]
+
+        def on_mark(label):
+            marks_out[label] = time.perf_counter()
+            daemon.signal_profile(label == "profile_start")
+
+        setup_s = time.perf_counter() - T_PROCESS
+        rec = await window.run_window(client, rest, plan, seconds,
+                                      on_mark, marks)
+        rec["cpu_s"] = (before["cpu_s"], pool.cpu_seconds())
+        rec["setup_s"] = setup_s
+        rec["reports_before"] = before["reports"]
+        rec["drain_s"] = await window.drain(
+            client, rec["released"], time.monotonic() + DRAIN_BUDGET_S)
+        rec["probe_op"] = probe_op
+        rec["stray"] = client.stray
+        rec["dead_links"] = client.dead_links()
+        return rec
+    finally:
+        client.close()
+
+
+def start_pool(cell, daemon, procs, workdir, seed, seconds, tiny, base_port):
+    """A fresh pool beside the daemon (which may still be warming up):
+    generated, configured, its nodes started, and the window's
+    operations signed → (pool, plan, ops)."""
+    base_dir = tempfile.mkdtemp(prefix="pool_", dir=workdir)
+    pool = Pool(procs, base_dir, cell.config, tiny, base_port)
+    pool.generate(traffic.trustee_seed(seed))
+    pool.write_config(daemon.info["port"])
+    pool.start_nodes()
+    plan = generators.plan(cell.traffic, seed, seconds)
+    ops = make_ops(seed, plan, cell.traffic)
+    log("%d operations signed" % len(ops))
+    return pool, plan, ops
+
+
+def finish_pool(pool, daemon, plan, ops, seconds, traced, deadline):
+    """Window, drain, the nodes' last reports, nodes stopped → everything
+    the readers and the check need. The daemon is left running."""
+    marks = {}
+    try:
+        rec = asyncio.run(drive(pool, daemon, ops, plan, seconds, traced,
+                                marks, deadline))
+    except BaseException:
+        pool.log_tails()
+        raise
+    rec["marks"] = marks
+    released = rec["released"]
+    valid = [op for op in released if op.valid]
+    want = len(pool.genesis_domain_txns()) + 1 + sum(
+        1 for op in valid if op.answers)
+    rec["reports_after"] = pool.wait_reports(want, time.monotonic() + 30)
+    rec["dead_nodes"] = pool.dead_nodes()
+    pool.stop()
+    rec["pool"] = pool
+    rec["plan"] = plan
+    rec["seconds"] = seconds
+    return rec
+
+
+def read_metrics(cell, group, run) -> dict:
+    """Every metric of `end_to_end` or `per_layer` that this cell
+    reports is a data file (metrics/<name>.json) naming a reader
+    (readers/<reader>.py). A reader that finds nothing to read returns
+    None and the metric is left out of the line; a reader that fails
+    fails the run (a device kind that peaks.json does not hold must not
+    pass as a run without a roofline)."""
+    out = {}
+    for m in cell.metrics(group):
+        spec = load_json(os.path.join(HERE, "metrics", m["name"] + ".json"))
+        reader = importlib.import_module("readers." + spec["reader"])
+        value = reader.read(spec, run)
+        if value is not None:
+            out[m["name"]] = value
+    return out
+
+
+def gathered(rec, daemon_stats, side, daemon) -> dict:
+    """What a traced run hands its readers, beside the window's record."""
+    return dict(rec, daemon_stats=daemon_stats, side=side,
+                warm=daemon.warm, spans_file=daemon.trace_file,
+                profile_dir=daemon.profile_dir, ready=daemon.info,
+                peaks_file=os.path.join(HERE, "peaks.json"), cache={})
+
+
+def judge(rec, daemon, daemon_stats, tiny):
+    pool = rec["pool"]
+    ops = [rec["probe_op"]] + rec["released"]
+    obs = check.Observed(pool.names, pool.f, ops, rec["reports_after"],
+                         daemon.info, daemon_stats,
+                         ran_dry=rec["ran_dry"], tiny=tiny)
+    genesis = pool.genesis_domain_txns()
+    t = time.perf_counter()
+    got = check.compare(obs, genesis)
+    got["seconds"] = time.perf_counter() - t
+    got["obs"] = obs
+    got["genesis"] = genesis
+    if rec["dead_nodes"] or rec["dead_links"]:
+        got["values"]["unanswered_by_a_node"] += 1
+        got["notes"]["dead"] = {"nodes": rec["dead_nodes"],
+                                "links": rec["dead_links"]}
+    if not jax_backend_untouched():
+        got["values"]["daemon_faults"] += 1
+        got["notes"]["parent"] = "the harness initialised a JAX backend"
+    return got
+
+
+def result_line(rec, metrics, units, daemon, side, got, device_extra,
+                breakdown=None) -> dict:
+    released = rec["released"]
+    valid = [op for op in released if op.valid]
+    device = dict((daemon.info or {}).get("device") or {})
+    line = {
+        "correct": check.verdict(got["values"]),
+        "attempted": len(released),
+        "failed": sum(1 for op in valid if op.done is None),
+        "metrics": {k: {"value": v, "unit": units[k]}
+                    for k, v in metrics.items()},
+        "device": {"platform": device.get("platform"),
+                   "kind": device.get("kind"),
+                   "count": device.get("count"),
+                   "memory_peak_bytes": side.get("memory_peak_bytes")},
+        "host": {"cores": os.cpu_count()},
+        "window": {"seconds": rec["t1"] - rec["t0"],
+                   "drain_s": rec["drain_s"],
+                   "refused_as_expected": sum(
+                       1 for op in released if not op.valid and op.done),
+                   "valid_also_refused": sum(
+                       1 for op in valid if op.refused),
+                   "check_s": got["seconds"],
+                   "warm": daemon.warm},
+    }
+    line["device"].update(device_extra or {})
+    if breakdown:
+        line["breakdown"] = breakdown
+    line["compared"] = check.table(got["values"])
+    return line
+
+
+def print_checks(got) -> None:
+    if got["notes"].get("daemon_problems") or not check.verdict(
+            got["values"]):
+        log("notes: %s" % json.dumps(got["notes"], default=str)[:6000])
+    for name, (value, limit) in check.table(got["values"]).items():
+        print("compared %-22s %8s  limit %s" % (name, value, limit),
+              file=sys.stderr, flush=True)
+
+
+def units_of(cell) -> dict:
+    return {m["name"]: m["unit"]
+            for g in ("end_to_end", "per_layer") for m in cell.bench[g]}
+
+
+def start_daemon(cell, procs, workdir, tiny, traced):
+    daemon = Daemon(procs, workdir, cell.config, tiny, traced)
+    daemon.start()
+    try:
+        info = daemon.wait_ready(timeout=300)
+    except RuntimeError:
+        raise NoResult("the verify daemon did not start: no accelerator "
+                       "here, or another process holds it\n"
+                       + tail(os.path.join(workdir, "daemon.err"), 15))
+    device = info.get("device") or {}
+    log("host cores %s; daemon device %s; compile cache %s" % (
+        os.cpu_count(), json.dumps(device), info.get("compile_cache")))
+    if not tiny and (device.get("platform") != "tpu"
+                     or (device.get("count") or 0) < cell.chips):
+        raise NoResult("the cell asks for %d tpu chip(s), the daemon got "
+                       "%s" % (cell.chips, device))
+    return daemon
+
+
+def single(args, cell, procs, workdir) -> int:
+    traced = bool(args.trace)
+    deadline = time.monotonic() + SETUP_BUDGET_S
+    natives = native_modules()
+    log("native modules %s" % json.dumps(natives))
+    daemon = start_daemon(cell, procs, workdir, args.tiny, traced)
+    warm_thread, warm_box = in_thread(daemon.warm_up, args.seed,
+                                      SETUP_BUDGET_S)
+    base_port = 19000 + (os.getpid() % 40) * 320
+    pool, plan, ops = start_pool(cell, daemon, procs, workdir, args.seed,
+                                 args.seconds, args.tiny, base_port)
+    warm_thread.join()
+    if "error" in warm_box:
+        log(tail(os.path.join(workdir, "daemon.err"), 30))
+        raise warm_box["error"]
+    log("daemon warm: first launch %.1fs, steady %.3fs" % (
+        daemon.warm["first_launch_s"], daemon.warm["steady_launch_s"]))
+    rec = finish_pool(pool, daemon, plan, ops, args.seconds, traced,
+                      deadline)
+    daemon_stats, side = daemon.stop()
+    got = judge(rec, daemon, daemon_stats, args.tiny)
+    units = units_of(cell)
+    device_extra, breakdown = {}, None
+    if traced:
+        run = gathered(rec, daemon_stats, side, daemon)
+        metrics = read_metrics(cell, "per_layer", run)
+        trace = run["cache"].get("device_trace")
+        if trace:
+            device_extra = {"busy_s": trace["busy_s"],
+                            "window_s": trace["window_s"]}
+            breakdown = trace.get("breakdown")
+    else:
+        metrics = read_metrics(cell, "end_to_end", rec)
+    if args.keep:
+        keep(args.keep, workdir, pool, daemon)
+    print_checks(got)
+    line = result_line(rec, metrics, units, daemon, side, got, device_extra,
+                       breakdown)
+    if traced and not device_extra and not args.tiny:
+        log("the traced run read no device trace")
+        return 1
+    print(json.dumps(line), flush=True)
+    return 0
+
+
+def keep(dest, workdir, pool, daemon) -> None:
+    """Builder only: copy logs, spans and the trace out of the temp dir
+    (chiprun_out/ comes back from the chip)."""
+    os.makedirs(dest, exist_ok=True)
+    for name in os.listdir(workdir):
+        src = os.path.join(workdir, name)
+        if os.path.isfile(src):
+            shutil.copy(src, dest)
+    for name in pool.names:
+        out = os.path.join(pool.base_dir, name + ".out")
+        if os.path.exists(out):
+            shutil.copy(out, dest)
+    xplane = trace_reduce.newest_xplane(daemon.profile_dir)
+    if xplane:
+        shutil.copy(xplane, dest)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--trace", type=int, default=0, choices=[0, 1])
+    ap.add_argument("--tiny", action="store_true",
+                    help="builder: CPU rehearsal at a tiny size; the "
+                         "last line says correct false (no tpu)")
+    ap.add_argument("--seeds", default=None,
+                    help="builder: several seeds on ONE warmed daemon, "
+                         "a fresh pool each, with the controls")
+    ap.add_argument("--sweep", default=None,
+                    help="builder: comma-separated period_s values, one "
+                         "warmed daemon, a fresh pool each")
+    ap.add_argument("--keep", default=None, metavar="DIR",
+                    help="builder: copy logs and traces here")
+    args = ap.parse_args()
+    if not os.path.isdir(os.path.join(ROOT, "plenum_tpu")):
+        print("plenum_tpu/ is not beside benchmark/: nothing to measure",
+              file=sys.stderr)
+        return 2
+    procs = Procs()
+    signal.signal(signal.SIGTERM, lambda s, f: sys.exit(143))
+    workdir = tempfile.mkdtemp(prefix="plenum_bench_")
+    try:
+        cell = Cell(args.workload)
+        if args.seconds is None:
+            args.seconds = float(cell.bench["run_seconds"])
+        if args.tiny:
+            for key, value in cell.traffic.get("tiny", {}).items():
+                cell.traffic["params"][key] = value
+        if args.seeds or args.sweep:
+            import builder
+            return builder.main(args, cell, procs, workdir)
+        return single(args, cell, procs, workdir)
+    except NoResult as e:
+        print("no result: %s" % e, file=sys.stderr)
+        return 3
+    finally:
+        procs.stop()
+        shutil.rmtree(workdir, ignore_errors=True)
+
+
+if __name__ == "__main__":
+    sys.exit(main())
