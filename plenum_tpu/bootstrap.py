@@ -17,6 +17,7 @@ reference pool_manager.py), domain txns hold steward/trustee NYMs.
 from __future__ import annotations
 
 import json
+import logging
 import os
 import sys
 from typing import Dict, List, Optional, Sequence
@@ -274,5 +275,15 @@ async def run_node(node, stop_event=None) -> None:
             produced = await node.prod()
             await asyncio.sleep(0 if produced else 0.01)
     finally:
-        await node.nodestack.stop()
-        await node.clientstack.stop()
+        try:
+            await node.nodestack.stop()
+            await node.clientstack.stop()
+        finally:
+            # a node told of a host trace session (the verify daemon's
+            # --trace-file) hands out its spans here, at clean stop —
+            # the only I/O the flight recorder does in a served node
+            try:
+                node.node.write_trace_dump()
+            except Exception:
+                logging.getLogger(__name__).warning(
+                    "%s: trace dump failed", node.name, exc_info=True)

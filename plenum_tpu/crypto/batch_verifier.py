@@ -26,6 +26,8 @@ from plenum_tpu.observability import telemetry as _telemetry
 
 VerifyItem = Tuple[bytes, bytes, bytes]  # (message, signature64, verkey32)
 
+_NULL_TRACER = NullTracer()
+
 
 class _Ready:
     """Already-materialized result (scalar paths)."""
@@ -44,10 +46,12 @@ class _PendingDevice:
     """In-flight device batch: JAX dispatch is async — creating this does
     not block; collect() materializes (blocks on the device)."""
 
-    def __init__(self, ok_device, valid, n):
+    def __init__(self, ok_device, valid, n, tracer=None, span_args=None):
         self._ok = ok_device
         self._valid = valid
         self._n = n
+        self._tracer = tracer or _NULL_TRACER
+        self._span_args = span_args or {}
 
     def ready(self) -> bool:
         is_ready = getattr(self._ok, "is_ready", None)
@@ -55,7 +59,10 @@ class _PendingDevice:
 
     def collect(self) -> List[bool]:
         import numpy as np
-        return list(np.asarray(self._ok)[:self._n] & self._valid)
+        # waiting for the device and copying the verdicts back
+        with self._tracer.span("verify_collect", CAT_DEVICE,
+                               **self._span_args):
+            return list(np.asarray(self._ok)[:self._n] & self._valid)
 
 
 class ScalarVerifier:
@@ -106,6 +113,11 @@ class OpenSSLVerifier:
 
 class JaxBatchVerifier:
     name = "tpu_batch"
+    # the verify daemon sets both before each coalesced batch, so a
+    # launch's host halves show inside its device_verify span with the
+    # batch's items/unique; everyone else leaves the null tracer
+    tracer = _NULL_TRACER
+    span_args: dict = {}
 
     def verify_batch(self, items: Sequence[VerifyItem]) -> List[bool]:
         return self.dispatch(items).collect()
@@ -116,11 +128,17 @@ class JaxBatchVerifier:
         consensus work / other nodes\' dispatches with the device round
         trip and harvests later (SURVEY.md §7 backpressure design)."""
         from plenum_tpu.ops import ed25519_jax
-        msgs = [m for m, _, _ in items]
-        sigs = [s for _, s, _ in items]
-        vks = [vk for _, _, vk in items]
-        ok_dev, valid, n = ed25519_jax.verify_batch_async(msgs, sigs, vks)
-        return _PendingDevice(ok_dev, valid, n)
+        tracer, span_args = self.tracer, self.span_args
+        # bytes → padded host arrays
+        with tracer.span("verify_pack", CAT_DEVICE, **span_args):
+            msgs = [m for m, _, _ in items]
+            sigs = [s for _, s, _ in items]
+            vks = [vk for _, _, vk in items]
+            arrays, valid, n = ed25519_jax.pack_batch(msgs, sigs, vks)
+        # host-to-device transfer and the dispatch, until it returns
+        with tracer.span("verify_launch", CAT_DEVICE, **span_args):
+            ok_dev = ed25519_jax.launch_packed(arrays, n)
+        return _PendingDevice(ok_dev, valid, n, tracer, span_args)
 
 
 def _default_threshold(threshold):
@@ -143,6 +161,12 @@ class AdaptiveVerifier:
         self.threshold = _default_threshold(threshold)
         self._scalar = scalar or OpenSSLVerifier()
         self._batch = batch or JaxBatchVerifier()
+
+    @property
+    def device_provider(self):
+        """The provider that serves batches at or above the threshold
+        (where a caller attaches device-side tracing)."""
+        return self._batch
 
     def verify_batch(self, items: Sequence[VerifyItem]) -> List[bool]:
         if len(items) >= self.threshold:

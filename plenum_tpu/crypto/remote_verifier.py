@@ -7,10 +7,13 @@ so the daemon round trip (socket + coalescing window + device launch)
 overlaps consensus
 work instead of blocking a tick. The socket is plain blocking TCP used
 non-blockingly for reads; frames are length-prefixed msgpack (see the
-daemon's protocol doc).
+daemon's protocol doc). Request ids start at 1: a frame with id 0 is a
+control frame from the daemon (a host trace session) and goes to
+``on_control`` instead of the results; with no callback it is dropped.
 """
 from __future__ import annotations
 
+import json
 import logging
 import socket
 import struct
@@ -18,6 +21,8 @@ import time
 from typing import Dict, List, Sequence, Tuple
 
 import msgpack
+
+from plenum_tpu.observability.tracing import CAT_DEVICE, NullTracer
 
 logger = logging.getLogger(__name__)
 
@@ -51,8 +56,10 @@ class _RemotePending:
                 break
             # block until THIS request's frame lands — returning on just
             # any response would mis-handle out-of-order harvest when
-            # more than one request is in flight
-            v._pump(block=True, until=self._req_id)
+            # more than one request is in flight. The span is the
+            # caller's one thread blocked on the daemon
+            with v.tracer.span("verify_wait", CAT_DEVICE, n=self._n):
+                v._pump(block=True, until=self._req_id)
         body = v._results.pop(self._req_id, b"")
         # a short body (daemon rejected the frame, or the link dropped
         # mid-request) fails the missing tail instead of crashing the
@@ -78,6 +85,9 @@ class RemoteVerifier:
         self._outstanding: Dict[int, int] = {}  # req_id -> item count
         self._next_id = 0
         self._last_dial_fail = 0.0
+        self.tracer = NullTracer()   # node injects the real one
+        # callable(payload) for the daemon's id-0 control frames
+        self.on_control = None
         # initial connect is best-effort: in multi-process deployments
         # the daemon may come up after the node (start-ordering race,
         # daemon restart); dispatch() re-dials lazily, so construction
@@ -161,6 +171,29 @@ class RemoteVerifier:
     def verify_batch(self, items: Sequence[VerifyItem]) -> List[bool]:
         return self.dispatch(items).collect()
 
+    def daemon_stats(self) -> dict:
+        """The running daemon's counters (its ``stats()``), asked for
+        over this connection: the frame ``[id, "stats"]`` is answered
+        by the daemon's connection handler and never waits behind a
+        verification batch. Raises ConnectionError if the link is down
+        or drops."""
+        if self._sock is None:
+            raise ConnectionError("no link to the verify daemon")
+        self._next_id += 1
+        req_id = self._next_id
+        frame = msgpack.packb([req_id, "stats"], use_bin_type=True)
+        self._outstanding[req_id] = 0
+        try:
+            self._sock.sendall(LEN.pack(len(frame)) + frame)
+        except OSError:
+            self._drop_link()
+        while req_id not in self._results and self._sock is not None:
+            self._pump(block=True, until=req_id)
+        body = self._results.pop(req_id, b"")
+        if not body:
+            raise ConnectionError("verify daemon link lost")
+        return json.loads(body)
+
     # ------------------------------------------------------------- recv
 
     def _pump(self, block: bool, until: int = None):
@@ -196,5 +229,9 @@ class RemoteVerifier:
                 return
             req_id, body = msgpack.unpackb(self._rx[4:4 + n], raw=False)
             self._rx = self._rx[4 + n:]
+            if req_id == 0:
+                if self.on_control is not None:
+                    self.on_control(body)
+                continue
             self._results[req_id] = body
             self._outstanding.pop(req_id, None)

@@ -20,6 +20,12 @@ stage:
                   inside it (exclusive time: nested spans are charged
                   to their own stage exactly once)
 * ``reply``     — reply construction + audit paths
+* ``transport`` — the prod tick's socket seams on a served node
+                  (server/networked_node.py): AEAD decrypt + parse of
+                  peer and client frames, encrypt + write of outboxes
+* ``untraced``  — what is left of a productive prod tick
+                  (``prod_tick``) once every span inside it is taken
+                  off: host work no stage span covers yet
 
 Span time is EXCLUSIVE: a ``fused_dispatch`` nested inside
 ``batch_apply`` counts toward ``dispatch_wait``, and only the
@@ -37,7 +43,8 @@ from plenum_tpu.observability.telemetry import TM as _TM
 
 # stage order is the money-path order; reports preserve it
 STAGES = ("intake", "propagate", "serialize", "parse", "queue_wait",
-          "3pc", "dispatch_wait", "execute", "reply")
+          "3pc", "dispatch_wait", "execute", "reply", "transport",
+          "untraced")
 
 # named sub-stages of the execute budget line (conflict-lane executor,
 # server/executor.py): plan+prefetch / per-request validate-apply /
@@ -64,6 +71,9 @@ _NAME_TO_STAGE = {
     # latency is attributable instead of smearing into the consuming
     # 3PC stage — a mis-sized queue shows up as THIS row moving.
     "queue_wait": "queue_wait",
+    # the served node's tick envelope (server/networked_node.py): its
+    # EXCLUSIVE time is the tick's work that no stage span covers
+    "prod_tick": "untraced",
 }
 _CAT_TO_STAGE = {
     "intake": "intake",
@@ -73,6 +83,7 @@ _CAT_TO_STAGE = {
     "bls": "dispatch_wait",
     "execute": "execute",
     "reply": "reply",
+    "transport": "transport",
 }
 
 

@@ -30,6 +30,7 @@ tids follow first-appearance order, and the timeline is sorted by
 from __future__ import annotations
 
 import json
+import os
 from typing import Iterable, List, Optional
 
 
@@ -129,13 +130,62 @@ def chrome_trace(tracers: Iterable, telemetry: Iterable = ()) -> dict:
             "displayTimeUnit": "ms"}
 
 
+def dump_metadata(tracers: Iterable) -> dict:
+    """What a reader of a FILE needs to trust it, per tracer name: the
+    clock the timestamps are readings of, `stats()` (`recorded`,
+    `dropped`), when the oldest surviving record was written (μs, like
+    every `ts`: a window that starts before it lost records to a wrap)
+    and a `clock_sync` (perf μs, wall s) pair sampled now."""
+    out = {}
+    for tracer in tracers:
+        if tracer is None or not hasattr(tracer, "clock_info"):
+            continue
+        oldest = tracer.oldest_written_at()
+        perf, wall = tracer.clock_pair()
+        out[tracer.name or "node"] = {
+            "clock": tracer.clock_info(),
+            "stats": tracer.stats(),
+            "oldest_ts": None if oldest is None
+            else int(round(oldest * 1e6)),
+            "clock_sync": {"perf_ts": int(round(perf * 1e6)),
+                           "wall_s": wall},
+        }
+    return out
+
+
 def export_chrome_trace(tracers: Iterable, path: str,
                         telemetry: Iterable = ()) -> str:
-    """Write the merged timeline to `path`; → path."""
+    """Write the merged timeline, with `dump_metadata` under
+    "metadata", to `path` (through a temporary file and a rename: a
+    present file is a complete one); → path."""
+    tracers = list(tracers)
     doc = chrome_trace(tracers, telemetry=telemetry)
-    with open(path, "w") as f:
+    doc["metadata"] = dump_metadata(tracers)
+    tmp = "%s.%d.tmp" % (path, os.getpid())
+    with open(tmp, "w") as f:
         json.dump(doc, f)
+    os.replace(tmp, path)
     return path
+
+
+def merge_trace_documents(docs: Iterable[dict]) -> dict:
+    """Dumps of several processes (a host trace session leaves one per
+    node beside the daemon's) → one document. Every process numbered
+    its own pids from 1, so they are renumbered to stay distinct; the
+    timestamps already share the host's monotonic clock."""
+    events: List[dict] = []
+    metadata: dict = {}
+    base = 0
+    for doc in docs:
+        top = 0
+        for e in doc.get("traceEvents", []):
+            top = max(top, e.get("pid", 0))
+            events.append(dict(e, pid=e.get("pid", 0) + base))
+        base += top
+        metadata.update(doc.get("metadata") or {})
+    events.sort(key=lambda e: (e.get("ph") != "M", e.get("ts", 0)))
+    return {"traceEvents": events, "displayTimeUnit": "ms",
+            "metadata": metadata}
 
 
 def pool_telemetry(nodes: Iterable) -> List:

@@ -670,44 +670,63 @@ def launch_lanes(n: int) -> int:
     return padded
 
 
+def pack_batch(msgs: Sequence[bytes], sigs: Sequence[bytes],
+               verkeys: Sequence[bytes]):
+    """Host half of a launch: bytes → host arrays padded to the lane
+    count the launch will run. → (arrays, valid_host_bools, n); arrays
+    is None for an empty batch.
+
+    The batch axis is padded to the next power of two (min 8) by
+    repeating row 0 so every size in [1, 2^k] shares one compiled
+    kernel — variable pool queue depths must not trigger XLA
+    recompiles; batches clearing the mesh gate (ops/mesh.py) are
+    bucket-padded per device instead."""
+    n = len(msgs)
+    if n == 0:
+        return None, np.zeros(0, dtype=bool), 0
+    arrays, valid = host_pack(msgs, sigs, verkeys)
+    from plenum_tpu.ops import mesh as mesh_mod
+    padded = launch_lanes(n)
+    _tmy.get_seam_hub().record_launch(
+        _tmy.SEAM_ED25519, n, padded, shape=padded)
+    if mesh_mod.get_mesh().should_shard(n):
+        arrays = mesh_mod.pad_rows(arrays, padded)
+    elif padded != n:
+        arrays = [np.concatenate(
+            [a, np.repeat(a[:1], padded - n, axis=0)], axis=0)
+            for a in arrays]
+    return arrays, valid, n
+
+
+def launch_packed(arrays, n: int):
+    """Device half: transfer `pack_batch`'s arrays and enqueue the
+    kernel; returns its un-awaited ok array (None for an empty batch).
+
+    Multi-chip: batches clearing the mesh gate are launched as one
+    batch-axis-sharded SPMD program over every chip (zero
+    collectives). The mesh path runs the XLA kernel: it SPMD-partitions
+    over the batch axis with no code change, whereas the Pallas kernel
+    is a per-chip program (its per-device halves still run the winning
+    tile grid when each shard fills a block)."""
+    if arrays is None:
+        return None
+    from plenum_tpu.ops import mesh as mesh_mod
+    m = mesh_mod.get_mesh()
+    if m.should_shard(n):
+        return m.dispatch(_verify_kernel, arrays, n=n)
+    m.note_passthrough(n)
+    return _dispatch_kernel(*arrays)
+
+
 def verify_batch_async(msgs: Sequence[bytes], sigs: Sequence[bytes],
                        verkeys: Sequence[bytes]):
     """Non-blocking batched verify: enqueues the device computation and
     returns (ok_device_array, valid_host_bools, n) immediately — JAX
     dispatch is async, so the caller overlaps host work with the device
     round trip and materializes later (np.asarray(ok)[:n] & valid).
-
-    Multi-chip: batches clearing the mesh gate (ops/mesh.py) are
-    bucket-padded per device and launched as one batch-axis-sharded
-    SPMD program over every chip (zero collectives); otherwise the
-    single-device path below is unchanged."""
-    n = len(msgs)
-    if n == 0:
-        return None, np.zeros(0, dtype=bool), 0
-    arrays, valid = host_pack(msgs, sigs, verkeys)
-    from plenum_tpu.ops import mesh as mesh_mod
-    m = mesh_mod.get_mesh()
-    padded = launch_lanes(n)
-    _tmy.get_seam_hub().record_launch(
-        _tmy.SEAM_ED25519, n, padded, shape=padded)
-    if m.should_shard(n):
-        # the mesh path runs the XLA kernel: it SPMD-partitions over the
-        # batch axis with no code change, whereas the Pallas kernel is a
-        # per-chip program (its per-device halves still run the winning
-        # tile grid when each shard fills a block)
-        arrays = mesh_mod.pad_rows(arrays, padded)
-        ok = m.dispatch(_verify_kernel, arrays, n=n)
-        return ok, valid, n
-    m.note_passthrough(n)
-    # pad the batch axis to the next power of two (min 8) by repeating
-    # row 0 so every size in [1, 2^k] shares one compiled kernel —
-    # variable pool queue depths must not trigger XLA recompiles
-    if padded != n:
-        arrays = [np.concatenate(
-            [a, np.repeat(a[:1], padded - n, axis=0)], axis=0)
-            for a in arrays]
-    ok = _dispatch_kernel(*arrays)
-    return ok, valid, n
+    `pack_batch` then `launch_packed`, for callers that time neither."""
+    arrays, valid, n = pack_batch(msgs, sigs, verkeys)
+    return launch_packed(arrays, n), valid, n
 
 
 # Backend selection: the Pallas whole-verify kernel (its VMEM-resident

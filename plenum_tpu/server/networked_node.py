@@ -22,10 +22,16 @@ from plenum_tpu.runtime.timer import QueueTimer
 from plenum_tpu.network.keys import NodeKeys
 from plenum_tpu.network.stack import (
     HA, ClientStack, NodeStack, RemoteInfo)
+from plenum_tpu.observability.tracing import CAT_TRANSPORT
 from plenum_tpu.server.node import Node
 from plenum_tpu.utils.metrics import MetricsName
 
 logger = logging.getLogger(__name__)
+
+
+def _no_clock() -> float:
+    """The prod tick's clock while its tracer is disarmed: no read."""
+    return 0.0
 
 
 class NetworkedNode(Prodable):
@@ -193,6 +199,16 @@ class NetworkedNode(Prodable):
     async def prod(self, limit: int = None) -> int:
         """One tick (reference node.py:1037): rx quotas → consensus →
         timer → lifecycle → flush."""
+        # flight recorder: the tick's envelope (prod_tick: its exclusive
+        # time is the work no stage span covers) and its three socket
+        # seams are recorded at the end, and only if the tick did
+        # something — a node polls a hundred times a second when idle,
+        # and those ticks would fill the ring with empty spans
+        tracer = self.node.tracer
+        traced = tracer.enabled
+        now = tracer.now if traced else _no_clock
+        pending_at_start = self._pending_auth
+        t_tick = now()
         # harvest a landed verification batch before taking new work
         if self._pending_auth is not None and \
                 self.node.client_batch_ready(self._pending_auth):
@@ -205,21 +221,38 @@ class NetworkedNode(Prodable):
         if self.nodestack.metrics is not metrics:
             self.nodestack.metrics = metrics
             self.clientstack.metrics = metrics
+        t_rx = now()
         with metrics.measure_time(MetricsName.NODE_RX_TIME):
-            c = self.nodestack.service(
+            c = from_nodes = self.nodestack.service(
                 self._on_node_wire_msg,
                 quota=self.config.NODE_TO_NODE_STACK_QUOTA,
                 size_quota=self.config.NODE_TO_NODE_STACK_SIZE)
+        t_client = now()
         with metrics.measure_time(MetricsName.CLIENT_RX_TIME):
-            c += self._collect_client_msgs()
+            from_clients = self._collect_client_msgs()
+            c += from_clients
+        t_service = now()
         c += self.node.service()
         with metrics.measure_time(MetricsName.TIMER_SERVICE_TIME):
             c += self.timer.service()
         with metrics.measure_time(MetricsName.LIFECYCLE_TIME):
             self.nodestack.service_lifecycle()
+        t_flush = now()
         with metrics.measure_time(MetricsName.TRANSPORT_FLUSH_TIME):
             flushed = self.nodestack.flush_outboxes()
-            self.clientstack.flush_client_outboxes()
+            to_clients = self.clientstack.flush_client_outboxes()
         if flushed:
             metrics.add_event(MetricsName.TRANSPORT_BATCH_SIZE, flushed)
+        # a verification batch harvested or dispatched is work too
+        if traced and (c or flushed or to_clients
+                       or self._pending_auth is not pending_at_start):
+            t_end = now()
+            tracer.complete("prod_tick", CAT_TRANSPORT, t_tick, t_end,
+                            produced=c)
+            tracer.complete("node_rx", CAT_TRANSPORT, t_rx, t_client,
+                            messages=from_nodes)
+            tracer.complete("client_rx", CAT_TRANSPORT, t_client,
+                            t_service, messages=from_clients)
+            tracer.complete("transport_flush", CAT_TRANSPORT, t_flush,
+                            t_end, frames=flushed + to_clients)
         return c

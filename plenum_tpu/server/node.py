@@ -176,12 +176,20 @@ class Node:
         # flight recorder (observability/): one ring-buffer tracer per
         # node, injected into every instrumented stage below so a 3PC
         # batch's whole lifecycle lands in one per-node buffer that the
-        # sim pool / trace_view merges into a pool-wide timeline
+        # sim pool / trace_view merges into a pool-wide timeline. ONE
+        # Tracer always, armed from the start iff TRACING_ENABLED:
+        # every component keeps this reference, so arming it later (a
+        # host trace session, _on_verifier_control) needs no
+        # re-injection, and a disarmed one costs what NullTracer does
         if tracer is None:
             tracer = Tracer(name=name,
-                            capacity=self.config.TRACING_BUFFER_SPANS) \
-                if self.config.TRACING_ENABLED else NullTracer(name)
+                            capacity=self.config.TRACING_BUFFER_SPANS,
+                            armed=bool(self.config.TRACING_ENABLED))
         self.tracer = tracer
+        # directory of the host trace session this node was told of
+        # (the verify daemon's id-0 control frame); the dump is written
+        # there at clean stop (write_trace_dump), never before
+        self.trace_session_dir: Optional[str] = None
         # always-on telemetry plane (observability/telemetry.py): one
         # hub per node — latency histograms on the ordered money path,
         # pool-health gauges, recovery counters. Device-seam lane
@@ -469,10 +477,16 @@ class Node:
         # journey plane: outgoing envelopes carry an advisory causal
         # stamp only when this node is traced AND the config gate is on
         # — an untraced node has no buffers for journeys to join, so
-        # stamping it would be pure wire bytes
+        # stamping it would be pure wire bytes. Settled HERE, once: a
+        # tracer armed at runtime turns on spans only (stamps change
+        # envelope bytes). The per-request instants only the journey
+        # join reads (request_accepted, propagate_quorum, wire_send,
+        # wire_recv) follow it: at tens of thousands of writes a window
+        # they alone would overrun the ring the per-batch spans fit in
         _trace_ctx = bool(getattr(self.config, "TRACE_CONTEXT_ENABLED",
                                   True)) \
             and getattr(self.tracer, "enabled", False)
+        self._trace_ctx = _trace_ctx
         self.propagator.trace_context = _trace_ctx
         if self._outbox_3pc is not None:
             self._outbox_3pc.trace_context = _trace_ctx
@@ -489,8 +503,12 @@ class Node:
             # device-dispatch profiling inside the CoalescingVerifierHub
             # (a hub shared across co-resident nodes keeps whichever
             # tracer was attached last — one buffer still sees every
-            # fused launch)
+            # fused launch) and the RemoteVerifier's blocking read
             verifier.tracer = self.tracer
+        if verifier is not None and hasattr(verifier, "on_control"):
+            # the verify daemon's control frames (id 0): a host trace
+            # session arms this node's tracer
+            verifier.on_control = self._on_verifier_control
         if getattr(self.tracer, "enabled", False):
             # mesh_dispatch spans + per-device counters land in the same
             # buffer (process-wide mesh: last tracer attached wins, like
@@ -1205,7 +1223,8 @@ class Node:
         key = request.key
         # lifecycle root: everything downstream (propagate quorum, 3PC,
         # reply) correlates back to this digest on the merged timeline
-        self.tracer.instant("request_accepted", CAT_INTAKE, key=key)
+        if self._trace_ctx:
+            self.tracer.instant("request_accepted", CAT_INTAKE, key=key)
         if self.telemetry.enabled:
             # intake→reply latency start mark; a full map (pool deeply
             # backlogged) degrades to counting the drop, never growing
@@ -1968,6 +1987,41 @@ class Node:
             self._catchup_started_at = None
         logger.info("%s catchup finished; last_ordered=%s", self.name,
                     self.replica.data.last_ordered_3pc)
+
+    # ======================================================= trace session
+
+    def _on_verifier_control(self, msg) -> None:
+        """A control frame from the verify daemon (id 0). One daemon
+        started with --trace-file opens a trace session for the whole
+        host: ``{"trace": {"dir": ...}}`` arms this node's recorder and
+        names where its spans go at clean stop. Spans only: the
+        wire-carried stamps stay as the config settled them."""
+        session = msg.get("trace") if isinstance(msg, dict) else None
+        if isinstance(session, dict) and isinstance(
+                session.get("dir"), str):
+            self.trace_session_dir = session["dir"]
+            self.tracer.arm()
+            logger.info("%s: trace session opened by the verify daemon "
+                        "(%s)", self.name, self.trace_session_dir)
+
+    def write_trace_dump(self) -> Optional[str]:
+        """Clean stop of a node told of a trace session: write the ring
+        as ``<dir>/node_<Name>_spans.json`` — that one name, only into
+        a directory that exists, and nothing if no session was opened
+        or nothing was recorded. → the path written, or None."""
+        directory = self.trace_session_dir
+        if directory is None or not self.tracer.enabled \
+                or not os.path.isdir(directory):
+            return None
+        from plenum_tpu.observability.export import export_chrome_trace
+        name = "".join(c for c in self.name if c.isalnum() or c in "_-")
+        path = os.path.join(directory, "node_%s_spans.json" % name)
+        t0 = time.perf_counter()
+        export_chrome_trace([self.tracer], path)
+        logger.info("%s: wrote %d spans to %s in %.3fs", self.name,
+                    self.tracer.stats()["buffered"], path,
+                    time.perf_counter() - t0)
+        return path
 
     # ========================================================== helpers
 

@@ -352,12 +352,16 @@ class Propagator:
     # ---------------------------------------------------------- receiving
 
     def process_propagate(self, msg: Propagate, frm: str):
-        with self.metrics.measure_time(MetricsName.PROPAGATE_PROCESS_TIME):
+        with self.metrics.measure_time(MetricsName.PROPAGATE_PROCESS_TIME), \
+                self.tracer.span("propagate_process", CAT_PROPAGATE,
+                                 n=1, frm=frm):
             self._process_one(msg.request, msg.senderClient, frm)
 
     def process_propagate_batch(self, msg: PropagateBatch, frm: str):
         self.note_wire_stamp(getattr(msg, "traceCtx", None), frm)
-        with self.metrics.measure_time(MetricsName.PROPAGATE_PROCESS_TIME):
+        with self.metrics.measure_time(MetricsName.PROPAGATE_PROCESS_TIME), \
+                self.tracer.span("propagate_process", CAT_PROPAGATE,
+                                 n=len(msg.requests), frm=frm):
             self._process_propagate_batch(msg, frm)
 
     def note_wire_stamp(self, ctx, frm: str) -> None:
@@ -411,7 +415,9 @@ class Propagator:
         re-sort on the receive path. Finalisation stays columnar: all
         requests reaching quorum inside this envelope forward as one
         contiguous digest column."""
-        with self.metrics.measure_time(MetricsName.PROPAGATE_PROCESS_TIME):
+        with self.metrics.measure_time(MetricsName.PROPAGATE_PROCESS_TIME), \
+                self.tracer.span("propagate_process", CAT_PROPAGATE,
+                                 n=cols.n, frm=frm):
             self._process_propagate_columns(cols, frm)
 
     def _process_propagate_columns(self, cols, frm: str):
@@ -452,14 +458,21 @@ class Propagator:
                 logger.warning("%s: malformed PROPAGATE payload from %s "
                                "— ignored", self.name, frm)
                 return
-            if self._authenticator is not None \
-                    and not self._authenticator(request):
-                logger.warning(
-                    "%s: PROPAGATE from %s fails authentication "
-                    "(identifier=%s reqId=%s) — ignored, not echoed",
-                    self.name, frm, payload.get("identifier"),
-                    payload.get("reqId"))
-                return
+            if self._authenticator is not None:
+                # a relayed request that beat the client's own copy
+                # here is authenticated alone and inline, on the prod
+                # thread: one span a call, so their number and cost
+                # can be read off a dump
+                with self.tracer.span("propagate_auth_single",
+                                      CAT_PROPAGATE):
+                    authentic = self._authenticator(request)
+                if not authentic:
+                    logger.warning(
+                        "%s: PROPAGATE from %s fails authentication "
+                        "(identifier=%s reqId=%s) — ignored, not echoed",
+                        self.name, frm, payload.get("identifier"),
+                        payload.get("reqId"))
+                    return
             state = self.requests.add(request)
         propagates = state.propagates
         n0 = len(propagates)
@@ -497,10 +510,12 @@ class Propagator:
         PROPAGATE_BATCH)."""
         state.finalised = True
         state.forwarded = True
-        self.tracer.instant("propagate_quorum", CAT_PROPAGATE,
-                            key=state.request.key,
-                            votes=len(state.propagates),
-                            closer=closer or self.name)
+        if self.trace_context:
+            # read by the journey join alone, like the wire stamps
+            self.tracer.instant("propagate_quorum", CAT_PROPAGATE,
+                                key=state.request.key,
+                                votes=len(state.propagates),
+                                closer=closer or self.name)
         if sink is not None:
             sink.append(state)
         else:

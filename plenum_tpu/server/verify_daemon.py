@@ -17,6 +17,15 @@ Wire protocol (both directions): 4-byte little-endian length prefix +
 msgpack payload.
   request : [req_id, [[msg, sig, vk], ...]]
   response: [req_id, results_bytes]   (one 0/1 byte per item)
+Control, off the verification path (request ids start at 1):
+  [0, {"trace": {"dir": D}}]  daemon → every connection, once, on accept,
+      when started with --trace-file: a trace session for the whole
+      host. A node arms its flight recorder and writes
+      D/node_<Name>_spans.json at its clean stop, beside the daemon's
+      own file (server/node.py); other clients drop the frame.
+  [req_id, "stats"]  client → daemon: answered at once by the
+      connection handler with [req_id, stats() as JSON bytes]; never
+      queued behind a batch (``--stats`` prints it).
 
 Out-of-band (no protocol): started with a device backend, the daemon
 initializes its device BEFORE serving and states what it got — once in
@@ -77,6 +86,11 @@ class VerifyDaemon:
         self.port = port
         self._backend_name = backend
         self._verifier = create_verifier(backend)
+        # the provider whose dispatch packs and launches on the device
+        # (the adaptive one routes to it above its threshold): the
+        # batcher hands it the tracer and each batch's span args
+        self._device_verifier = getattr(
+            self._verifier, "device_provider", self._verifier)
         self._floor_verifier = OpenSSLVerifier()
         self._bucket = Config.VERIFY_DAEMON_BUCKET \
             if bucket is None else bucket
@@ -108,7 +122,9 @@ class VerifyDaemon:
         self.batch_sizes = {}     # pow2 bucket -> coalesced batches
         # flight recorder: the daemon runs in its own process, so it
         # gets its own tracer (attach a real one + trace_file to dump
-        # Perfetto timelines of coalescing vs device round trips)
+        # Perfetto timelines of coalescing vs device round trips at
+        # stop). A trace file also opens the host's trace session:
+        # every connection is told its directory on accept
         self.tracer = NullTracer("verify-daemon")
         self.trace_file = None
 
@@ -232,10 +248,18 @@ class VerifyDaemon:
                                 first_call=first_call)
         return out[:len(items)]
 
+    @staticmethod
+    def _send(writer: asyncio.StreamWriter, req_id: int, body) -> None:
+        frame = msgpack.packb([req_id, body], use_bin_type=True)
+        writer.write(LEN.pack(len(frame)) + frame)
+
     async def _handle_conn(self, reader: asyncio.StreamReader,
                            writer: asyncio.StreamWriter):
         self._writers.add(writer)
         try:
+            if self.trace_file is not None:
+                self._send(writer, 0, {"trace": {"dir": os.path.dirname(
+                    os.path.abspath(self.trace_file))}})
             while True:
                 hdr = await reader.readexactly(4)
                 (n,) = LEN.unpack(hdr)
@@ -252,7 +276,16 @@ class VerifyDaemon:
                     logger.warning("undecodable frame; closing",
                                    exc_info=True)
                     break
-                await self._queue.put((writer, req_id, items))
+                if items == "stats":
+                    # live counters, answered here: a control request
+                    # never waits in the batcher's queue
+                    self._send(writer, req_id,
+                               json.dumps(self.stats()).encode())
+                    continue
+                # stamped as read: the batch's verify_queue_wait span
+                # starts at its oldest frame's stamp
+                await self._queue.put(
+                    (writer, req_id, items, self.tracer.now()))
         except (asyncio.IncompleteReadError, ConnectionError):
             pass
         finally:
@@ -284,10 +317,9 @@ class VerifyDaemon:
                     except asyncio.TimeoutError:
                         break
                 _csp.add(requests=len(batch))
-            self.tracer.counter("verify_queue_depth", self._queue.qsize())
             all_items: List[Tuple[bytes, bytes, bytes]] = []
             spans = []
-            for _, _, items in batch:
+            for _, _, items, _ in batch:
                 lo = len(all_items)
                 try:
                     all_items.extend(
@@ -310,15 +342,26 @@ class VerifyDaemon:
             t_launch = loop.time()
             logger.debug("batch: %d items (%d unique) from %d requests",
                         len(all_items), len(order), len(batch))
+            # what every span of this batch carries; the device
+            # provider's pack/launch/collect spans (crypto/
+            # batch_verifier.py) are recorded from the worker thread
+            # inside device_verify. One batch is in flight at a time
+            # (the await below), so the attributes cannot mix batches
+            span_args = {"items": len(all_items), "unique": len(order),
+                         "requests": len(batch)}
+            self._device_verifier.tracer = self.tracer
+            self._device_verifier.span_args = span_args
+            # the oldest frame's wait: read off the socket → here
+            self.tracer.complete("verify_queue_wait", CAT_DEVICE,
+                                 batch[0][3], self.tracer.now(),
+                                 **span_args)
             try:
                 # this span IS the device round trip as the loop sees it
                 # (the worker thread serializes launches, so a deep span
                 # here means the NEXT batch coalesced under it — exactly
                 # the pipelining the timeline should show)
                 with self.tracer.span("device_verify", CAT_DEVICE,
-                                      items=len(all_items),
-                                      unique=len(order),
-                                      requests=len(batch)):
+                                      **span_args):
                     uniq_results = await loop.run_in_executor(
                         self._pool, self._verify_bucketed, order)
                 results = [uniq_results[i] for i in index]
@@ -334,14 +377,13 @@ class VerifyDaemon:
             size_bucket = 1 << max(0, len(order) - 1).bit_length()
             self.batch_sizes[size_bucket] = \
                 self.batch_sizes.get(size_bucket, 0) + 1
-            for (writer, req_id, _), (lo, cnt) in zip(batch, spans):
+            for (writer, req_id, _, _), (lo, cnt) in zip(batch, spans):
                 body = bytes(bytearray(
                     1 if results[lo + i] else 0 for i in range(cnt)))
-                frame = msgpack.packb([req_id, body], use_bin_type=True)
                 try:
                     if writer.transport.is_closing():
                         continue
-                    writer.write(LEN.pack(len(frame)) + frame)
+                    self._send(writer, req_id, body)
                     # bounded buffering without stalling the batcher on
                     # one slow peer: a connection whose response backlog
                     # passes the high-water mark is aborted (abort, not
@@ -359,13 +401,6 @@ class VerifyDaemon:
                         writer.transport.abort()
                 except Exception:
                     pass
-            if self.trace_file is not None and self.launches % 25 == 0:
-                # periodic (SIGTERM skips stop()), AFTER the replies are
-                # written and on a side thread: serializing 64k ring
-                # records must neither hold back computed results nor
-                # stall the event loop's frame reads — either would
-                # distort the very latencies being traced
-                await loop.run_in_executor(None, self._dump_trace)
 
 
 def wait_ready(path: str, proc=None, timeout: float = 180.0) -> dict:
@@ -458,9 +493,22 @@ def main():  # pragma: no cover - exercised via subprocess in bench
                          "port, backend, device facts, compile cache")
     ap.add_argument("--trace-file", default=None,
                     help="record coalesce/device spans and dump a "
-                         "Chrome trace-event JSON here (periodically "
-                         "and on clean stop)")
+                         "Chrome trace-event JSON here on clean stop; "
+                         "also opens a trace session for every node "
+                         "that connects, whose spans land in the same "
+                         "directory as node_<Name>_spans.json")
+    ap.add_argument("--stats", action="store_true",
+                    help="print the counters of the daemon running at "
+                         "--host/--port as one JSON line and exit")
     args = ap.parse_args()
+    if args.stats:
+        from plenum_tpu.crypto.remote_verifier import RemoteVerifier
+        rv = RemoteVerifier((args.host, args.port), timeout=5.0)
+        try:
+            print(json.dumps(rv.daemon_stats()), flush=True)
+        finally:
+            rv.close()
+        return
     logging.basicConfig(level=logging.INFO)
     if args.backend != "cpu":
         # persistent XLA compile cache (the one setter: honours

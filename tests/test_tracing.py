@@ -32,6 +32,60 @@ def _ticking_clock(step=0.001):
     return clock
 
 
+def test_disarmed_tracer_is_free_and_arms_without_reinjection():
+    """Disarmed, a Tracer is what NullTracer is: the shared null
+    context, nothing recorded, no ring. A component that was handed it
+    once records after arm() with no second injection."""
+    from plenum_tpu.observability import tracing
+    from plenum_tpu.server.propagator import Propagator
+
+    tracer = Tracer("n1", capacity=8, clock=_ticking_clock(), armed=False)
+    assert not tracer.armed and not tracer.enabled
+    assert tracer.span("s", CAT_3PC, key="k", n=1) is tracing._NULL_CTX
+    assert tracer.span("s") is NullTracer().span("s")
+    tracer.instant("i", CAT_3PC)
+    tracer.counter("c", 3)
+    tracer.complete("x", CAT_3PC, 0.0, 1.0)
+    assert tracer.spans() == [] and tracer._buf == []
+    assert tracer.stats() == {"enabled": False, "capacity": 8,
+                              "recorded": 0, "buffered": 0, "dropped": 0}
+    assert tracer.oldest_written_at() is None
+
+    component = Propagator.__new__(Propagator)   # any holder will do
+    component.tracer = tracer
+    with component.tracer.span("before", CAT_3PC):
+        pass
+    tracer.arm()
+    assert tracer.armed and tracer.enabled and len(tracer._buf) == 8
+    with component.tracer.span("after", CAT_3PC):
+        pass
+    assert [r[1] for r in tracer.spans()] == ["after"]
+    tracer.disarm()
+    tracer.instant("late", CAT_3PC)
+    assert [r[1] for r in tracer.spans()] == ["after"]   # kept, not grown
+    tracer.arm()                                     # same ring
+    tracer.instant("again", CAT_3PC)
+    assert [r[1] for r in tracer.spans()] == ["after", "again"]
+
+
+def test_complete_records_a_span_the_caller_measured():
+    tracer = Tracer("n1", capacity=4, clock=_ticking_clock())
+    t0 = tracer.now()
+    t1 = tracer.now()
+    tracer.complete("prod_tick", "transport", t0, t1, key="k", produced=3)
+    assert tracer.spans() == [
+        ("X", "prod_tick", "transport", t0, t1, "k", {"produced": 3})]
+    assert tracer.oldest_written_at() == t1
+    # after a wrap the oldest survivor moves on
+    for i in range(5):
+        tracer.complete("s%d" % i, "transport", 10.0 + i, 10.5 + i)
+    assert tracer.oldest_written_at() == 11.5
+    assert NullTracer().complete("x", "transport", 0.0, 1.0) is None
+    assert NullTracer().now() == 0.0
+    [ev] = [e for e in trace_events([tracer]) if e["name"] == "s4"]
+    assert (ev["ph"], ev["ts"], ev["dur"]) == ("X", 14000000, 500000)
+
+
 def test_ring_buffer_wraparound_keeps_newest():
     tracer = Tracer("n1", capacity=8, clock=_ticking_clock())
     for i in range(20):
@@ -221,6 +275,100 @@ def test_tracing_disabled_pool_records_nothing(mock_timer):
     assert chrome_trace(pool_tracers(nodes))["traceEvents"] == []
 
 
+def test_node_arms_on_a_trace_session_and_dumps_only_when_told(
+        mock_timer, tdir):
+    """A node whose config leaves tracing off holds a disarmed Tracer.
+    Told of a session (the verify daemon's id-0 frame) it records spans
+    through the references injected at construction, never stamps the
+    wire, touches no file while it runs, and writes exactly
+    node_<Name>_spans.json when told to stop."""
+    mock_timer.set_time(1600000000)
+    net = SimNetwork(mock_timer, DefaultSimRandom(13))
+    conf = Config(Max3PCBatchSize=10, Max3PCBatchWait=0.2, CHK_FREQ=5,
+                  LOG_SIZE=15)
+    nodes = [Node(n, NAMES, mock_timer, net.create_peer(n), config=conf,
+                  client_reply_handler=lambda c, m: None)
+             for n in NAMES]
+    alpha, beta = nodes[0], nodes[1]
+    assert isinstance(alpha.tracer, Tracer) and not alpha.tracer.armed
+    assert alpha.write_trace_dump() is None          # no session yet
+    session = os.path.join(tdir, "session")
+    os.mkdir(session)
+    alpha._on_verifier_control({"trace": {"dir": session}})
+    beta._on_verifier_control({"trace": {"dir": session + "-gone"}})
+    for junk in (None, "trace", {"trace": 1}, {"trace": {"dir": 7}}):
+        nodes[2]._on_verifier_control(junk)
+    assert alpha.tracer.armed and beta.tracer.armed
+    assert not nodes[2].tracer.armed
+    assert alpha.propagator.tracer is alpha.tracer
+    _order_one_batched(nodes, mock_timer)
+    assert all(n.domain_ledger.size >= 1 for n in nodes)
+    assert os.listdir(session) == []                 # nothing before stop
+    names = {r[1] for r in alpha.tracer.spans()}
+    assert {"auth_dispatch", "propagate_flush", "batch_apply", "order",
+            "reply"} <= names
+    # spans only: the stamps follow the config at start, and with them
+    # the per-request instants only the journey join reads
+    assert not alpha.propagator.trace_context
+    assert not names & {"request_accepted", "propagate_quorum",
+                        "wire_send", "wire_recv"}
+    assert nodes[2].tracer.spans() == []
+    path = alpha.write_trace_dump()
+    assert path == os.path.join(session, "node_Alpha_spans.json")
+    assert os.listdir(session) == ["node_Alpha_spans.json"]
+    assert beta.write_trace_dump() is None           # no such directory
+    assert not os.path.exists(session + "-gone")
+    with open(path) as f:
+        doc = json.load(f)
+    meta = doc["metadata"]["Alpha"]
+    assert meta["stats"]["recorded"] == len(alpha.tracer.spans())
+    assert meta["stats"]["dropped"] == 0
+    assert meta["clock"]["name"] == "perf_counter"
+    assert meta["oldest_ts"] == min(
+        e["ts"] + e.get("dur", 0) for e in doc["traceEvents"]
+        if e["ph"] != "M")
+
+
+def test_run_node_hands_out_the_dump_in_its_finally():
+    """bootstrap.run_node writes the dump after the stacks stop, also
+    when the loop is cancelled (what SIGINT does under asyncio.run)."""
+    import asyncio
+    from plenum_tpu.bootstrap import run_node
+    calls = []
+
+    class _Stack:
+        def __init__(self, name):
+            self.name = name
+
+        async def stop(self):
+            calls.append("stop " + self.name)
+
+    class _Core:
+        def write_trace_dump(self):
+            calls.append("dump")
+
+    class _Node:
+        name = "Alpha"
+        node = _Core()
+        nodestack, clientstack = _Stack("nodes"), _Stack("clients")
+
+        async def start_async(self):
+            calls.append("start")
+
+        async def prod(self):
+            return 0
+
+    async def main():
+        task = asyncio.ensure_future(run_node(_Node()))
+        await asyncio.sleep(0.05)
+        task.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await task
+
+    asyncio.run(main())
+    assert calls == ["start", "stop nodes", "stop clients", "dump"]
+
+
 def test_validator_info_reports_tracing_stats(traced_pool):
     from plenum_tpu.server.validator_info import ValidatorNodeInfoTool
     nodes, timer = traced_pool
@@ -331,6 +479,38 @@ def test_budget_exclusive_time_and_per_request_math():
     assert per_req["total"] == pytest.approx(13.5, abs=0.01)
 
 
+def test_budget_files_transport_and_the_tick_envelope():
+    """The served node's socket seams are the transport stage; what is
+    left of a productive tick once every span inside it is taken off is
+    `untraced`; a blocking wait on the daemon inside an inline
+    authentication is dispatch_wait, not propagate."""
+    from plenum_tpu.observability.budget import (
+        STAGES, budget_from_chrome, budget_from_tracers, stage_of)
+    assert "transport" in STAGES and "untraced" in STAGES
+    assert stage_of("node_rx", "transport") == "transport"
+    assert stage_of("prod_tick", "transport") == "untraced"
+    assert stage_of("verify_wait", "device") == "dispatch_wait"
+    tracer, t = _manual_tracer()
+    tracer.complete("prod_tick", "transport", 0.0, 0.1, produced=5)
+    tracer.complete("node_rx", "transport", 0.01, 0.03, messages=4)
+    tracer.complete("client_rx", "transport", 0.03, 0.04, messages=1)
+    _span(tracer, t, "propagate_process", "propagate", 0.012, 0.028,
+          n=3, frm="Beta")
+    _span(tracer, t, "propagate_auth_single", "propagate", 0.014, 0.02)
+    _span(tracer, t, "verify_wait", "device", 0.015, 0.019, n=1)
+    _span(tracer, t, "batch_apply", "execute", 0.05, 0.07, batch_size=5)
+    tracer.complete("transport_flush", "transport", 0.09, 0.1, frames=2)
+    for report in (budget_from_tracers([tracer]),
+                   budget_from_chrome(chrome_trace([tracer]))):
+        ms = report["stage_ms_per_node"]
+        assert ms["transport"] == pytest.approx(4 + 10 + 10, abs=0.01)
+        assert ms["propagate"] == pytest.approx(10 + 2, abs=0.01)
+        assert ms["dispatch_wait"] == pytest.approx(4, abs=0.01)
+        assert ms["execute"] == pytest.approx(20, abs=0.01)
+        assert ms["untraced"] == pytest.approx(100 - 60, abs=0.01)
+        assert sum(ms.values()) == pytest.approx(100, abs=0.01)
+
+
 def test_budget_from_chrome_matches_live_tracers(tdir):
     """The exported-file path (scripts/trace_budget) and the live
     path (bench.py) agree on the same spans."""
@@ -372,6 +552,42 @@ def test_trace_budget_cli(tdir):
          "--json"], capture_output=True, text=True)
     assert miss.returncode == 0
     assert "error" in json.loads(miss.stdout)
+
+
+def test_trace_budget_merges_a_session_directory(tdir):
+    """A host trace session leaves one dump per node beside the
+    daemon's: given the directory, trace_budget merges the node dumps
+    (each numbered its own pid 1) and leaves the daemon's out."""
+    import subprocess
+    import sys as _sys
+    from plenum_tpu.observability.export import merge_trace_documents
+    docs = []
+    for name, apply_s in (("Alpha", 0.05), ("Beta", 0.03)):
+        tracer, t = _manual_tracer(name)
+        _span(tracer, t, "batch_apply", "execute", 0.0, apply_s,
+              batch_size=4)
+        tracer.complete("node_rx", "transport", 0.1, 0.11, messages=2)
+        path = export_chrome_trace([tracer], os.path.join(
+            tdir, "node_%s_spans.json" % name))
+        with open(path) as f:
+            docs.append(json.load(f))
+    daemon, t = _manual_tracer("verify-daemon")
+    _span(daemon, t, "device_verify", "device", 0.0, 9.0, unique=600)
+    export_chrome_trace([daemon], os.path.join(tdir, "daemon_spans.json"))
+    merged = merge_trace_documents(docs)
+    assert sorted(merged["metadata"]) == ["Alpha", "Beta"]
+    assert {e["pid"] for e in merged["traceEvents"]} == {1, 2}
+    script = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "trace_budget")
+    out = subprocess.run([_sys.executable, script, tdir, "--json"],
+                         capture_output=True, text=True)
+    assert out.returncode == 0, out.stderr
+    report = json.loads(out.stdout)
+    assert (report["nodes"], report["ordered_reqs"]) == (2, 4)
+    ms = report["stage_ms_per_node"]
+    assert ms["execute"] == pytest.approx(40.0, abs=0.1)
+    assert ms["transport"] == pytest.approx(10.0, abs=0.1)
+    assert ms["dispatch_wait"] == 0.0
 
 
 # ------------------------------------------------------- dual clocks

@@ -5,6 +5,13 @@ Tests run the daemon in-process on the CPU backend — the wire protocol,
 coalescing, and pipelining are what's under test, not the kernel.
 """
 import asyncio
+import json
+import os
+import signal
+import subprocess
+import sys
+
+import pytest
 
 from plenum_tpu.common.config import Config
 from plenum_tpu.common.constants import NYM, TARGET_NYM, VERKEY
@@ -13,7 +20,8 @@ from plenum_tpu.crypto.signer import SimpleSigner
 from plenum_tpu.network.keys import NodeKeys
 from plenum_tpu.network.stack import HA, ClientConnection, RemoteInfo
 from plenum_tpu.server.networked_node import NetworkedNode
-from plenum_tpu.server.verify_daemon import VerifyDaemon
+from plenum_tpu.observability.tracing import Tracer
+from plenum_tpu.server.verify_daemon import VerifyDaemon, wait_ready
 
 
 def make_items(n, tamper=()):
@@ -258,14 +266,21 @@ def test_remote_verifier_tolerates_daemon_starting_late():
     asyncio.run(main())
 
 
-def test_networked_pool_orders_via_remote_daemon():
+@pytest.mark.parametrize("traced", [False, True])
+def test_networked_pool_orders_via_remote_daemon(traced, tmp_path):
     """Rung-3: a 4-node pool over real sockets with
     VERIFIER_PROVIDER=remote orders client writes through the daemon —
-    the full multi-process verification shape, in one process."""
+    the full multi-process verification shape, in one process. With a
+    trace file on the daemon the same run is a host trace session:
+    every node arms the recorder it was built with, and hands out its
+    spans when told to stop; without one no node records anything."""
     NAMES = ["Alpha", "Beta", "Gamma", "Delta"]
 
     async def main():
         daemon = VerifyDaemon(backend="cpu", window=0.001)
+        if traced:
+            daemon.tracer = Tracer("verify-daemon")
+            daemon.trace_file = str(tmp_path / "daemon_spans.json")
         await daemon.start()
         conf = Config(Max3PCBatchSize=10, Max3PCBatchWait=0.2, CHK_FREQ=5,
                       LOG_SIZE=15, HEARTBEAT_FREQ=10,
@@ -302,9 +317,16 @@ def test_networked_pool_orders_via_remote_daemon():
         assert await pump(10, lambda: all(
             len(n.nodestack.connecteds) == 3 for n in nodes.values()))
 
-        client = ClientConnection(nodes["Beta"].clientstack.ha,
-                                  expected_verkey=keys["Beta"].verkey_raw)
-        await client.connect()
+        # a client broadcasts: every node gets each request from the
+        # client too, so every node talks to the daemon (and so reads
+        # its control frame)
+        clients = {}
+        for name in NAMES:
+            clients[name] = ClientConnection(
+                nodes[name].clientstack.ha,
+                expected_verkey=keys[name].verkey_raw)
+            await clients[name].connect()
+        client = clients["Beta"]
         signer = SimpleSigner(seed=b"\x31" * 32)
         N = 20
         for i in range(1, N + 1):
@@ -315,7 +337,8 @@ def test_networked_pool_orders_via_remote_daemon():
                                  else "dmn%020d" % i,
                                  VERKEY: "~dmn%018d" % i}}
             req["signature"] = signer.sign(dict(req))
-            client.send(req)
+            for conn in clients.values():
+                conn.send(req)
         # a forged one must be nacked, not ordered
         bad = {"identifier": signer.identifier, "reqId": 999,
                "protocolVersion": 2,
@@ -333,10 +356,233 @@ def test_networked_pool_orders_via_remote_daemon():
             m.get("reqId") == 999
             for m in client.rx if m.get("op") == "REQNACK")), client.rx
 
-        client.close()
+        for conn in clients.values():
+            conn.close()
         for n in nodes.values():
             await n.nodestack.stop()
             await n.clientstack.stop()
+        assert os.listdir(str(tmp_path)) == []      # no I/O before stop
+        written = [n.node.write_trace_dump() for n in nodes.values()]
         await daemon.stop()
+        return nodes, written
 
-    asyncio.run(main())
+    nodes, written = asyncio.run(main())
+    if not traced:
+        assert written == [None] * 4 and os.listdir(str(tmp_path)) == []
+        assert all(not n.node.tracer.armed and n.node.tracer.spans() == []
+                   for n in nodes.values())
+        return
+    assert sorted(os.listdir(str(tmp_path))) == sorted(
+        ["daemon_spans.json"] + ["node_%s_spans.json" % n for n in NAMES])
+    for name, path in zip(NAMES, written):
+        with open(path) as f:
+            doc = json.load(f)
+        meta = doc["metadata"][name]
+        assert "MONOTONIC" in meta["clock"]["implementation"]
+        assert meta["stats"]["dropped"] == 0 and meta["stats"]["recorded"]
+        assert meta["oldest_ts"] <= meta["clock_sync"]["perf_ts"]
+        spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+        by_name = {}
+        for e in spans:
+            by_name.setdefault(e["name"], []).append(e)
+        # the tick's envelope and its three socket seams, one each per
+        # productive tick, the seams inside the envelope
+        ticks = by_name["prod_tick"]
+        for seam in ("node_rx", "client_rx", "transport_flush"):
+            assert len(by_name[seam]) == len(ticks)
+            assert all(e["cat"] == "transport" for e in by_name[seam])
+        for tick, rx in zip(ticks, by_name["node_rx"]):
+            assert tick["ts"] <= rx["ts"] \
+                and rx["ts"] + rx["dur"] <= tick["ts"] + tick["dur"]
+        assert any(e["args"]["n"] >= 1 and e["args"]["frm"] in NAMES
+                   for e in by_name["propagate_process"])
+        # arming at runtime turns on spans, not the journey plane
+        assert not [e for e in doc["traceEvents"] if e["name"] in (
+            "request_accepted", "propagate_quorum", "wire_send",
+            "wire_recv")]
+    assert not nodes["Alpha"].node.propagator.trace_context
+
+
+# ------------------------------------------- control frames (id 0, stats)
+
+@pytest.mark.parametrize("trace_file", [False, True])
+def test_trace_session_control_frame(trace_file, tmp_path):
+    """A daemon with a trace file tells every connection, once, where
+    the host's trace session lives (frame id 0); a RemoteVerifier hands
+    it to its callback and still resolves requests in order, one without
+    a callback drops it; a daemon without a trace file sends none."""
+    async def main():
+        daemon = VerifyDaemon(backend="cpu", window=0.001)
+        if trace_file:
+            daemon.trace_file = str(tmp_path / "sub" / "daemon.json")
+        await daemon.start()
+        loop = asyncio.get_event_loop()
+
+        def run():
+            got = []
+            listening = RemoteVerifier(("127.0.0.1", daemon.port))
+            listening.on_control = got.append
+            deaf = RemoteVerifier(("127.0.0.1", daemon.port))
+            out = []
+            for rv in (listening, deaf):
+                pendings = [rv.dispatch(make_items(6, tamper={i}))
+                            for i in range(3)]
+                # harvested last to first: frames are matched by id
+                out.append([p.collect() for p in reversed(pendings)])
+                assert 0 not in rv._results and not rv._outstanding
+                rv.close()
+            return got, out
+
+        got, out = await loop.run_in_executor(None, run)
+        await daemon.stop()
+        return got, out
+
+    got, out = asyncio.run(main())
+    want = [[j != i for j in range(6)] for i in (2, 1, 0)]
+    assert out == [want, want]
+    assert got == ([{"trace": {"dir": str(tmp_path / "sub")}}]
+                   if trace_file else [])
+
+
+def test_daemon_stats_without_stopping_it():
+    """[id, "stats"] is answered by the connection handler with the
+    daemon's counters and never enters the batcher's queue; --stats
+    prints them as one line."""
+    async def main():
+        daemon = VerifyDaemon(backend="cpu", window=0.001)
+        await daemon.start()
+        loop = asyncio.get_event_loop()
+
+        def run():
+            rv = RemoteVerifier(("127.0.0.1", daemon.port))
+            try:
+                assert all(rv.verify_batch(make_items(7)))
+                live = rv.daemon_stats()
+                # still usable for verification afterwards
+                assert rv.verify_batch(make_items(3, tamper={1})) \
+                    == [True, False, True]
+            finally:
+                rv.close()
+            cli = subprocess.run(
+                [sys.executable, "-m", "plenum_tpu.server.verify_daemon",
+                 "--stats", "--port", str(daemon.port)],
+                capture_output=True, text=True, timeout=60,
+                cwd=os.path.dirname(os.path.dirname(
+                    os.path.abspath(__file__))))
+            return live, cli
+
+        live, cli = await loop.run_in_executor(None, run)
+        launches = daemon.launches
+        await daemon.stop()
+        return live, cli, launches
+
+    live, cli, launches = asyncio.run(main())
+    assert (live["served"], live["host_items"], live["launches"]) \
+        == (7, 7, 1)
+    assert launches == 2          # the two batches; no stats frame
+    assert cli.returncode == 0, cli.stderr
+    lines = cli.stdout.strip().splitlines()
+    assert len(lines) == 1
+    assert json.loads(lines[0])["served"] == 10
+
+
+def test_daemon_writes_its_trace_only_at_sigterm(tmp_path):
+    """The daemon's spans are written in stop(), which SIGTERM reaches:
+    thirty batches (the old periodic dump fired every 25) leave no
+    file, the signal does, with the final stats line on stdout."""
+    ready, trace = tmp_path / "ready.json", tmp_path / "spans.json"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "plenum_tpu.server.verify_daemon",
+         "--backend", "cpu", "--port", "0", "--window", "0.001",
+         "--ready-file", str(ready), "--trace-file", str(trace)],
+        cwd=root, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+        text=True)
+    try:
+        port = wait_ready(str(ready), proc, timeout=120)["port"]
+        rv = RemoteVerifier(("127.0.0.1", port))
+        got = []
+        rv.on_control = got.append
+        for _ in range(30):
+            assert all(rv.verify_batch(make_items(2)))
+        assert rv.daemon_stats()["launches"] == 30
+        rv.close()
+        assert got == [{"trace": {"dir": str(tmp_path)}}]
+        assert not trace.exists()
+        proc.send_signal(signal.SIGTERM)
+        out, _ = proc.communicate(timeout=60)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    assert proc.returncode == 0
+    assert json.loads(out.strip().splitlines()[-1])["launches"] == 30
+    with open(trace) as f:
+        doc = json.load(f)
+    meta = doc["metadata"]["verify-daemon"]
+    assert meta["stats"]["dropped"] == 0
+    names = [e["name"] for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert names.count("device_verify") == 30
+    assert names.count("verify_queue_wait") == 30
+    assert not [e for e in doc["traceEvents"]
+                if e["name"] == "verify_queue_depth"]
+    import inspect
+    assert "_dump_trace" not in inspect.getsource(VerifyDaemon._batcher)
+
+
+def test_daemon_inner_spans_nest_inside_device_verify():
+    """A batch over the floor on a device backend: its wait in the
+    queue ends where device_verify starts; pack, launch and collect,
+    recorded from the worker thread, lie inside device_verify; every
+    one carries the batch's items and unique."""
+    async def main():
+        daemon = VerifyDaemon(backend="tpu_batch", window=0.02, bucket=8,
+                              cpu_floor=4)
+        daemon.tracer = Tracer("verify-daemon")
+        await daemon.start()
+        loop = asyncio.get_event_loop()
+
+        def run():
+            a = RemoteVerifier(("127.0.0.1", daemon.port), timeout=300)
+            b = RemoteVerifier(("127.0.0.1", daemon.port), timeout=300)
+            items = make_items(7, tamper={2})
+            pa, pb = a.dispatch(items), b.dispatch(items + make_items(1))
+            out = pa.collect(), pb.collect()
+            small = a.verify_batch(make_items(2))     # under the floor
+            a.close()
+            b.close()
+            return out, small
+
+        (ra, rb), small = await loop.run_in_executor(None, run)
+        await daemon.stop()
+        return daemon, ra, rb, small
+
+    daemon, ra, rb, small = asyncio.run(main())
+    assert ra == [i != 2 for i in range(7)] and rb == ra + [True]
+    assert small == [True, True]
+    recs = [r for r in daemon.tracer.spans() if r[0] == "X"]
+    by_name = {}
+    for _k, name, cat, t0, t1, _key, args in recs:
+        assert cat == "device"
+        by_name.setdefault(name, []).append((t0, t1, args))
+    dv = [s for s in by_name["device_verify"] if s[2]["unique"] >= 4]
+    # the two requests coalesced (one batch) or not (two): either way
+    # each device batch has one chunk of 8 lanes
+    assert 1 <= len(dv) <= 2
+    for inner in ("verify_pack", "verify_launch", "verify_collect"):
+        assert len(by_name[inner]) == len(dv)
+    for t0, t1, args in dv:
+        assert args["items"] >= args["unique"] >= 7
+        inside = [s for name in ("verify_pack", "verify_launch",
+                                 "verify_collect")
+                  for s in by_name[name] if t0 <= s[0] and s[1] <= t1]
+        assert len(inside) == 3
+        assert all(s[2] == args for s in inside)
+        assert inside[0][1] <= inside[1][0] <= inside[1][1] <= inside[2][0]
+        waits = [s for s in by_name["verify_queue_wait"]
+                 if s[2] == args]
+        assert waits and all(w[0] <= w[1] <= t0 for w in waits)
+    # the batch under the floor took OpenSSL: a wait and a round trip,
+    # no pack, launch or collect
+    assert len(by_name["device_verify"]) == len(dv) + 1
+    assert len(by_name["verify_queue_wait"]) == len(dv) + 1
