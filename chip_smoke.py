@@ -188,12 +188,15 @@ def child_begin(sz: "Sizes" = None):
 
 def device_path_report() -> dict:
     """What every kernel check asserts afterwards: no family stepped
-    down, the Pallas registry as decided, the ed25519 block size."""
+    down, the Pallas registry as decided, the ed25519 block size; and
+    whether the ed25519 kernel was loaded from the built-kernel store."""
     from plenum_tpu.ops import ed25519_pallas as edp
+    from plenum_tpu.ops import kernel_store
     from plenum_tpu.ops import mesh as mesh_mod
     return {"step_downs": mesh_mod.step_down_counts(),
             "kernel_backends": mesh_mod.kernel_backends(),
-            "ed25519_block_r": edp.BLOCK_R}
+            "ed25519_block_r": edp.BLOCK_R,
+            "kernel_store": kernel_store.counts()}
 
 
 def run_checks(checks, monitor) -> list:

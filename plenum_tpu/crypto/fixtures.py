@@ -29,3 +29,22 @@ def make_signed_batch(count: int, seed: int = 0, unique: int = None,
     reps = (count + unique - 1) // unique
     return ((msgs * reps)[:count], (sigs * reps)[:count],
             (vks * reps)[:count])
+
+
+_L = 2 ** 252 + 27742317777372353535851937790883648493   # group order
+
+
+def make_known_answer_batch(valid: int = 16, seed: int = 0
+                            ) -> Tuple[List[bytes], List[bytes], List[bytes]]:
+    """→ (msgs, sigs, verkeys): `valid` valid signatures, then each of
+    them corrupted three ways: a flipped bit in R, ``s + L`` (the same
+    point, not canonical: RFC 8032 refuses it), another message. What a
+    kernel taken from the store (ops/kernel_store.py) has to judge as
+    the host reference does before it serves."""
+    msgs, sigs, vks = make_signed_batch(valid, seed=seed,
+                                        msg_prefix=b"known-answer")
+    flipped = [bytes([sig[0] ^ 1]) + sig[1:] for sig in sigs]
+    plus_l = [sig[:32] + (int.from_bytes(sig[32:], "little") + _L)
+              .to_bytes(32, "little") for sig in sigs]
+    return (msgs * 3 + [m + b"!" for m in msgs],
+            sigs + flipped + plus_l + sigs, vks * 4)

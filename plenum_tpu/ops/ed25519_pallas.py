@@ -428,7 +428,54 @@ def _build_verify(n_blocks: int, interpret: bool = False):
           sd, kd)
         return out.reshape(nb8 * BLOCK_L) != 0
 
-    return jax.jit(run)
+    jitted = jax.jit(run)
+    # the store engages from what can be observed, not from a knob: a
+    # compiled Mosaic kernel on a TPU. Interpret mode and the CPU
+    # backend (tests, rehearsals, served nodes) trace as they always did
+    from plenum_tpu.ops import mesh as mesh_mod
+    if interpret or mesh_mod.probe_platform() != "tpu":
+        return jitted
+    return _stored_verify(jitted, n_blocks)
+
+
+def _stored_verify(jitted, n_blocks: int):
+    """The compiled kernel out of the built-kernel store
+    (ops/kernel_store.py): every process but the first on a cache loads
+    it and pays neither this file's Python (90-125 s of tracing and
+    lowering to Mosaic on the chip host) nor a compile."""
+    import jax
+    from plenum_tpu.ops import kernel_store
+    from plenum_tpu.ops import mesh as mesh_mod
+    batch = n_blocks * BLOCK
+    specs = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+        ((batch, NLIMB), jnp.int32), ((batch,), jnp.int32),
+        ((batch, NLIMB), jnp.int32), ((batch,), jnp.int32),
+        ((batch, 8), jnp.uint32), ((batch, 8), jnp.uint32))]
+    key = kernel_store.kernel_key(
+        [__file__, edj.__file__],
+        {"n_blocks": n_blocks, "vmem_limit_bytes": VMEM_LIMIT_BYTES},
+        kernel_store.runtime_versions(),
+        mesh_mod.device_facts()["kind"])
+    return kernel_store.default_store().load_or_build(
+        "ed25519_verify-%d" % n_blocks, key,
+        lambda: jitted.lower(*specs).compile(),
+        lambda fn: known_answer(fn, batch))
+
+
+def known_answer(fn, batch: int) -> bool:
+    """One launch of a fixed batch (valid signatures and three
+    corruptions of each, crypto/fixtures.py) through `fn`, its verdicts
+    compared item for item with the host reference: what keeps "an
+    accepted signature is a valid one" true of a kernel that was loaded
+    and not built here."""
+    from plenum_tpu.crypto.batch_verifier import OpenSSLVerifier
+    from plenum_tpu.crypto.fixtures import make_known_answer_batch
+    from plenum_tpu.ops import mesh as mesh_mod
+    msgs, sigs, vks = make_known_answer_batch()
+    want = OpenSSLVerifier().verify_batch(list(zip(msgs, sigs, vks)))
+    arrays, valid = edj.host_pack(msgs, sigs, vks)
+    got = np.asarray(fn(*mesh_mod.pad_rows(arrays, batch)))
+    return (got[:len(msgs)] & valid).tolist() == want
 
 
 def verify_kernel(ay, asign, ry, rsign, s_words, k_words,

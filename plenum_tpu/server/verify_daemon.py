@@ -30,12 +30,16 @@ Control, off the verification path (request ids start at 1):
 Out-of-band (no protocol): started with a device backend, the daemon
 initializes its device BEFORE serving and states what it got — once in
 its log and in the ``--ready-file`` (one JSON object: port, backend,
-device {platform, kind, count}, compile_cache) — so the launcher knows
-which process owns the chip; a chip that cannot be initialised fails
-the start instead of serving from the CPU backend. On SIGTERM/SIGINT it
-stops cleanly and prints ONE JSON line of counters to stdout (logging
-goes to stderr): device launches and items, host (OpenSSL floor) items,
-failed batches, coalesced batch sizes, kernel-family step-downs.
+device {platform, kind, count}, compile_cache, kernel_store) — so the
+launcher knows which process owns the chip; a chip that cannot be
+initialised fails the start instead of serving from the CPU backend. On
+SIGTERM/SIGINT it stops cleanly and prints ONE JSON line of counters to
+stdout (logging goes to stderr): device launches and items, host
+(OpenSSL floor) items, failed batches, coalesced batch sizes,
+kernel-family step-downs, and ``kernel_store``: whether the ed25519
+Pallas kernel was loaded from the built-kernel store
+(ops/kernel_store.py) or built here, how long that took, and why a
+stored file was replaced.
 
 Reference equivalence: the reference verifies inline through libsodium
 (plenum/server/client_authn.py:84); this daemon is the tpu-native
@@ -197,6 +201,10 @@ class VerifyDaemon:
             out["step_downs"] = mesh_mod.step_down_counts()
             out["kernel_backends"] = mesh_mod.kernel_backends()
             out["mesh"] = mesh_mod.mesh_stats()
+            # whether this process loaded its Pallas kernel from the
+            # built-kernel store or had to trace and compile it
+            from plenum_tpu.ops import kernel_store
+            out["kernel_store"] = kernel_store.counts()
         return out
 
     # ------------------------------------------------------------ conns
@@ -437,6 +445,10 @@ async def run_daemon(host="127.0.0.1", port=0, backend="adaptive",
         ready["device"] = mesh_mod.device_facts()
         import jax
         ready["compile_cache"] = jax.config.jax_compilation_cache_dir
+        # all zeros here: the store is read inside the first launch
+        # that fills a Pallas block, which no frame has asked for yet
+        from plenum_tpu.ops import kernel_store
+        ready["kernel_store"] = kernel_store.counts()
         logger.info("verify daemon device: %s", json.dumps(ready["device"]))
     daemon = VerifyDaemon(host, port, backend, window=window,
                           bucket=bucket, cpu_floor=cpu_floor)

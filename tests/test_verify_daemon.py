@@ -530,6 +530,44 @@ def test_daemon_writes_its_trace_only_at_sigterm(tmp_path):
     assert "_dump_trace" not in inspect.getsource(VerifyDaemon._batcher)
 
 
+def test_device_daemon_states_its_kernel_store(tmp_path):
+    """A daemon on a device backend says in its ready file, in its
+    [id, "stats"] answer and in its final line whether it loaded or
+    built its Pallas kernel (ops/kernel_store.py). On the CPU platform
+    nothing reaches the store: every count reads zero."""
+    ready = tmp_path / "ready.json"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "plenum_tpu.server.verify_daemon",
+         "--backend", "tpu_batch", "--port", "0", "--window", "0.001",
+         "--bucket", "8", "--cpu-floor", "64",
+         "--ready-file", str(ready)],
+        cwd=root, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+        text=True, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    try:
+        info = wait_ready(str(ready), proc, timeout=120)
+        rv = RemoteVerifier(("127.0.0.1", info["port"]))
+        try:
+            assert rv.verify_batch(make_items(3, tamper={1})) \
+                == [True, False, True]
+            live = rv.daemon_stats()
+        finally:
+            rv.close()
+        proc.send_signal(signal.SIGTERM)
+        out, _ = proc.communicate(timeout=60)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    assert proc.returncode == 0
+    untouched = {"loaded": 0, "built": 0, "rebuilt": {}, "load_s": 0.0,
+                 "build_s": 0.0}
+    assert info["kernel_store"] == untouched and "compile_cache" in info
+    assert live["kernel_store"] == untouched
+    assert json.loads(out.strip().splitlines()[-1])["kernel_store"] \
+        == untouched
+
+
 def test_daemon_inner_spans_nest_inside_device_verify():
     """A batch over the floor on a device backend: its wait in the
     queue ends where device_verify starts; pack, launch and collect,
