@@ -10,6 +10,8 @@ import sys
 import threading
 import time
 
+import traffic
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
@@ -19,8 +21,15 @@ NODE_NAMES = ["Alpha", "Beta", "Gamma", "Delta", "Epsilon", "Zeta", "Eta",
               "Chi", "Psi", "Omega", "Aleph"]
 
 
+T_START = time.perf_counter()
+
+
 def log(*a) -> None:
-    print("[bench]", *a, file=sys.stderr, flush=True)
+    """To standard error, with the seconds since this module was
+    imported (a few tenths after the process started): the parts of
+    set-up are read off these lines."""
+    print("[bench %6.1fs]" % (time.perf_counter() - T_START), *a,
+          file=sys.stderr, flush=True)
 
 
 def tail(path, n=30) -> str:
@@ -207,21 +216,35 @@ class Pool:
         self.names = NODE_NAMES[:config["nodes"]]
         self.f = (len(self.names) - 1) // 3
         self.node_procs = {}
+        self._genesis = None
 
-    def generate(self, trustee_seed: bytes) -> dict:
+    def generate(self, seed: int) -> dict:
+        """generate_pool's pool, and after its own domain genesis lines
+        the role-less NYMs of the configuration's `genesis.identities`,
+        in the shape of the lines that are there. The nodes read the
+        file as they start; the reference reads the same file."""
         from plenum_tpu.bootstrap import generate_pool
-        return generate_pool(self.base_dir, self.names,
-                             base_port=self.base_port,
-                             trustee_seed=trustee_seed)
+        summary = generate_pool(self.base_dir, self.names,
+                                base_port=self.base_port,
+                                trustee_seed=traffic.trustee_seed(seed))
+        count = (self.config.get("genesis") or {}).get("identities", 0)
+        if count:
+            with open(os.path.join(self.base_dir,
+                                   "domain_transactions_genesis"),
+                      "a") as f:
+                f.writelines(json.dumps(
+                    traffic.identity(seed, i).genesis_nym(),
+                    sort_keys=True) + "\n" for i in range(count))
+        return summary
 
     def genesis_domain_txns(self):
-        out = []
-        with open(os.path.join(self.base_dir,
-                               "domain_transactions_genesis")) as f:
-            for line in f:
-                if line.strip():
-                    out.append(json.loads(line))
-        return out
+        """The domain genesis file as generate() left it, read once."""
+        if self._genesis is None:
+            with open(os.path.join(self.base_dir,
+                                   "domain_transactions_genesis")) as f:
+                self._genesis = [json.loads(line) for line in f
+                                 if line.strip()]
+        return self._genesis
 
     def write_config(self, daemon_port: int) -> None:
         settings = dict(self.config["node_config"])

@@ -34,7 +34,6 @@ import check  # noqa: E402
 import generators  # noqa: E402
 import operations  # noqa: E402
 import trace_reduce  # noqa: E402
-import traffic  # noqa: E402
 import window  # noqa: E402
 from client import Client, Op  # noqa: E402
 from pool import (  # noqa: E402
@@ -81,10 +80,11 @@ class Cell:
 
 # ------------------------------------------------------------ one run
 
-def make_ops(seed: int, plan: dict, traffic_file: dict):
+def make_ops(seed: int, plan: dict, traffic_file: dict, genesis=None):
     count = plan["max_ops"] if plan["kind"] == "closed" \
         else len(plan["due"])
-    made = operations.make(seed, count + 1, traffic_file["operations"])
+    made = operations.make(seed, count + 1, traffic_file["operations"],
+                           genesis)
     return [Op(req, Client.wire(req), valid) for req, valid in made]
 
 
@@ -131,17 +131,28 @@ async def drive(pool, daemon, ops, plan, seconds, traced, marks_out,
         client.close()
 
 
-def start_pool(cell, daemon, procs, workdir, seed, seconds, tiny, base_port):
-    """A fresh pool beside the daemon (which may still be warming up):
-    generated, configured, its nodes started, and the window's
-    operations signed → (pool, plan, ops)."""
+def new_pool(cell, procs, workdir, seed, tiny, base_port):
+    """A fresh pool's files: keys, and the two genesis files with the
+    configuration's identities. They need no daemon, so `single` makes
+    them while the daemon starts."""
     base_dir = tempfile.mkdtemp(prefix="pool_", dir=workdir)
     pool = Pool(procs, base_dir, cell.config, tiny, base_port)
-    pool.generate(traffic.trustee_seed(seed))
+    pool.generate(seed)
+    log("pool generated: %d domain genesis txns" % len(
+        pool.genesis_domain_txns()))
+    return pool
+
+
+def start_pool(cell, daemon, procs, workdir, seed, seconds, tiny, base_port,
+               pool=None):
+    """A fresh pool beside the daemon (which may still be warming up):
+    generated unless it is handed in, configured, its nodes started,
+    and the window's operations signed → (pool, plan, ops)."""
+    pool = pool or new_pool(cell, procs, workdir, seed, tiny, base_port)
     pool.write_config(daemon.info["port"])
     pool.start_nodes()
     plan = generators.plan(cell.traffic, seed, seconds)
-    ops = make_ops(seed, plan, cell.traffic)
+    ops = make_ops(seed, plan, cell.traffic, cell.config.get("genesis"))
     log("%d operations signed" % len(ops))
     return pool, plan, ops
 
@@ -263,15 +274,26 @@ def units_of(cell) -> dict:
             for g in ("end_to_end", "per_layer") for m in cell.bench[g]}
 
 
-def start_daemon(cell, procs, workdir, tiny, traced):
+def launch_daemon(cell, procs, workdir, tiny, traced):
     daemon = Daemon(procs, workdir, cell.config, tiny, traced)
     daemon.start()
+    return daemon
+
+
+def start_daemon(cell, procs, workdir, tiny, traced):
+    return daemon_ready(
+        launch_daemon(cell, procs, workdir, tiny, traced), cell, tiny)
+
+
+def daemon_ready(daemon, cell, tiny):
+    """Wait for a launched daemon; it has to hold the chip(s) the cell
+    asks for."""
     try:
         info = daemon.wait_ready(timeout=300)
     except RuntimeError:
         raise NoResult("the verify daemon did not start: no accelerator "
                        "here, or another process holds it\n"
-                       + tail(os.path.join(workdir, "daemon.err"), 15))
+                       + tail(os.path.join(daemon.dir, "daemon.err"), 15))
     device = info.get("device") or {}
     log("host cores %s; daemon device %s; compile cache %s" % (
         os.cpu_count(), json.dumps(device), info.get("compile_cache")))
@@ -287,12 +309,14 @@ def single(args, cell, procs, workdir) -> int:
     deadline = time.monotonic() + SETUP_BUDGET_S
     natives = native_modules()
     log("native modules %s" % json.dumps(natives))
-    daemon = start_daemon(cell, procs, workdir, args.tiny, traced)
+    base_port = 19000 + (os.getpid() % 40) * 320
+    daemon = launch_daemon(cell, procs, workdir, args.tiny, traced)
+    pool = new_pool(cell, procs, workdir, args.seed, args.tiny, base_port)
+    daemon_ready(daemon, cell, args.tiny)
     warm_thread, warm_box = in_thread(daemon.warm_up, args.seed,
                                       SETUP_BUDGET_S)
-    base_port = 19000 + (os.getpid() % 40) * 320
     pool, plan, ops = start_pool(cell, daemon, procs, workdir, args.seed,
-                                 args.seconds, args.tiny, base_port)
+                                 args.seconds, args.tiny, base_port, pool)
     warm_thread.join()
     if "error" in warm_box:
         log(tail(os.path.join(workdir, "daemon.err"), 30))
@@ -376,6 +400,14 @@ def main() -> int:
         if args.tiny:
             for key, value in cell.traffic.get("tiny", {}).items():
                 cell.traffic["params"][key] = value
+            if "genesis" in cell.config["tiny"]:
+                cell.config["genesis"] = cell.config["tiny"]["genesis"]
+        if operations.uses_genesis(cell.traffic["operations"]) \
+                and not cell.config.get("genesis"):
+            raise NoResult(
+                "traffic %r signs with the deployment's identities and "
+                "configuration %r states no genesis" % (
+                    cell.entry["traffic"], cell.entry["config"]))
         if args.seeds or args.sweep:
             import builder
             return builder.main(args, cell, procs, workdir)

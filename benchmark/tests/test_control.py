@@ -18,7 +18,6 @@ import controls  # noqa: E402
 import operations  # noqa: E402
 import traffic  # noqa: E402
 from client import Op  # noqa: E402
-from reference.codec import b58encode  # noqa: E402
 
 NAMES = ["Alpha", "Beta", "Gamma", "Delta", "Epsilon", "Zeta", "Eta"]
 READY = {"device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
@@ -26,40 +25,49 @@ STATS = {"device_launches": 3, "failed_batches": 0, "step_downs": {},
          "mesh": {"dispatches": 3}, "kernel_backends": {"ed25519": True}}
 
 
-def genesis(seed):
-    signer = traffic.Signer(traffic.trustee_seed(seed))
-    return [{"reqSignature": {}, "txn": {"data": {
-        "dest": signer.identifier, "role": "0",
-        "verkey": "~" + b58encode(signer.verkey[16:])},
-        "metadata": {}, "type": "1"}, "txnMetadata": {}, "ver": "1"}]
+IDENTITIES = 300     # of the configuration with a `genesis`
 
 
-def ops_for(seed, count=400):
+def genesis(seed, identities=0):
+    """A domain genesis as Pool.generate leaves it, without the
+    stewards: the trustee, then the configuration's identities."""
+    trustee = traffic.Signer(traffic.trustee_seed(seed))
+    return [trustee.genesis_nym("0")] + [
+        traffic.identity(seed, i).genesis_nym() for i in range(identities)]
+
+
+def ops_for(seed, count=400, identities=0):
+    mix = {"kind": "nym_write_authors" if identities else "nym_write",
+           "zipf_constant": 0.99, "corrupted_every": 50}
     ops = [Op(req, b"", valid)
            for req, valid in operations.make(
-               seed, count, {"kind": "nym_write", "corrupted_every": 50})]
+               seed, count, mix, {"identities": identities})]
     for i, op in enumerate(ops):
         op.due = op.sent = float(i)
         op.done = float(i) + 0.5
     return ops
 
 
+@pytest.mark.parametrize("identities", [0, IDENTITIES])
 @pytest.mark.parametrize("seed", [3, 2147483900, 77])
 @pytest.mark.parametrize("n", [4, 7])
-def test_unbroken_reference_is_correct(seed, n):
-    obs = controls.reference_pool(NAMES[:n], (n - 1) // 3, ops_for(seed),
-                                  genesis(seed), READY, STATS, tiny=False)
-    values = check.compare(obs, genesis(seed))["values"]
+def test_unbroken_reference_is_correct(seed, n, identities):
+    obs = controls.reference_pool(
+        NAMES[:n], (n - 1) // 3, ops_for(seed, identities=identities),
+        genesis(seed, identities), READY, STATS, tiny=False)
+    values = check.compare(obs, genesis(seed, identities))["values"]
     assert check.verdict(values), values
 
 
+@pytest.mark.parametrize("identities", [0, IDENTITIES])
 @pytest.mark.parametrize("seed", [3, 2147483900, 77])
 @pytest.mark.parametrize("control", controls.CONTROLS)
-def test_control_is_not_correct(seed, control):
-    obs = controls.reference_pool(NAMES[:4], 1, ops_for(seed),
-                                  genesis(seed), READY, STATS, tiny=False,
-                                  break_guarantee=control)
-    values = check.compare(obs, genesis(seed))["values"]
+def test_control_is_not_correct(seed, control, identities):
+    obs = controls.reference_pool(
+        NAMES[:4], 1, ops_for(seed, identities=identities),
+        genesis(seed, identities), READY, STATS, tiny=False,
+        break_guarantee=control)
+    values = check.compare(obs, genesis(seed, identities))["values"]
     assert not check.verdict(values), values
 
 

@@ -20,12 +20,13 @@ import os
 import subprocess
 import sys
 
+import pytest
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(HERE)
 
 
-def run_tiny(env_extra=None, seed=5):
+def run_tiny(env_extra=None, seed=5, cell="pool4-write-burst"):
     """run.py's rehearsal; with a fault, through tests/faulty_run.py,
     which swaps one process's entry and changes nothing else."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -34,7 +35,7 @@ def run_tiny(env_extra=None, seed=5):
         else os.path.join(HERE, "run.py")
     proc = subprocess.run(
         [sys.executable, entry, "--workload",
-         "pool4-write-burst", "--seed", str(seed), "--seconds", "8",
+         cell, "--seed", str(seed), "--seconds", "8",
          "--trace", "0", "--tiny"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
@@ -42,23 +43,30 @@ def run_tiny(env_extra=None, seed=5):
     return line, {k: v[0] for k, v in line["compared"].items()}
 
 
-def test_sound_run_fails_only_for_want_of_a_tpu():
-    line, got = run_tiny()
+CELLS = pytest.mark.parametrize("cell", ["pool4-write-burst",
+                                         "pool4-authors-burst"])
+
+
+@CELLS
+def test_sound_run_fails_only_for_want_of_a_tpu(cell):
+    line, got = run_tiny(cell=cell)
     assert line["correct"] is False
     assert got.pop("daemon_faults") == 1
     assert not any(got.values()), got
 
 
-def test_daemon_that_passes_every_signature():
-    line, got = run_tiny({"BENCH_DAEMON_FAULT": "accept_all"})
+@CELLS
+def test_daemon_that_passes_every_signature(cell):
+    line, got = run_tiny({"BENCH_DAEMON_FAULT": "accept_all"}, cell=cell)
     assert line["correct"] is False
     # some node let a corrupted write stand; whether it is also ordered
     # before the drain ends depends on how the copies were batched
     assert got["corrupted_not_refused"] + got["corrupted_ordered"] > 0, got
 
 
-def test_node_that_leaves_its_state_unchanged():
-    line, got = run_tiny({"BENCH_NODE_FAULT": "state_unchanged"})
+@CELLS
+def test_node_that_leaves_its_state_unchanged(cell):
+    line, got = run_tiny({"BENCH_NODE_FAULT": "state_unchanged"}, cell=cell)
     assert line["correct"] is False
     assert got["nodes_off_state"] + got["nodes_off_ledger"] \
         + got["unanswered_by_a_node"] > 0, got
