@@ -58,19 +58,22 @@ class TouchedKeys:
 class LanePlan:
     """The partition of one ordered batch into execution lanes."""
 
-    __slots__ = ("lanes", "n_lanes", "serial_requests", "conflict_ratio",
-                 "read_keys_by_ledger", "write_keys_by_ledger",
-                 "lane_sizes")
+    __slots__ = ("lanes", "n_lanes", "serial_requests", "conflicted",
+                 "conflict_ratio", "read_keys_by_ledger",
+                 "write_keys_by_ledger", "lane_sizes")
 
     def __init__(self, lanes: List[int], n_lanes: int,
-                 serial_requests: int, conflict_ratio: float,
+                 serial_requests: int, conflicted: int,
                  read_keys_by_ledger: Dict[int, List[bytes]],
                  write_keys_by_ledger: Dict[int, List[bytes]],
                  lane_sizes: Dict[int, int]):
         self.lanes = lanes                  # per-request lane id
         self.n_lanes = n_lanes              # declared lanes + serial
         self.serial_requests = serial_requests
-        self.conflict_ratio = conflict_ratio
+        # requests that share a lane: the serial lane's, and those of
+        # every declared lane of more than one
+        self.conflicted = conflicted
+        self.conflict_ratio = (conflicted / len(lanes)) if lanes else 0.0
         self.read_keys_by_ledger = read_keys_by_ledger
         self.write_keys_by_ledger = write_keys_by_ledger
         self.lane_sizes = lane_sizes        # lane id -> request count
@@ -153,7 +156,7 @@ def plan_lanes(touches: Sequence[Optional[TouchedKeys]]) -> LanePlan:
         lanes=lanes,
         n_lanes=n_lanes,
         serial_requests=serial,
-        conflict_ratio=(conflicted / n) if n else 0.0,
+        conflicted=conflicted,
         read_keys_by_ledger={lid: list(keys)
                              for lid, keys in read_keys.items()},
         write_keys_by_ledger={lid: list(keys)

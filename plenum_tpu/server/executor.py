@@ -163,6 +163,13 @@ class NodeBatchExecutor(BatchExecutor):
                 if state is not None and state.begin_read_window(keys):
                     windows.append(state)
             sp.add(lanes=plan.n_lanes, serial=plan.serial_requests)
+            if self.tracer.enabled:
+                # requests in a lane of more than one (the serial lane
+                # counts whole), and the largest lane: what the batch's
+                # conflicts look like, per batch, in the dump
+                sp.add(conflicted=plan.conflicted,
+                       largest_lane=max(plan.lane_sizes.values(),
+                                        default=0))
         return plan
 
     def _apply_batch(self, pre_prepare_digests: List[str], ledger_id: int,
@@ -195,7 +202,9 @@ class NodeBatchExecutor(BatchExecutor):
             with self.tracer.span(
                     "lane_apply", CAT_EXECUTE, key=pp_digest or None,
                     batch_size=len(requests),
-                    lanes=plan.n_lanes if plan else 0):
+                    lanes=plan.n_lanes if plan else 0) as lane_sp:
+                traced = self.tracer.enabled
+                misses0 = self.write_manager.nym_misses() if traced else 0
                 # batch order is the canonical schedule: every request
                 # observes exactly the writes ordered before it (reads
                 # go pending-buffer → read window → trie), so the lane
@@ -222,6 +231,9 @@ class NodeBatchExecutor(BatchExecutor):
                         seq_base[handler_lid] + len(group) + 1)
                     group.append(txn)
                     valid.append(digest)
+                if traced:
+                    lane_sp.add(nym_misses=self.write_manager.nym_misses()
+                                - misses0)
         finally:
             for st in windows:
                 st.end_read_window()

@@ -414,7 +414,9 @@ class Node:
             forward_handler=self._forward_finalised,
             authenticator=authenticate_propagated,
             forward_batch_handler=self._forward_finalised_batch,
-            flat_wire_enabled=flat_wire_on)
+            flat_wire_enabled=flat_wire_on,
+            already_ordered=lambda request:
+                self._committed_at(request) is not None)
         network.subscribe(Propagate, self.propagator.process_propagate)
         network.subscribe(PropagateBatch,
                           self.propagator.process_propagate_batch)
@@ -1049,9 +1051,14 @@ class Node:
         with self.metrics.measure_time(MetricsName.DEVICE_DISPATCH_TIME), \
                 self.tracer.span("auth_dispatch", CAT_DEVICE,
                                  n=len(msgs)) as _sp:
+            traced = self.tracer.enabled
+            misses0 = self.write_manager.nym_misses() if traced else 0
             pending = self._dispatch_client_batch(msgs)
             if pending is not None:
                 _sp.add(dispatched=len(pending[0]))
+            if traced:
+                _sp.add(nym_misses=self.write_manager.nym_misses()
+                        - misses0)
             return pending
 
     def _dispatch_client_batch(self, msgs: List[Tuple[dict, str]]):
@@ -1858,8 +1865,13 @@ class Node:
             self._tm_intake_ts.pop(digest, None)
             self.propagator.requests.free(digest)
 
+    def _committed_at(self, request: Request):
+        """The dedup index's "ledger:seqNo" for a request whose payload
+        is already on a ledger, else None."""
+        return self.seq_no_db.get_or_none(request.payload_digest.encode())
+
     def _committed_reply(self, request: Request) -> Optional[Reply]:
-        raw = self.seq_no_db.get_or_none(request.payload_digest.encode())
+        raw = self._committed_at(request)
         if raw is None:
             return None
         lid, seq_no = bytes(raw).decode().split(":")

@@ -176,7 +176,8 @@ class Propagator:
                  forward_handler: Callable[[Request], None],
                  authenticator: Callable[[Request], bool] = None,
                  forward_batch_handler: Callable[[list], None] = None,
-                 flat_wire_enabled: bool = False):
+                 flat_wire_enabled: bool = False,
+                 already_ordered: Callable[[Request], bool] = None):
         """network: ExternalBus; forward_handler: called exactly once per
         finalised request (feeds ordering queues). authenticator(request)
         → bool gates requests FIRST LEARNED from a peer's PROPAGATE: a
@@ -188,13 +189,20 @@ class Propagator:
         forward_batch_handler(requests): optional columnar forward — all
         requests finalised by ONE inbound PROPAGATE_BATCH go to the
         ordering queues as one contiguous digest column (one downstream
-        stash-replay per batch instead of per request)."""
+        stash-replay per batch instead of per request).
+        already_ordered(request) → bool: the node's dedup index says
+        this request is on a ledger. Commit frees a request's state
+        here, so a relay's copy that arrives afterwards looks like a
+        first sighting; voting for it would finalise it and order it a
+        second time (reference node.py processPropagate: "ignoring
+        propagated request ... already ordered")."""
         self.name = name
         self.quorums = quorums
         self._network = network
         self._forward = forward_handler
         self._forward_batch = forward_batch_handler
         self._authenticator = authenticator
+        self._already_ordered = already_ordered
         # flat zero-copy wire (common/serializers/flat_wire.py): each
         # queued payload is packed ONCE at queue time — the same bytes
         # feed the size budget AND the envelope, so the old pack-for-
@@ -457,6 +465,12 @@ class Propagator:
             except Exception:
                 logger.warning("%s: malformed PROPAGATE payload from %s "
                                "— ignored", self.name, frm)
+                return
+            if self._already_ordered is not None \
+                    and self._already_ordered(request):
+                # its batch committed here and freed it: a late copy
+                # gets no vote, or an operation that stays valid (an
+                # owner's rewrite of its own nym) is ordered twice
                 return
             if self._authenticator is not None:
                 # a relayed request that beat the client's own copy

@@ -208,6 +208,10 @@ class NymHandler(WriteRequestHandler):
         # invalidated: update_state pops the nym it writes; any state
         # rewind clears it wholesale (clear_caches)
         self._nym_cache: dict = {}
+        # lookups the cache could not serve, ever; a traced span records
+        # the difference across itself (`nym_misses` on auth_dispatch
+        # and lane_apply)
+        self.nym_misses = 0
 
     def static_validation(self, request: Request):
         op = request.operation
@@ -278,6 +282,7 @@ class NymHandler(WriteRequestHandler):
         rec = self._nym_cache.get(identifier, self._MISS)
         if rec is not self._MISS:
             return rec
+        self.nym_misses += 1
         rec, _, _ = decode_state_value(self.state.get(
             nym_to_state_key(identifier), isCommitted=False))
         from plenum_tpu.common.config import Config

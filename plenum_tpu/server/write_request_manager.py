@@ -11,7 +11,7 @@ from __future__ import annotations
 import logging
 from typing import Dict, List, Optional, Tuple
 
-from plenum_tpu.common.constants import AUDIT_LEDGER_ID
+from plenum_tpu.common.constants import AUDIT_LEDGER_ID, NYM
 from plenum_tpu.common.exceptions import InvalidClientRequest
 from plenum_tpu.common.request import Request
 from plenum_tpu.common.txn_util import append_txn_metadata, reqToTxn
@@ -190,6 +190,12 @@ class WriteRequestManager:
                                      None)
                 if invalidate is not None:
                     invalidate(keys)
+
+    def nym_misses(self) -> int:
+        """Lookups NymHandler's record cache could not serve, so far
+        (0 without a NYM handler): a traced span records the
+        difference across itself."""
+        return getattr(self.request_handlers.get(NYM), "nym_misses", 0)
 
     def apply_request_deferred(self, request: Request, batch_ts: int,
                                seq_no: int) -> Tuple[dict, object]:
