@@ -7,7 +7,8 @@ int-keyed KV store; each committed txn's leaf hash feeds the
 CompactMerkleTree; uncommitted txns extend a shadow tree (root-only) so
 state roots for PRE-PREPARE are available before commit.
 """
-from typing import Callable, Dict, Generator, List, Optional, Tuple
+from typing import (
+    Callable, Dict, Generator, Iterable, List, Optional, Tuple)
 
 from plenum_tpu.common.serializers.base58 import b58decode, b58encode
 from plenum_tpu.common.serializers.serialization import ledger_txn_serializer
@@ -92,6 +93,28 @@ class Ledger:
         self._store.put(_seq_key(seq_no), serialized)
         self.seqNo = seq_no
         return seq_no
+
+    def add_committed_bulk(self, txns: Iterable[dict]) -> Tuple[int, int]:
+        """Append MANY already-committed txns in one pass (a node's
+        genesis load): seqNo metadata, serialization, leaf hash, the
+        tree's frontier and the stored bytes a txn — tree, root and
+        store are byte-equal to `add` a txn, but no root hash, audit
+        path or base58 string is computed along the way. Returns
+        (first, last) seqNo. The leaves go through hashlib one by one,
+        not through the batched seam: for 100,000 leaves of 230 bytes
+        hashlib read 0.07 s and the seam 0.18-0.27 s on a TPU v5e
+        (2.70 s through XLA on the CPU backend), and tree.extend's
+        level-wise build 0.41 s against 0.32 s for the frontier merges."""
+        first = self.seqNo + 1
+        serialize, hash_leaf = self.serialize_for_tree, self.hasher.hash_leaf
+        tree_append, store_put = self.tree._append_hash, self._store.put
+        for seq_no, txn in enumerate(txns, first):
+            append_txn_metadata(txn, seq_no=seq_no)
+            serialized = serialize(txn)
+            tree_append(hash_leaf(serialized), want_path=False)
+            store_put(_seq_key(seq_no), serialized)
+            self.seqNo = seq_no
+        return first, self.seqNo
 
     def add(self, txn: dict) -> dict:
         """Append a committed txn; returns merkle info (seqNo, rootHash,

@@ -35,24 +35,31 @@ class ValidatorNodeInfoTool:
     @property
     def info(self) -> dict:
         node = self._node
+        node_info = {
+            "Name": node.name,
+            "Mode": ("participating" if node.mode_participating
+                     else ("syncing" if node.leecher.in_progress
+                           else "stalled")),
+            "View_no": node.view_no,
+            "Last_ordered_3PC": list(node.last_ordered),
+            "Master_primary": node.master_primary_name,
+            "Count_of_replicas": node.replicas.num_instances,
+            "Replicas_status": self._replicas_status(),
+            "Committed_ledger_root_hashes": self._ledger_roots(),
+            "Committed_state_root_hashes": self._state_roots(),
+            "Ledger_sizes": self._ledger_sizes(),
+        }
+        # a first start's genesis load: txns loaded and the seconds it
+        # took; a restart from persisted stores loaded nothing and
+        # carries no such key
+        genesis_load = getattr(node, "genesis_load", None)
+        if genesis_load is not None:
+            node_info["Genesis_load"] = dict(genesis_load)
         return {
             "alias": node.name,
             "timestamp": int(self._get_time()),
             "uptime_s": int(self._get_time() - self._started_at),
-            "Node_info": {
-                "Name": node.name,
-                "Mode": ("participating" if node.mode_participating
-                         else ("syncing" if node.leecher.in_progress
-                               else "stalled")),
-                "View_no": node.view_no,
-                "Last_ordered_3PC": list(node.last_ordered),
-                "Master_primary": node.master_primary_name,
-                "Count_of_replicas": node.replicas.num_instances,
-                "Replicas_status": self._replicas_status(),
-                "Committed_ledger_root_hashes": self._ledger_roots(),
-                "Committed_state_root_hashes": self._state_roots(),
-                "Ledger_sizes": self._ledger_sizes(),
-            },
+            "Node_info": node_info,
             "Pool_info": self._pool_info(),
             "View_change_info": self._view_change_info(),
             "Catchup_status": self._catchup_status(),

@@ -125,15 +125,6 @@ def test_hottest_did_takes_seven_to_nine_percent_at_100000():
 
 # -------------------------------- write manager and executor, by path
 
-def load_genesis(wm, txns):
-    """As Node._load_genesis does."""
-    handler = wm.request_handlers[NYM]
-    for txn in txns:
-        handler.ledger.add(dict(txn))
-        handler.update_state(txn, None, None, is_committed=True)
-        handler.state.commit()
-
-
 def ordered(batch_no, roots, pp_time):
     return Ordered(
         instId=0, viewNo=0, valid_reqIdr=["r"], invalid_reqIdr=[],
@@ -173,7 +164,7 @@ def test_three_batches_reach_the_references_roots(
     dm = NodeBootstrap.init_storage(config=conf)
     wm, _rm = NodeBootstrap.init_managers(dm, conf)
     txns = genesis(seed)
-    load_genesis(wm, txns)
+    NodeBootstrap.load_genesis(wm, txns)
     rejects, store = [], {}
     executor = NodeBatchExecutor(
         wm, store.get, lanes=lanes, lane_min=2, fused_dispatch=fused,
@@ -249,7 +240,7 @@ def test_spans_carry_the_conflicts_and_the_cache_misses():
     dm = NodeBootstrap.init_storage(config=Config(
         STATE_DEVICE_ENGINE=False))
     wm, _rm = NodeBootstrap.init_managers(dm)
-    load_genesis(wm, genesis(seed))
+    NodeBootstrap.load_genesis(wm, genesis(seed))
     store = {}
     executor = NodeBatchExecutor(wm, store.get, lanes=True, lane_min=2)
     executor.tracer = Tracer(name="X", capacity=256, armed=False)
