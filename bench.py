@@ -99,39 +99,9 @@ def make_requests(n, signer):
     return reqs
 
 
-def wire_faithful_serde():
-    """SimNetwork serialize_deserialize hook reproducing the REAL
-    transport's codec costs: every delivered message is packed with
-    the canonical wire serializer ONCE per message object (NodeStack
-    serializes an outbound frame once, then fans the same bytes out to
-    every peer) and unpacked + factory-reconstructed once PER DELIVERY
-    (every receiver parses its own copy). Without this, the in-process
-    sim hands live objects around and the typed-object wire pays ZERO
-    serialization — the flat-codec A/B would be comparing a real parse
-    against a free one."""
-    from plenum_tpu.common.messages.message_factory import (
-        node_message_factory)
-    from plenum_tpu.common.serializers.serializers import (
-        MsgPackSerializer)
-    ser = MsgPackSerializer()
-
-    def serde(msg):
-        raw = getattr(msg, "_wire_raw", None)
-        if raw is None:
-            raw = ser.serialize(msg.to_dict())
-            try:
-                msg._wire_raw = raw   # non-schema attr: pack once
-            except Exception:
-                pass
-        return node_message_factory.get_instance(
-            **ser.deserialize(raw))
-
-    return serde
-
-
 def make_sim_pool(names, verifier_name, seed=7, batch=None,
                   tracing=False, mesh=True, telemetry=True,
-                  flat_wire=True, wire_serde=False, extra_conf=None):
+                  extra_conf=None):
     """Build an n-node sim pool with the given verification provider
     (shared scaffolding for the 4-node headline and 25-node backlog
     configs — one drain/hub wiring to maintain). tracing=True turns on
@@ -140,8 +110,7 @@ def make_sim_pool(names, verifier_name, seed=7, batch=None,
     applies MESH_* to the process-wide mesh) for the on/off configs;
     telemetry=False pins the always-on telemetry plane off (its
     overhead A/B config — every other config keeps it ON, the
-    production shape); flat_wire=False pins the typed-object wire
-    fallback (the wire_flat_ab config's B side)."""
+    production shape)."""
     from plenum_tpu.common.config import Config
     from plenum_tpu.crypto.batch_verifier import create_verifier
     from plenum_tpu.runtime.sim_random import DefaultSimRandom
@@ -151,19 +120,13 @@ def make_sim_pool(names, verifier_name, seed=7, batch=None,
 
     timer = MockTimer()
     timer.set_time(SIM_EPOCH)
-    if callable(wire_serde):
-        serde = wire_serde
-    elif wire_serde:
-        serde = wire_faithful_serde()
-    else:
-        serde = None
     net = SimNetwork(timer, DefaultSimRandom(seed), min_latency=0.001,
-                     max_latency=0.005, serialize_deserialize=serde)
+                     max_latency=0.005)
     overrides = dict(Max3PCBatchSize=batch or CLIENT_BATCH,
                      Max3PCBatchWait=0.05,
                      CHK_FREQ=10, LOG_SIZE=30, HEARTBEAT_FREQ=10 ** 6,
                      TRACING_ENABLED=tracing, MESH_ENABLED=mesh,
-                     TELEMETRY_ENABLED=telemetry, FLAT_WIRE=flat_wire)
+                     TELEMETRY_ENABLED=telemetry)
     overrides.update(extra_conf or {})
     conf = Config(**overrides)
     nodes = [Node(name, names, timer, net.create_peer(name), config=conf)
@@ -1565,159 +1528,6 @@ def merkle_regression_flags(mk):
     }
 
 
-class _TrustedVerifier:
-    """Clean-box intake for the wire A/B: every signature verdict is
-    True with zero crypto work, so the pump measures the WIRE + 3PC +
-    execute host path, not this container's pure-Python ed25519 floor
-    (the PR-8 'intake excluded' methodology — both A/B sides share the
-    identical zero-cost intake)."""
-
-    name = "trusted"
-
-    class _Ready:
-        __slots__ = ("n",)
-
-        def __init__(self, n):
-            self.n = n
-
-        def ready(self):
-            return True
-
-        def collect(self):
-            return [True] * self.n
-
-    def verify_batch(self, items):
-        return [True] * len(items)
-
-    def dispatch(self, items):
-        return self._Ready(len(items))
-
-
-def wire_flat_ab():
-    """Clean-box 25-node pump A/B for the flat zero-copy wire
-    (ROADMAP item 3 acceptance): the IDENTICAL deterministic 25-node
-    sim pool + ordering workload with the flat codec on vs the
-    typed-object fallback, both traced, intake excluded via the
-    trusted verifier. The claim is read off scripts/trace_budget's
-    per-stage exclusive host-ms — the serialize/parse rows are the
-    populations the codec attacks, and host_ms_per_ordered_req.total
-    is the headline ratio — plus the wire byte counters from an
-    isolated seam hub (how much smaller the flat envelopes are)."""
-    from plenum_tpu.crypto.signer import SimpleSigner
-    from plenum_tpu.observability.budget import budget_from_tracers
-    from plenum_tpu.observability.export import pool_tracers
-    from plenum_tpu.observability.telemetry import (
-        TM, TelemetryHub, set_seam_hub)
-
-    n_nodes = int(os.environ.get("BENCH_WIRE_NODES", "25"))
-    n = int(os.environ.get("BENCH_WIRE_REQS", "800"))
-    wall_budget = float(os.environ.get("BENCH_WIRE_WALL", "150"))
-    batch = int(os.environ.get("BENCH_WIRE_BATCH", "200"))
-    names = ["W%02d" % i for i in range(n_nodes)]
-    reqs = make_requests(n, SimpleSigner(seed=b"\x61" * 32))
-    chunks = [reqs[i:i + batch] for i in range(0, n, batch)]
-
-    def run_one(flat: bool) -> dict:
-        prev_hub = set_seam_hub(TelemetryHub(name="wire-ab"))
-        # the serde cost (the transport's own pack/unpack + factory
-        # reconstruction, which on real sockets happens in the stack
-        # glue OUTSIDE any tracer span) is accumulated here and folded
-        # into the per-request totals below
-        serde_stats = {"s": 0.0, "calls": 0}
-        base_serde = wire_faithful_serde()
-
-        def counting_serde(msg, _stats=serde_stats):
-            t0 = time.perf_counter()
-            result = base_serde(msg)
-            _stats["s"] += time.perf_counter() - t0
-            _stats["calls"] += 1
-            return result
-
-        # clean box: the device seams (batched SHA-256, device MPT,
-        # fused dispatch window) are pinned to their host paths — they
-        # are IDENTICAL on both wire modes, and on this shared box
-        # their dispatch-wait jitter is larger than the wire deltas
-        # under test. The pump measures the serial host money path the
-        # codec changes; the device seams have their own gated benches.
-        nodes, timer = make_sim_pool(
-            names, "cpu", seed=13, batch=batch, tracing=True,
-            flat_wire=flat, wire_serde=counting_serde,
-            extra_conf=dict(SHA256_BACKEND="scalar",
-                            FUSED_BATCH_DISPATCH=False,
-                            STATE_DEVICE_ENGINE=False,
-                            MESH_ENABLED=False))
-        for nd in nodes:
-            nd.authnr._verifier = _TrustedVerifier()
-        t0 = time.perf_counter()
-        deadline = t0 + wall_budget
-        pipelined_intake(nodes, timer, chunks, client_id="wire",
-                         deadline=deadline)
-        while time.perf_counter() < deadline:
-            for nd in nodes:
-                nd.service()
-            timer.run_for(0.01)
-            if all(nd.domain_ledger.size >= n for nd in nodes):
-                break
-        elapsed = time.perf_counter() - t0
-        ordered = min(nd.domain_ledger.size for nd in nodes)
-        budget = budget_from_tracers(pool_tracers(nodes))
-        hub = set_seam_hub(prev_hub)
-        counters = hub.snapshot().get("counters") or {}
-        codec_ms = (serde_stats["s"] * 1e3 / n_nodes
-                    / max(1, ordered))
-        stage_ms = budget.get("host_ms_per_ordered_req") or {}
-        total = (stage_ms.get("total") or 0.0) + codec_ms
-        return {
-            "req_per_s": round(ordered / max(1e-9, elapsed), 1),
-            "ordered": ordered,
-            "drained": ordered >= n,
-            "host_ms_per_ordered_req": stage_ms,
-            # transport codec work per ordered request per node (pack
-            # once per message, unpack+reconstruct per delivery)
-            "wire_codec_ms_per_req": round(codec_ms, 4),
-            "host_ms_incl_codec": round(total, 4),
-            "wire_deliveries": serde_stats["calls"],
-            "wire_bytes_sent_per_node":
-                counters.get(TM.WIRE_BYTES_SENT, 0) // max(1, n_nodes),
-        }
-
-    out = {"nodes": n_nodes, "reqs": n}
-    # INTERLEAVED best-of-2 (the tracing/telemetry A/B methodology):
-    # alternating runs expose both wire modes to the same box-load
-    # profile, and best-of drops the run that paid the cold XLA
-    # compiles — a one-sided warmup would bias whichever mode ran first
-    rounds = int(os.environ.get("BENCH_WIRE_ROUNDS", "2"))
-    for _ in range(rounds):
-        for label, flat in (("flat", True), ("typed", False)):
-            run = run_one(flat)
-            best = out.get(label)
-            if best is None or run["host_ms_incl_codec"] \
-                    < best["host_ms_incl_codec"]:
-                out[label] = run
-    flat_ms = out["flat"]["host_ms_incl_codec"]
-    typed_ms = out["typed"]["host_ms_incl_codec"]
-    if flat_ms and typed_ms:
-        out["host_ms_ratio_typed_vs_flat"] = round(typed_ms / flat_ms, 2)
-        # the wire-owned populations side by side: the budget's
-        # serialize/parse spans plus the transport codec work
-        wire_pop = {}
-        for label in ("flat", "typed"):
-            stage_ms = out[label]["host_ms_per_ordered_req"] or {}
-            wire_pop[label] = {
-                "serialize": stage_ms.get("serialize"),
-                "parse": stage_ms.get("parse"),
-                "transport_codec": out[label]["wire_codec_ms_per_req"],
-            }
-            wire_pop[label]["total"] = round(sum(
-                v for v in wire_pop[label].values() if v), 4)
-        out["wire_stage_ms_per_req"] = wire_pop
-        ft, tt = wire_pop["flat"]["total"], wire_pop["typed"]["total"]
-        if ft:
-            # the populations the codec actually attacks, isolated
-            out["wire_only_ratio_typed_vs_flat"] = round(tt / ft, 2)
-    return out
-
-
 PIPELINE_SPEEDUP_FLOOR = 1.5
 
 
@@ -1742,7 +1552,7 @@ def _pipeline_parity_roots(pipeline: bool, sanitizer=None):
     net = SimNetwork(timer, DefaultSimRandom(77),
                      min_latency=0.003, max_latency=0.003)
     conf = Config(Max3PCBatchSize=5, Max3PCBatchWait=0.2,
-                  FLAT_WIRE=True, PIPELINE_ENABLED=pipeline,
+                  PIPELINE_ENABLED=pipeline,
                   SANITIZER_ENABLED=sanitizer)
     nodes = [Node(name, names, timer, net.create_peer(name), config=conf)
              for name in names]
@@ -1774,7 +1584,8 @@ def pipeline_ab():
     a fast wrong pipeline must never produce a headline. The timing
     side keeps the real OpenSSL verifier (signature work is one of the
     stages the worker thread absorbs) and pins the device seams to
-    their host paths, same reasoning as wire_flat_ab."""
+    their host paths (identical on both sides, and on a shared box
+    their dispatch-wait jitter is larger than the delta under test)."""
     out = {"nodes": int(os.environ.get("BENCH_PIPE_NODES", "25")),
            "reqs": int(os.environ.get("BENCH_PIPE_REQS", "800")),
            "cores": os.cpu_count() or 1}
@@ -1827,8 +1638,8 @@ def pipeline_ab():
             "drained": ordered >= n,
         }
 
-    # INTERLEAVED best-of-N, the wire_flat_ab methodology: alternating
-    # runs expose both modes to the same box-load profile
+    # INTERLEAVED best-of-N: alternating runs expose both modes to
+    # the same box-load profile
     rounds = int(os.environ.get("BENCH_PIPE_ROUNDS", "2"))
     for _ in range(rounds):
         for label, pipe in (("on", True), ("off", False)):
@@ -2925,7 +2736,6 @@ def main():
     host_ms_regression = host_ms_regression_flags(
         (tracing.get("host_ms_per_ordered_req") or {}).get("total"),
         (tracing.get("host_ms_per_ordered_req") or {}).get("execute"))
-    wire_ab = wire_flat_ab()
     pipe_ab = pipeline_ab()
     pipe_gate_failures = pipeline_regression_gate(pipe_ab)
     san = sanitizer_overhead()
@@ -3001,7 +2811,6 @@ def main():
             "gateway": gw,
             "tracing_overhead": tracing,
             "host_ms_regression": host_ms_regression,
-            "wire_flat_ab": wire_ab,
             "pipeline_ab": pipe_ab,
             "sanitizer_overhead": san,
             "telemetry_overhead": telemetry,
@@ -3067,18 +2876,6 @@ def main():
             # warn-tripwire vs the best prior recorded round (same
             # convention as merkle_regression)
             "host_ms_regression": host_ms_regression["warn"],
-            # flat zero-copy wire A/B (25-node clean-box pump): typed
-            # fallback host-ms over flat host-ms per ordered request
-            "wire_host_ms_ratio": wire_ab.get(
-                "host_ms_ratio_typed_vs_flat"),
-            "wire_flat_req_per_s": (wire_ab.get("flat") or {}).get(
-                "req_per_s"),
-            "wire_typed_req_per_s": (wire_ab.get("typed") or {}).get(
-                "req_per_s"),
-            "wire_flat_host_ms": (wire_ab.get("flat") or {}).get(
-                "host_ms_incl_codec"),
-            "wire_typed_host_ms": (wire_ab.get("typed") or {}).get(
-                "host_ms_incl_codec"),
             # pipeline-parallel node runtime A/B (25-node clean-box
             # pump): parity asserted byte-equal BEFORE timing, then
             # PIPELINE_ENABLED on over off — the one-thread-ceiling

@@ -8,9 +8,10 @@ self.prepares[key] if s != primary])``) — O(n) per message, O(n²) per
 batch per node, and at 25 validators the counting loop alone dominated
 the ordering money path (BENCH_r05: ~209 ordered req/s against ~62k
 device verifies/s). The fix is columnar: incremental quorum counters
-bumped at vote insert (one dict read per check) and batch intake
-(``process_prepare_batch``/``process_commit_batch``) that hoists the
-shared checks and compares the digest column in one vectorized pass.
+bumped at vote insert (one dict read per check) and columnar intake
+(``process_prepare_columns``/``process_commit_columns``) that hoists
+the shared checks and compares the digest column in one vectorized
+pass.
 
 Encoding: inside a HOT per-message handler — a function whose name is
 ``process_*``/``_process_*``/``validate_*``/``_try_*``/``_has_*``
@@ -104,8 +105,8 @@ class PerItemHotLoopRule(Rule):
                         "handler %s — O(items) per inbound message is "
                         "quadratic per batch; use an incremental "
                         "counter maintained at insert, or move the "
-                        "work to the columnar *_batch intake "
-                        "(process_prepare_batch/process_commit_batch)"
+                        "work to the columnar *_columns intake "
+                        "(process_prepare_columns/process_commit_columns)"
                         % (coll, func.name)))
                     break
         return out

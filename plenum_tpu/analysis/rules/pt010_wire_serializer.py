@@ -2,8 +2,9 @@
 
 Historical bug class: the wire layers under ``network/`` and
 ``server/`` invoking a serializer once PER ITEM inside a send/receive
-handler's loop. The PR-11 incident is the THREE_PC_BATCH receive path:
-every inner vote of every envelope went through
+handler's loop. The PR-11 incident is the receive path of the typed
+3PC envelope of the time (since removed): every inner vote of every
+envelope went through
 ``node_message_factory.get_instance`` (full schema validation + object
 construction) only for the columnar intake to strip the object back
 down to digest/view/seq columns — per-message deserialization was the

@@ -1,7 +1,7 @@
 """PT015 trace-context-taint-into-consensus-path.
 
-The wire trace stamp (flat_wire ``KIND_TRACE`` section / the typed
-envelopes' ``traceCtx`` field) is ADVISORY by contract
+The wire trace stamp (flat_wire ``KIND_TRACE`` section) is ADVISORY
+by contract
 (docs/wire.md): a peer controls every byte of it, a corrupt stamp
 decodes to ``None``, and message handling must proceed identically
 with or without it. That contract only holds if stamp CONTENT is
@@ -16,7 +16,7 @@ This rule pins the boundary from both directions:
   call closure of the PT012 consensus roots (execution lanes,
   flat-wire encode half, view change, primary selection, ordering
   digests, gateway lane router) calls the trace-section parse surface
-  (``decode_trace_stamp`` / ``TraceStamp.from_wire``). Stamp content
+  (``decode_trace_stamp``). Stamp content
   would flow straight into a consensus decision.
 * **parse-reaches-consensus** — the parse surface's own call closure
   contains a consensus root: stamp handling calling back into
@@ -38,24 +38,14 @@ from plenum_tpu.analysis.rules.pt012_nondeterminism import DEFAULT_ROOTS
 # the trace-section parse surface: the only places wire-controlled
 # stamp bytes become Python values
 _PARSE_TERMINALS = frozenset({"decode_trace_stamp"})
-_PARSE_CLASS = "TraceStamp"
-_PARSE_CLASS_METHOD = "from_wire"
 
 
 def _is_parse_call(chain) -> bool:
-    if not chain:
-        return False
-    terminal = chain[-1]
-    if terminal in _PARSE_TERMINALS:
-        return True
-    return terminal == _PARSE_CLASS_METHOD and _PARSE_CLASS in chain
+    return bool(chain) and chain[-1] in _PARSE_TERMINALS
 
 
 def _is_parse_symbol(fn) -> bool:
-    if fn["name"] in _PARSE_TERMINALS:
-        return True
-    return (fn["name"] == _PARSE_CLASS_METHOD
-            and fn.get("cls") == _PARSE_CLASS)
+    return fn["name"] in _PARSE_TERMINALS
 
 
 class TraceContextTaintRule(ProgramRule):

@@ -19,24 +19,8 @@ class Config:
     LOG_SIZE = 3 * CHK_FREQ      # watermark window [h, h+LOG_SIZE]
 
     # ---- columnar 3PC dataflow (server/three_pc_outbox.py +
-    # OrderingService.process_*_batch): coalesce every instance's
-    # broadcast 3PC votes into one THREE_PC_BATCH wire message per prod
-    # tick, and process inbound envelopes through the vectorized
-    # columnar intake. Inbound batches are always understood; this knob
-    # only gates our own coalesced SENDING. While an adversary tap is
-    # installed the outbox degrades to per-message sends regardless.
-    THREE_PC_BATCH_WIRE = True
-    # flat zero-copy wire codec (common/serializers/flat_wire.py):
-    # PREPARE/COMMIT votes travel as contiguous typed columns and
-    # PROPAGATE payloads as length-prefixed sections inside ONE
-    # FLAT_WIRE envelope per peer per tick — one pack / one parse
-    # instead of per-message serializer calls, zero intermediate
-    # message objects on the receive path. Inbound flat envelopes are
-    # always understood; this knob gates only our own SENDING (the
-    # typed THREE_PC_BATCH / PROPAGATE_BATCH path is the validated
-    # fallback, and an installed adversary tap degrades to it
-    # regardless so fault injection keeps per-message granularity).
-    FLAT_WIRE = True
+    # common/serializers/flat_wire.py): every instance's broadcast 3PC
+    # votes of one prod tick leave as one flat envelope per peer.
     # micro-batching window for delivery-provoked votes (seconds): a
     # vote provoked outside a prod tick waits at most this long for
     # same-window siblings before the outbox flushes — peer deliveries
@@ -385,10 +369,9 @@ class Config:
     # ---- journey plane (observability/journey.py): wire-carried trace
     # context. When on, flat envelopes ride as version 2 with an
     # advisory TRACE section (origin node, flush seq, perf+wall send
-    # timestamps; ≤89 payload bytes) and the typed THREE_PC_BATCH /
-    # PROPAGATE_BATCH fallback carries the same stamp in a nullable
-    # traceCtx field, so receivers can join per-node tracer buffers
-    # into per-request cross-node journeys. Purely advisory: stamps are
+    # timestamps; ≤89 payload bytes; a per-message send carries
+    # none), so receivers can join per-node tracer buffers into
+    # per-request cross-node journeys. Purely advisory: stamps are
     # decoded outside the consensus sections (plenum-lint PT015 pins
     # unreachability), malformed stamps degrade to None without
     # touching message handling, and bench.py trace_context_overhead

@@ -88,28 +88,6 @@ class Propagate(MessageBase):
     )
 
 
-class PropagateBatch(MessageBase):
-    """Many PROPAGATEs in one wire message (no reference equivalent —
-    the reference sends one PROPAGATE per request, plenum/server/
-    propagator.py:204, and amortizes only at the ZMQ frame layer).
-    At n nodes every request is handled n-1 times per node; batching at
-    the MESSAGE level amortizes handler dispatch, validation, and sim/
-    transport delivery across a whole tick of requests — the difference
-    between the 25-node pool collapsing and draining. `clients` uses ""
-    for requests whose submitting client is unknown."""
-
-    typename = "PROPAGATE_BATCH"
-    schema = (
-        ("requests", IterableField(AnyMapField(), min_length=1)),
-        # "" = submitting client unknown (relay hop)
-        ("clients", IterableField(AnyField())),
-        # advisory causal stamp [origin, flush_seq, perf_ts, wall_ts]
-        # (flat_wire.TraceStamp.as_list) — observability-only; malformed
-        # content decodes to None and never affects request handling
-        ("traceCtx", AnyField(nullable=True, optional=True)),
-    )
-
-
 # ----------------------------------------------------------------- 3PC
 
 class PrePrepare(MessageBase):
@@ -163,43 +141,6 @@ class Commit(MessageBase):
     )
 
 
-class ThreePCBatch(MessageBase):
-    """One sender's whole tick of broadcast 3PC votes — PRE-PREPAREs,
-    PREPAREs and COMMITs across ALL of its protocol instances — in ONE
-    wire message (no reference equivalent; the reference sends each vote
-    separately and amortizes only at the ZMQ frame layer). At n nodes
-    with f+1 RBFT instances every 3PC phase is otherwise its own
-    broadcast per instance per in-flight batch; coalescing at the
-    MESSAGE level amortizes serialization (one msgpack pack for the
-    whole batch), transport delivery, and receive-side dispatch — and
-    hands the receiver a COLUMN of same-sender votes for the columnar
-    `process_prepare_batch` / `process_commit_batch` intake.
-
-    `messages` entries are the inner messages' `to_dict()` wire form
-    (op field included) in SEND ORDER — FIFO per sender preserves the
-    PP-before-PREPARE-before-COMMIT causality the per-message wire had.
-    In-process transports (SimNetwork) deliver live MessageBase objects
-    instead; `as_dict` normalizes to wire form only when a real
-    transport serializes the envelope."""
-
-    typename = "THREE_PC_BATCH"
-    schema = (
-        ("messages", IterableField(AnyField(), min_length=1)),
-        # advisory causal stamp [origin, flush_seq, perf_ts, wall_ts]
-        # (flat_wire.TraceStamp.as_list) — observability-only; malformed
-        # content decodes to None and never affects vote handling
-        ("traceCtx", AnyField(nullable=True, optional=True)),
-    )
-
-    def as_dict(self):
-        d = {"messages": [
-            m.to_dict() if isinstance(m, MessageBase) else m
-            for m in self.messages]}
-        if getattr(self, "traceCtx", None) is not None:
-            d["traceCtx"] = list(self.traceCtx)
-        return d
-
-
 class FlatBatch(MessageBase):
     """Flat zero-copy wire envelope (common/serializers/flat_wire.py):
     PREPARE/COMMIT votes as contiguous typed columns, PRE-PREPAREs and
@@ -207,10 +148,10 @@ class FlatBatch(MessageBase):
     peer per tick, zero intermediate Python message objects on the
     receive path. The payload is opaque bytes to the transport (msgpack
     wraps it as a single bin field, no canonical-sort recursion into
-    the votes); `to_legacy_messages` re-materializes typed messages for
-    the fault-injection unwrap seams. The typed THREE_PC_BATCH /
-    PROPAGATE_BATCH path stays as validated fallback
-    (Config.FLAT_WIRE=False or an installed adversary tap)."""
+    the votes); `to_legacy_messages` re-materializes single messages
+    for the fault-injection unwrap seams. It is what a node sends; the
+    only other wire is each vote or PROPAGATE alone (a tapped bus, or
+    a chunk the flat layout cannot carry)."""
 
     typename = "FLAT_WIRE"
     schema = (

@@ -1,12 +1,11 @@
 """Flat zero-copy wire format for the 3PC / propagate money path.
 
-Every THREE_PC_BATCH envelope used to round-trip each inner vote
-through per-field msgpack of a Python message object: one canonical
-``_sort_deep`` + packb per vote on the send side, one
-``node_message_factory.get_instance`` (full schema validation + object
-construction) per vote on the receive side — only for the columnar
-intake to strip the objects back down to digest/view/seq columns.
-This module replaces that with ONE pack and ONE parse per envelope:
+A vote sent as its own message round-trips through per-field msgpack
+of a Python message object: one canonical ``_sort_deep`` + packb on
+the send side, one ``node_message_factory.get_instance`` (full schema
+validation + object construction) on the receive side. This module is
+the pool's wire instead: ONE pack and ONE parse per envelope for a
+sender's whole tick of votes and PROPAGATEs:
 
 * **PREPARE / COMMIT votes become contiguous typed columns** — instId,
   viewNo, ppSeqNo (little-endian unsigned ints), ppTime (f64), digest
@@ -23,13 +22,11 @@ This module replaces that with ONE pack and ONE parse per envelope:
   payloads are stored as msgpack blobs behind a u32 offset table —
   still one wire message, one parse, with per-item unpacking deferred
   to the consumer.
-* **The typed-object path stays as validated fallback** — the codec
-  slots into the serializer registry boundary exactly like
-  MsgPackSerializer does; ``Config.FLAT_WIRE = False`` (or an
-  installed adversary tap) restores the per-message / THREE_PC_BATCH
-  wire unchanged, and ``to_legacy_messages`` re-materializes a flat
-  envelope into typed messages so fault-injection taps keep seeing
-  per-type granularity.
+* **The per-message wire is the reference** — senders use it while
+  an adversary tap is installed and for a chunk this layout cannot
+  carry (:class:`FlatWireUnencodable`); no option selects a wire.
+  ``to_legacy_messages`` re-materializes a flat envelope into single
+  messages so fault-injection taps keep seeing per-type granularity.
 
 Envelope layout (all integers little-endian; see docs/wire.md):
 
@@ -97,7 +94,7 @@ raises :class:`FlatWireError` — the node handler converts that into a
 per-sender suspicion and drops the envelope; it can never crash the
 prod loop. Entry-LEVEL garbage (a root string failing schema
 validation, an unparseable PRE-PREPARE blob) costs only that entry,
-exactly like a bad entry in a legacy THREE_PC_BATCH.
+never the envelope.
 """
 from __future__ import annotations
 
@@ -157,8 +154,8 @@ class FlatWireError(Exception):
 
 class FlatWireUnencodable(Exception):
     """A message whose field values the flat layout cannot carry
-    (e.g. an out-of-range integer); the sender falls back to the
-    typed-object wire for that chunk."""
+    (e.g. an out-of-range integer); the sender sends that chunk's
+    messages one by one instead."""
 
 
 def _serializer():
@@ -194,11 +191,10 @@ def _ragged_table(columns: List[List[bytes]]) -> Tuple[bytes, bytes]:
 
 
 class TraceStamp:
-    """Advisory causal stamp carried by a version-2 envelope (and by
-    the typed THREE_PC_BATCH / PROPAGATE fallback as a plain list).
-    Pure data — the timestamp VALUES are produced at the sender's
-    flush seam and passed in as arguments; nothing in this module
-    reads a clock."""
+    """Advisory causal stamp carried by a version-2 envelope's TRACE
+    section (a per-message send carries none). Pure data — the
+    timestamp VALUES are produced at the sender's flush seam and
+    passed in as arguments; nothing in this module reads a clock."""
 
     __slots__ = ("origin", "seq", "perf_ts", "wall_ts")
 
@@ -208,30 +204,6 @@ class TraceStamp:
         self.seq = seq
         self.perf_ts = perf_ts
         self.wall_ts = wall_ts
-
-    def as_list(self) -> list:
-        """Typed-fallback wire form (rides a nullable message field)."""
-        return [self.origin, self.seq, self.perf_ts, self.wall_ts]
-
-    @classmethod
-    def from_wire(cls, value) -> Optional["TraceStamp"]:
-        """Typed-fallback decode: ANY content problem → None (the
-        stamp is advisory; it can never fail the carrying message)."""
-        try:
-            origin, seq, perf_ts, wall_ts = value
-            origin = str(origin)
-            if len(origin.encode("utf-8")) > TRACE_NAME_MAX:
-                return None
-            seq = int(seq)
-            perf_ts = float(perf_ts)
-            wall_ts = float(wall_ts)
-            if seq < 0 or seq >> 64 \
-                    or not math.isfinite(perf_ts) \
-                    or not math.isfinite(wall_ts):
-                return None
-            return cls(origin, seq, perf_ts, wall_ts)
-        except Exception:
-            return None
 
     def __repr__(self):
         return ("TraceStamp(origin=%r, seq=%d, perf_ts=%r, wall_ts=%r)"
@@ -412,7 +384,7 @@ def encode_three_pc(pps, prepares, commits,
                     trace: Optional[bytes] = None) -> bytes:
     """One sender's tick of broadcast 3PC votes → one flat envelope.
     Raises FlatWireUnencodable when a field value cannot ride the flat
-    layout (the caller falls back to the typed envelope)."""
+    layout (the caller sends those votes one by one)."""
     sections = []
     if pps:
         sections.append((KIND_PREPREPARE, len(pps),
@@ -719,53 +691,46 @@ def parse_envelope(data, max_bytes: Optional[int] = None
     return ParsedEnvelope(sections, len(data), stamp)
 
 
-def unwrap_for_tap(payload) -> Optional[list]:
+def unwrap_for_tap(message) -> Optional[list]:
     """The fault-injection unwrap policy, shared by BOTH tap seams
-    (ExternalBus taps and SimNetwork processors): a flat envelope's
-    typed per-message contents, or None when the envelope should be
-    delivered WHOLE — malformed (the receiving node's evidence to
-    judge: per-sender suspicion) or all-entries-invalid (the node's
+    (ExternalBus taps and SimNetwork processors): a ``FlatBatch``'s
+    per-message contents, or None when ``message`` is delivered WHOLE —
+    not an envelope at all, malformed (the receiving node's evidence
+    to judge: per-sender suspicion) or all-entries-invalid (the node's
     own intake does the per-entry dropping and its warn accounting,
     not the tap)."""
+    from plenum_tpu.common.messages.node_messages import FlatBatch
+    if not isinstance(message, FlatBatch):
+        return None
     try:
-        inner = to_legacy_messages(payload)
+        inner = to_legacy_messages(message.payload)
     except FlatWireError:
         return None
     return inner or None
 
 
 def to_legacy_messages(data) -> List:
-    """Re-materialize a flat envelope into the typed messages the
-    per-message wire would have carried (FIFO section order): 3PC
-    sections become individual votes, a PROPAGATE section becomes the
-    legacy Propagate / PropagateBatch. Used by the fault-injection
-    unwrap seams (ExternalBus tap, SimNetwork processors) so adversary
+    """Re-materialize a flat envelope into the messages the
+    per-message wire would have carried (FIFO section order): single
+    votes and single Propagates. Used by the fault-injection unwrap
+    seams (ExternalBus tap, SimNetwork processors) so adversary
     behaviors keep matching on per-type messages; entries that fail
-    validation are dropped exactly as the typed intake would drop
+    validation are dropped exactly as the node's intake would drop
     them."""
-    from plenum_tpu.common.messages.node_messages import (
-        Propagate, PropagateBatch)
+    from plenum_tpu.common.messages.node_messages import Propagate
     env = parse_envelope(data)
     out: List = []
     for sec in env.sections:
         if sec.kind == KIND_PROPAGATE:
-            reqs, clients = [], []
             for i in range(sec.n):
                 try:
-                    reqs.append(sec.request(i))
+                    request = sec.request(i)
                 except Exception:
                     logger.warning("flat wire: bad PROPAGATE entry "
                                    "— dropped")
                     continue
-                clients.append(sec.client(i))
-            if not reqs:
-                continue
-            if len(reqs) == 1:
-                out.append(Propagate(request=reqs[0],
-                                     senderClient=clients[0] or None))
-            else:
-                out.append(PropagateBatch(requests=reqs,
-                                          clients=clients))
+                out.append(Propagate(request=request,
+                                     senderClient=sec.client(i) or None))
         else:
             for i in range(sec.n):
                 msg = sec.materialize(i)

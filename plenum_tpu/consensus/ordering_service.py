@@ -253,7 +253,8 @@ class OrderingService:
         self._commit_vote_count: Dict[Tuple[int, int], int] = {}
         # optional per-node coalescing outbox (ThreePCOutbox): broadcast
         # Prepare/Commit/PrePrepare ride ONE wire batch per tick instead
-        # of a message each; None = legacy per-message sends
+        # of a message each; None (a stand-alone ReplicaService) =
+        # per-message sends
         self.outbox = None
         self.ordered: Set[Tuple[int, int]] = set()
         self.batches: Dict[Tuple[int, int], PrePrepare] = {}  # applied order
@@ -432,7 +433,7 @@ class OrderingService:
 
     def _send_3pc(self, msg):
         """Broadcast one 3PC vote: coalesced through the node's outbox
-        when attached (one THREE_PC_BATCH per tick on the wire), the
+        when attached (one flat envelope per tick on the wire), the
         plain per-message send otherwise."""
         if self.outbox is not None:
             self.outbox.queue(msg)
@@ -690,118 +691,18 @@ class OrderingService:
 
     def bind_owner_thread(self, ident: int) -> None:
         """Pin 3PC intake to the prod thread (pipelined node). Every
-        ``process_*_batch`` / ``process_*_columns`` call off that
-        thread raises — the pipeline's ownership contract (workers
-        parse, the prod thread counts votes) enforced at the seam
-        instead of trusted by convention. Implemented as a sanitizer
-        region pin: identical RuntimeError contract, one guard
-        implementation for the whole node."""
+        ``process_preprepare_batch`` / ``process_prepare_columns`` /
+        ``process_commit_columns`` call off that thread raises — the
+        pipeline's ownership contract (workers parse, the prod thread
+        counts votes) enforced at the seam instead of trusted by
+        convention. Implemented as a sanitizer region pin: identical
+        RuntimeError contract, one guard implementation for the whole
+        node."""
         self._sanitizer.bind_region("prod", int(ident))
         self._sanitizer.pin("3PC intake", "prod")
 
     def _assert_owner(self) -> None:
         self._sanitizer.check("3PC intake")
-
-    def process_prepare_batch(self, prepares: List[Prepare], frm: str):
-        """Columnar PREPARE intake: one sender's wire batch processed in
-        one pass — shared checks hoisted out of the per-item path, the
-        digest column checked against the matching PRE-PREPAREs in ONE
-        vectorized comparison, quorum counters bumped per item, and
-        _try_prepared run once per touched batch instead of once per
-        message."""
-        self._assert_owner()
-        with self.metrics.measure_time(MetricsName.PREPARE_PROCESS_TIME), \
-                self.tracer.span("prepare_batch", CAT_3PC, frm=frm,
-                                 n=len(prepares)):
-            return self._process_prepare_batch(prepares, frm)
-
-    def _process_prepare_batch(self, prepares: List[Prepare], frm: str):
-        survivors = self._columnar_precheck(prepares, frm)
-        if not survivors:
-            return
-        # vote inserts + digest columns for items whose PP is here
-        prepares_store = self.prepares
-        pre_prepares = self.prePrepares
-        checked: List[Tuple[Prepare, PrePrepare]] = []
-        touched: Dict[Tuple[int, int], PrePrepare] = {}
-        for p in survivors:
-            key = (p.viewNo, p.ppSeqNo)
-            if frm in prepares_store[key]:
-                continue   # duplicate PREPARE
-            pp = pre_prepares.get(key)
-            if pp is None:
-                # PRE-PREPARE not here yet: store the vote, it counts
-                # when the PP lands (same as the per-message path)
-                self._add_prepare_vote(key, frm, p)
-                continue
-            checked.append((p, pp))
-        if checked:
-            mask = digest_match_mask(
-                [pp.digest for _, pp in checked],
-                [p.digest for p, _ in checked])
-            for (p, pp), ok in zip(checked, mask):
-                key = (p.viewNo, p.ppSeqNo)
-                if frm in prepares_store[key]:
-                    # duplicate WITHIN this envelope: an earlier entry
-                    # for the same key won the insert while this one
-                    # was already collected (first-valid-wins, exactly
-                    # like sequential per-message processing)
-                    continue
-                if not ok:
-                    self._raise_suspicion(frm, Suspicions.PR_DIGEST_WRONG,
-                                          "PREPARE digest mismatch", p)
-                    continue
-                self._add_prepare_vote(key, frm, p)
-                touched[key] = pp
-        for pp in touched.values():
-            self._try_prepared(pp)
-
-    def _columnar_precheck(self, msgs: list, frm: str,
-                           on_old_view=None) -> list:
-        """The _validate_3pc verdicts for a whole single-sender batch:
-        sender/instance/participation checked ONCE, the view/watermark
-        integer compares inlined per item. Items that must stash are
-        routed into the stasher's normal buckets (their per-message
-        handlers replay them later); survivors are returned for the
-        columnar fast path."""
-        if not msgs:
-            return msgs
-        data = self._data
-        inst_id = data.inst_id
-        if frm not in data.validators:
-            return []                       # DISCARD all: not a validator
-        stash = self._stasher.stash
-        if not data.node_mode_participating:
-            for m in msgs:
-                stash(STASH_CATCH_UP, m, frm)
-            return []
-        view_no = data.view_no
-        waiting_nv = data.waiting_for_new_view
-        low = data.low_watermark
-        high = data.high_watermark
-        out = []
-        for m in msgs:
-            if m.instId != inst_id:
-                continue                    # DISCARD: wrong instance
-            v = m.viewNo
-            if v < view_no:
-                if on_old_view is not None:
-                    on_old_view(m, frm)
-                continue                    # DISCARD: old view
-            if v > view_no:
-                stash(STASH_VIEW_3PC, m, frm)
-                continue
-            if waiting_nv:
-                stash(STASH_VIEW_3PC, m, frm)
-                continue
-            s = m.ppSeqNo
-            if s <= low:
-                continue                    # DISCARD: below low watermark
-            if s > high:
-                stash(STASH_WATERMARKS, m, frm)
-                continue
-            out.append(m)
-        return out
 
     def process_prepare_columns(self, cols, frm: str):
         """Flat-wire PREPARE intake: the parsed envelope columns
@@ -851,7 +752,7 @@ class OrderingService:
                     continue
                 p = cols.materialize(i)
                 if p is None:
-                    continue   # bad entry: dropped like the typed path
+                    continue   # bad entry: costs only itself
                 if not ok:
                     self._raise_suspicion(frm, Suspicions.PR_DIGEST_WRONG,
                                           "PREPARE digest mismatch", p)
@@ -907,10 +808,18 @@ class OrderingService:
                 bls.retry_backfill(key, self.commits[key], pp,
                                    self._data.quorums)
 
+    # The SECOND of the two 3PC verdict tables. ``_validate_3pc`` (the
+    # per-message path) is its specification: a rule changed there is
+    # changed here. tests/test_columnar_3pc.py
+    # (test_columnar_equals_per_message_*) and tests/test_flat_wire.py
+    # (test_flat_intake_equals_per_message_*) replay randomized vote
+    # streams through both and hold every store, stash and suspicion
+    # equal.
     def _precheck_columns(self, cols, frm: str,
                           on_old_view=None) -> List[int]:
-        """``_columnar_precheck`` evaluated over parsed flat columns:
-        the sender/participation gates run once, then ONE pass of
+        """The ``_validate_3pc`` verdicts for one sender's parsed flat
+        columns: sender/instance/participation checked ONCE, then ONE
+        pass of
         C-level int compares over the column values (``tolist`` of the
         numpy views — at wire-typical envelope sizes scalar compares
         beat numpy temporaries by an order of magnitude, the same
@@ -1086,46 +995,6 @@ class OrderingService:
         candidates.setdefault(frm, commit)
         return self._bls.retry_backfill(key, candidates, pp,
                                         self._data.quorums)
-
-    def process_commit_batch(self, commits: List[Commit], frm: str):
-        """Columnar COMMIT intake: one sender's wire batch in one pass
-        (hoisted checks, counter bumps, one _try_order per touched
-        key). BLS share validation stays per item — each COMMIT carries
-        its own share."""
-        self._assert_owner()
-        with self.metrics.measure_time(MetricsName.COMMIT_PROCESS_TIME), \
-                self.tracer.span("commit_batch", CAT_3PC, frm=frm,
-                                 n=len(commits)):
-            return self._process_commit_batch(commits, frm)
-
-    def _process_commit_batch(self, commits: List[Commit], frm: str):
-        survivors = self._columnar_precheck(
-            commits, frm, on_old_view=self._late_commit_backfill)
-        if not survivors:
-            return
-        commits_store = self.commits
-        pre_prepares = self.prePrepares
-        bls = self._bls
-        touched: Dict[Tuple[int, int], PrePrepare] = {}
-        for c in survivors:
-            key = (c.viewNo, c.ppSeqNo)
-            if frm in commits_store[key]:
-                continue   # duplicate COMMIT
-            pp = pre_prepares.get(key)
-            if bls is not None and pp is not None:
-                err = bls.validate_commit(c, frm, pp)
-                if err:
-                    self._raise_suspicion(frm, Suspicions.CM_BLS_SIG_WRONG,
-                                          err, c)
-                    continue
-            self._add_commit_vote(key, frm, c)
-            if pp is not None:
-                touched[key] = pp
-        for key, pp in touched.items():
-            self._try_order(pp)
-            if key in self.ordered and bls is not None:
-                bls.retry_backfill(key, self.commits[key], pp,
-                                   self._data.quorums)
 
     def process_preprepare_batch(self, pps: List[PrePrepare], frm: str):
         """PRE-PREPAREs from one wire batch: low-volume (one per

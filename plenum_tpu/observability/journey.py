@@ -8,7 +8,7 @@ next: for one ordered request, WHERE did its wall-clock go ACROSS the
 pool — the wire, waiting for the slowest quorum voter, or local
 stages?  This module joins the per-node tracer buffers (or an exported
 Chrome trace document — both forms carry the same records) with the
-wire-carried trace stamps (flat_wire KIND_TRACE / typed ``traceCtx``)
+wire-carried trace stamps (flat_wire KIND_TRACE)
 into:
 
 * **per-request journeys**, keyed by request digest and joined to the

@@ -8,38 +8,6 @@ send handler is the transport (or the SimNetwork in tests).
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Type
 
 
-def _unwrap_three_pc_batch(message) -> Optional[list]:
-    """Inner typed messages of a coalesced envelope — THREE_PC_BATCH or
-    a flat-wire FLAT_WIRE envelope — or None when `message` is neither.
-    Lazy import: the runtime layer must stay importable without the
-    message schema module loaded. Dict entries (a real-transport typed
-    envelope) are reconstructed through the message factory and flat
-    payloads re-materialized through the codec so the tap always sees
-    typed per-message granularity; an unreconstructable entry is
-    dropped here exactly as the node's own intake would drop it."""
-    from plenum_tpu.common.messages.node_messages import (
-        FlatBatch, ThreePCBatch)
-    if isinstance(message, FlatBatch):
-        from plenum_tpu.common.serializers import flat_wire
-        # malformed / all-entries-invalid envelopes pass through WHOLE
-        # (the receiving node owns that judgement) — the policy is
-        # single-sourced next to the codec
-        return flat_wire.unwrap_for_tap(message.payload)
-    if not isinstance(message, ThreePCBatch):
-        return None
-    from plenum_tpu.common.messages.message_factory import (
-        node_message_factory)
-    out = []
-    for entry in message.messages:
-        if isinstance(entry, dict):
-            try:
-                entry = node_message_factory.get_instance(**entry)
-            except Exception:
-                continue
-        out.append(entry)
-    return out
-
-
 class Router:
     """Maps message type → list of handlers; dispatch is synchronous."""
 
@@ -135,7 +103,10 @@ class ExternalBus(Router):
             # votes, and an envelope passed through whole would smuggle
             # every inner vote past them — the receive-side mirror of
             # the ThreePCOutbox per-message degrade on the send side
-            inner = _unwrap_three_pc_batch(message)
+            # (lazy import: the runtime layer stays importable
+            # without the message schema and codec loaded)
+            from plenum_tpu.common.serializers import flat_wire
+            inner = flat_wire.unwrap_for_tap(message)
             if inner is not None:
                 result = None
                 for entry in inner:

@@ -28,10 +28,9 @@ from plenum_tpu.common.constants import (
     NODE, NYM, POOL_LEDGER_ID, VERKEY)
 from plenum_tpu.common.exceptions import InvalidClientMessageException
 from plenum_tpu.common.messages.client_request import ClientMessageValidator
-from plenum_tpu.common.messages.message_factory import node_message_factory
 from plenum_tpu.common.messages.node_messages import (
-    Commit, FlatBatch, Ordered, Prepare, PrePrepare, Propagate,
-    PropagateBatch, Reject, Reply, RequestAck, RequestNack, ThreePCBatch)
+    FlatBatch, Ordered, Propagate, Reject, Reply, RequestAck,
+    RequestNack)
 from plenum_tpu.common.serializers import flat_wire
 from plenum_tpu.common.request import Request
 from plenum_tpu.common.txn_util import (
@@ -409,23 +408,16 @@ class Node:
             on_backup_pp_sent=self.last_sent_pp_store.store_last_sent)
 
         # ---- columnar 3PC wire path: every instance's broadcast votes
-        # coalesce into ONE THREE_PC_BATCH per tick (flushed at the end
+        # coalesce into ONE flat envelope per tick (flushed at the end
         # of service()); inbound envelopes route into the columnar
-        # process_*_batch intake per instance. Incoming batches are
-        # always understood (peers may coalesce regardless of our own
-        # sending config).
+        # process_*_columns intake per instance. Single votes (a
+        # tapped sender, a chunk the flat layout refused) arrive
+        # through each replica's own per-message subscriptions.
         from plenum_tpu.server.three_pc_outbox import ThreePCOutbox
-        self._outbox_3pc = None
         self._outbox_flush_armed = False
-        flat_wire_on = getattr(self.config, "FLAT_WIRE", True)
-        if getattr(self.config, "THREE_PC_BATCH_WIRE", True):
-            self._outbox_3pc = ThreePCOutbox(
-                network, msg_len_limit=self.config.MSG_LEN_LIMIT,
-                flat_wire_enabled=flat_wire_on)
-            self.replicas.set_outbox(self._outbox_3pc)
-        network.subscribe(ThreePCBatch, self._process_three_pc_batch)
-        # flat zero-copy envelopes are always understood, whatever our
-        # own sending config (peers choose their wire independently)
+        self._outbox_3pc = ThreePCOutbox(
+            network, msg_len_limit=self.config.MSG_LEN_LIMIT)
+        self.replicas.set_outbox(self._outbox_3pc)
         network.subscribe(FlatBatch, self._process_flat_batch)
 
         # ---- propagation
@@ -450,12 +442,9 @@ class Node:
             forward_handler=self._forward_finalised,
             authenticator=authenticate_propagated,
             forward_batch_handler=self._forward_finalised_batch,
-            flat_wire_enabled=flat_wire_on,
             already_ordered=lambda request:
                 self._committed_at(request) is not None)
         network.subscribe(Propagate, self.propagator.process_propagate)
-        network.subscribe(PropagateBatch,
-                          self.propagator.process_propagate_batch)
 
         self._validator = ClientMessageValidator()
 
@@ -526,9 +515,8 @@ class Node:
             and getattr(self.tracer, "enabled", False)
         self._trace_ctx = _trace_ctx
         self.propagator.trace_context = _trace_ctx
-        if self._outbox_3pc is not None:
-            self._outbox_3pc.trace_context = _trace_ctx
-            self._outbox_3pc.origin = name
+        self._outbox_3pc.trace_context = _trace_ctx
+        self._outbox_3pc.origin = name
         # telemetry rides the same single-injection-point pattern: the
         # executor times the execute/fused-dispatch stages, the
         # ordering service the 3PC stage, the view changer counts
@@ -1425,8 +1413,7 @@ class Node:
         """Arm the deferred vote flush when an inbound delivery left
         provoked votes in the 3PC outbox — shared by the serial
         delivery path and the pipeline drain."""
-        if self._outbox_3pc is not None and len(self._outbox_3pc) \
-                and not self._outbox_flush_armed:
+        if len(self._outbox_3pc) and not self._outbox_flush_armed:
             self._outbox_flush_armed = True
             self.timer.schedule(
                 getattr(self.config, "THREE_PC_FLUSH_WINDOW", 0.002),
@@ -1444,63 +1431,7 @@ class Node:
         consensus timeouts, and the prod-tick flush in service() still
         bounds the wait when the timer is starved."""
         self._outbox_flush_armed = False
-        if self._outbox_3pc is not None:
-            self._outbox_3pc.flush()
-
-    def _process_three_pc_batch(self, msg: ThreePCBatch, frm: str):
-        """Inbound coalesced 3PC envelope: reconstruct wire entries,
-        split by protocol instance, and feed each instance's columnar
-        intake — PRE-PREPAREs first, then PREPAREs, then COMMITs (a
-        sender's envelope is FIFO, and no sender emits a vote before
-        its own earlier-phase vote for the same key, so phase-major
-        processing preserves per-sender causality)."""
-        ctx = getattr(msg, "traceCtx", None)
-        if ctx is not None:
-            self._note_wire_stamp(
-                flat_wire.TraceStamp.from_wire(ctx), frm, CAT_3PC)
-        groups: Dict[int, Tuple[list, list, list]] = {}
-        # the typed path's receive-side deserialization cost — one
-        # factory reconstruction per inner vote — is the `parse` stage
-        # the flat codec's single-parse replaces; span it so the A/B
-        # reads off scripts/trace_budget instead of being inferred
-        with self.tracer.span("wire_parse", CAT_3PC,
-                              n=len(msg.messages)):
-            for entry in msg.messages:
-                if isinstance(entry, dict):
-                    try:
-                        entry = node_message_factory.get_instance(**entry)
-                    except Exception as e:
-                        logger.warning(
-                            "%s: bad entry in THREE_PC_BATCH from %s: %s",
-                            self.name, frm, e)
-                        continue
-                if isinstance(entry, PrePrepare):
-                    idx = 0
-                elif isinstance(entry, Prepare):
-                    idx = 1
-                elif isinstance(entry, Commit):
-                    idx = 2
-                else:
-                    logger.warning(
-                        "%s: non-3PC entry %s in THREE_PC_BATCH from %s "
-                        "— dropped", self.name, type(entry).__name__, frm)
-                    continue
-                inst_id = entry.instId
-                group = groups.get(inst_id)
-                if group is None:
-                    group = groups[inst_id] = ([], [], [])
-                group[idx].append(entry)
-        for inst_id, (pps, prepares, commits) in groups.items():
-            replica = self.replicas.get(inst_id)
-            if replica is None:
-                continue   # fewer instances here than at the sender
-            ordering = replica.ordering
-            if pps:
-                ordering.process_preprepare_batch(pps, frm)
-            if prepares:
-                ordering.process_prepare_batch(prepares, frm)
-            if commits:
-                ordering.process_commit_batch(commits, frm)
+        self._outbox_3pc.flush()
 
     def _process_flat_batch(self, msg: FlatBatch, frm: str):
         """Inbound flat zero-copy envelope: ONE parse turns the payload
@@ -1512,8 +1443,7 @@ class Node:
         the full stash/verdict machinery), then PREPARE columns, then
         COMMIT columns. A structurally invalid envelope raises a
         per-sender suspicion and is dropped whole — it can never crash
-        the prod loop; a bad ENTRY costs only itself, like a bad entry
-        in a typed THREE_PC_BATCH."""
+        the prod loop; a bad ENTRY costs only itself."""
         payload = msg.payload
         try:
             with self.tracer.span(
@@ -2125,14 +2055,13 @@ class Node:
             if self._pipeline is not None:
                 self._pipeline.drain()
             # propagates queued this tick (intake + batch echoes) leave
-            # as ONE PROPAGATE_BATCH before consensus work runs
+            # as ONE flat envelope before consensus work runs
             self.propagator.flush()
             count = self.replicas.service()
             # every instance's 3PC votes queued this tick (from
             # send_3pc_batch above AND from inbound processing since the
-            # last tick) leave as ONE THREE_PC_BATCH
-            if self._outbox_3pc is not None:
-                self._outbox_3pc.flush()
+            # last tick) leave as ONE flat envelope
+            self._outbox_3pc.flush()
             return count
 
     # ------------------------------------------------------- inspection

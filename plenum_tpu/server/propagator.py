@@ -10,10 +10,8 @@ from __future__ import annotations
 import logging
 from typing import Callable, Dict, Optional, Set
 
-import msgpack
-
 from plenum_tpu.common.messages.node_messages import (
-    FlatBatch, Propagate, PropagateBatch)
+    FlatBatch, Propagate)
 from plenum_tpu.common.request import Request
 from plenum_tpu.common.serializers import flat_wire
 from plenum_tpu.common.serializers.serializers import MsgPackSerializer
@@ -25,24 +23,6 @@ from plenum_tpu.utils.metrics import MetricsName, NullMetricsCollector
 _wire_serializer = MsgPackSerializer()
 
 logger = logging.getLogger(__name__)
-
-
-def _payload_size(payload: dict) -> int:
-    """Serialized size estimate for batch budgeting (exact when the C
-    canonical packer is available; real msgpack size otherwise — a flat
-    guess would under-count multi-KB ATTRIB raws, letting a batch exceed
-    the transport frame limit and be dropped wholesale)."""
-    if _fp is not None:
-        try:
-            return len(_fp.canonical_msgpack(payload)) + 16
-        except TypeError:
-            pass
-    try:
-        return len(msgpack.packb(payload, use_bin_type=True)) + 16
-    except Exception:
-        # unpackable oddity: assume the worst entry the budget accepts
-        # 40 of rather than dropping the propagate entirely
-        return 3 * 1024
 
 
 def _strict_deep_eq_py(a, b) -> bool:
@@ -163,8 +143,8 @@ class Requests(dict):
 
 
 class Propagator:
-    # upper bound on entries per PROPAGATE_BATCH; the size budget below
-    # is the real wire guard
+    # upper bound on entries per envelope; the size budget below is
+    # the real wire guard
     BATCH_LIMIT = 200
     # serialized-payload budget per batch: MSG_LEN_LIMIT (128 KiB) minus
     # generous envelope/AEAD headroom — chunking by count alone would
@@ -176,7 +156,6 @@ class Propagator:
                  forward_handler: Callable[[Request], None],
                  authenticator: Callable[[Request], bool] = None,
                  forward_batch_handler: Callable[[list], None] = None,
-                 flat_wire_enabled: bool = False,
                  already_ordered: Callable[[Request], bool] = None):
         """network: ExternalBus; forward_handler: called exactly once per
         finalised request (feeds ordering queues). authenticator(request)
@@ -187,7 +166,7 @@ class Propagator:
         by the TamperedPropagate adversary scenario). Requests from the
         client intake path were authenticated there already.
         forward_batch_handler(requests): optional columnar forward — all
-        requests finalised by ONE inbound PROPAGATE_BATCH go to the
+        requests finalised by ONE inbound envelope go to the
         ordering queues as one contiguous digest column (one downstream
         stash-replay per batch instead of per request).
         already_ordered(request) → bool: the node's dedup index says
@@ -206,11 +185,9 @@ class Propagator:
         # flat zero-copy wire (common/serializers/flat_wire.py): each
         # queued payload is packed ONCE at queue time — the same bytes
         # feed the size budget AND the envelope, so the old pack-for-
-        # sizing-then-discard cost disappears. Degrades to the typed
-        # Propagate/PropagateBatch wire while an adversary tap is
-        # installed (per-message granularity IS the fault-injection
-        # seam) or when the flag is off.
-        self._flat = flat_wire_enabled
+        # sizing-then-discard cost disappears. Under an adversary tap
+        # every request leaves as its own Propagate (per-message
+        # granularity IS the fault-injection seam).
         self.requests = Requests()
         self.metrics = NullMetricsCollector()   # node injects the real one
         self.tracer = NullTracer()              # node injects the real one
@@ -219,10 +196,11 @@ class Propagator:
         # keeps this seam free
         self.trace_context = False
         self._flush_seq = 0
-        # queued outgoing propagates, flushed as PROPAGATE_BATCH once
-        # per tick: at n validators every request is otherwise its own
-        # message n-1 times per node — batching is what lets wide pools
-        # (25 nodes) drain instead of drowning in per-message overhead
+        # queued outgoing propagates, flushed as one envelope per chunk
+        # once per tick: at n validators every request is otherwise its
+        # own message n-1 times per node — batching is what lets wide
+        # pools (25 nodes) drain instead of drowning in per-message
+        # overhead
         self._out: list = []
 
     def update_quorums(self, quorums: Quorums):
@@ -261,21 +239,18 @@ class Propagator:
         self._try_finalise(request.key)
 
     def _queue_out(self, payload: dict, client_name) -> None:
-        if self._flat:
-            try:
-                raw = _wire_serializer.serialize(payload)
-                # estimate covers the client-id string + per-entry
-                # offset-table overhead too; the post-encode split in
-                # _send_flat_chunk backstops any remaining lag
-                self._out.append((payload, client_name,
-                                  len(raw) + len(client_name or "") + 24,
-                                  raw))
-                return
-            except Exception:
-                # unpackable oddity: ride the typed fallback below
-                pass
-        self._out.append((payload, client_name, _payload_size(payload),
-                          None))
+        try:
+            raw = _wire_serializer.serialize(payload)
+        except Exception:
+            # unpackable oddity: its chunk leaves as single Propagates;
+            # sized as the worst entry the budget accepts 40 of
+            self._out.append((payload, client_name, 3 * 1024, None))
+            return
+        # estimate covers the client-id string + per-entry offset-table
+        # overhead too; the post-encode split in _send_flat_chunk
+        # backstops any remaining lag
+        self._out.append((payload, client_name,
+                          len(raw) + len(client_name or "") + 24, raw))
 
     def flush(self) -> int:
         """Send everything queued since the last flush, chunked under
@@ -292,8 +267,7 @@ class Propagator:
 
     def _flush(self) -> int:
         out, self._out = self._out, []
-        flat = self._flat and not getattr(self._network, "has_tap",
-                                          False)
+        flat = not getattr(self._network, "has_tap", False)
 
         def send_chunk(chunk):
             if flat and all(e[3] is not None for e in chunk):
@@ -301,21 +275,14 @@ class Propagator:
                     self._send_flat_chunk(chunk)
                     return
                 except flat_wire.FlatWireUnencodable as e:
-                    # cannot ride the flat layout: typed fallback below
-                    logger.debug("propagator: flat encode fell back "
-                                 "(%s)", e)
-            if len(chunk) == 1:
-                # bare single-request sends carry no stamp — the
-                # context is advisory and the batch forms carry it
-                self._network.send(Propagate(request=chunk[0][0],
-                                             senderClient=chunk[0][1]))
-            else:
-                stamp = self._next_stamp()
-                self._network.send(PropagateBatch(
-                    requests=[r for r, _, _, _ in chunk],
-                    clients=[c or "" for _, c, _, _ in chunk],
-                    traceCtx=stamp.as_list() if stamp else None))
-                self._note_send(stamp, len(chunk), 0)
+                    logger.debug("propagator: flat encode refused (%s);"
+                                 " chunk sent per message", e)
+            # under a tap, or a chunk the flat layout cannot carry:
+            # single Propagates in queue order; they carry no stamp —
+            # the context is advisory and the envelope carries it
+            for payload, client, _, _ in chunk:
+                self._network.send(Propagate(request=payload,
+                                             senderClient=client))
 
         chunk, chunk_size = [], 0
         for entry in out:
@@ -364,56 +331,6 @@ class Propagator:
                 self.tracer.span("propagate_process", CAT_PROPAGATE,
                                  n=1, frm=frm):
             self._process_one(msg.request, msg.senderClient, frm)
-
-    def process_propagate_batch(self, msg: PropagateBatch, frm: str):
-        self.note_wire_stamp(getattr(msg, "traceCtx", None), frm)
-        with self.metrics.measure_time(MetricsName.PROPAGATE_PROCESS_TIME), \
-                self.tracer.span("propagate_process", CAT_PROPAGATE,
-                                 n=len(msg.requests), frm=frm):
-            self._process_propagate_batch(msg, frm)
-
-    def note_wire_stamp(self, ctx, frm: str) -> None:
-        """Advisory typed-fallback stamp intake: decode the nullable
-        traceCtx field and record a receive-side anchor instant. Every
-        failure mode is swallowed into 'no journey hop' — the stamp can
-        never affect propagate handling (plenum-lint PT015 pins this
-        unreachability from consensus)."""
-        if ctx is None or not self.tracer.enabled:
-            return
-        stamp = flat_wire.TraceStamp.from_wire(ctx)
-        if stamp is None:
-            return
-        recv_perf, recv_wall = self.tracer.clock_pair()
-        self.tracer.instant(
-            "wire_recv", CAT_PROPAGATE,
-            key="%s:%d" % (stamp.origin, stamp.seq),
-            origin=stamp.origin, seq=stamp.seq, frm=frm,
-            sent_perf=stamp.perf_ts, sent_wall=stamp.wall_ts,
-            recv_wall=recv_wall)
-
-    def _process_propagate_batch(self, msg: PropagateBatch, frm: str):
-        clients = msg.clients or [""] * len(msg.requests)
-        if len(clients) != len(msg.requests):
-            # malformed (byzantine?) batch: dropping it silently via zip
-            # truncation would make a protocol violation invisible
-            logger.warning(
-                "%s: PROPAGATE_BATCH from %s with %d requests but %d "
-                "clients — discarded", self.name, frm,
-                len(msg.requests), len(clients))
-            return
-        if self._forward_batch is None:
-            for payload, client in zip(msg.requests, clients):
-                self._process_one(payload, client or None, frm)
-            return
-        # columnar finalisation: requests that reach quorum inside this
-        # batch collect into one forward call — their digests stay a
-        # contiguous column all the way into the ordering queues
-        finalised: list = []
-        for payload, client in zip(msg.requests, clients):
-            self._process_one(payload, client or None, frm,
-                              finalise_sink=finalised)
-        if finalised:
-            self._forward_batch([s.request for s in finalised])
 
     def process_propagate_columns(self, cols, frm: str):
         """Flat-wire PROPAGATE intake: the parsed section hands each
@@ -520,8 +437,8 @@ class Propagator:
         propagate-close attribution), forward exactly once. The digest
         access is free here — forwarding hands request.key to the
         ordering queues anyway. With a `sink` the caller owns
-        forwarding (batch path: one columnar forward per inbound
-        PROPAGATE_BATCH)."""
+        forwarding (envelope path: one columnar forward per inbound
+        envelope)."""
         state.finalised = True
         state.forwarded = True
         if self.trace_context:

@@ -140,7 +140,7 @@ class Replicas:
     def set_outbox(self, outbox) -> None:
         """Attach one node-wide coalescing 3PC outbox to every protocol
         instance — current AND future backups (all instances' broadcast
-        votes ride the same per-tick THREE_PC_BATCH)."""
+        votes ride the same per-tick flat envelope)."""
         self._outbox = outbox
         for replica in self._replicas.values():
             replica.ordering.outbox = outbox

@@ -8,11 +8,11 @@ every instance's broadcast votes during a prod tick and flushes them as
 ONE wire message per peer: a flat zero-copy ``FlatBatch`` envelope
 (common/serializers/flat_wire.py — PREPARE/COMMIT votes as contiguous
 typed columns, PRE-PREPAREs as a length-prefixed section; one pack for
-the whole tick) when ``Config.FLAT_WIRE`` is on, else the typed
-``ThreePCBatch`` envelope (one msgpack pack on the socket path). The
-receiving node routes flat envelopes into the columnar
-``process_*_columns`` intake with zero intermediate message objects,
-typed envelopes into ``process_*_batch``.
+the whole tick). The receiving node routes the envelope into the
+columnar ``process_*_columns`` intake with zero intermediate message
+objects. There are two wires and no option between them: the flat
+envelope, and single votes — sent while the bus has a tap, and for one
+chunk when the flat layout cannot carry a value of it.
 
 Correctness notes:
 
@@ -47,7 +47,7 @@ import logging
 from typing import List
 
 from plenum_tpu.common.messages.node_messages import (
-    Commit, FlatBatch, PrePrepare, ThreePCBatch)
+    Commit, FlatBatch, PrePrepare)
 from plenum_tpu.common.serializers import flat_wire
 from plenum_tpu.observability.tracing import CAT_3PC, NullTracer
 from plenum_tpu.observability.telemetry import TM, get_seam_hub
@@ -111,13 +111,11 @@ class ThreePCOutbox:
     # entry-count cap per envelope; the size budget is the real guard
     BATCH_LIMIT = 300
 
-    def __init__(self, network, msg_len_limit: int = 128 * 1024,
-                 flat_wire_enabled: bool = True):
+    def __init__(self, network, msg_len_limit: int = 128 * 1024):
         self._network = network
         # generous envelope/AEAD headroom, like the propagator's budget
         self._size_budget = msg_len_limit - 8 * 1024
         self._out: List = []
-        self._flat = flat_wire_enabled
         self.size_model = EnvelopeSizeModel()
         self.tracer = NullTracer()   # node injects the real one
         # journey plane: node sets origin + trace_context from config;
@@ -175,23 +173,19 @@ class ThreePCOutbox:
             for m in out:
                 send(m)
             return
-        if self._flat:
-            self._flush_flat(out, send)
-            return
-        self._flush_typed(out, send)
-
-    # ------------------------------------------------------- flat wire
-
-    def _flush_flat(self, out: List, send) -> None:
         for chunk in self._chunks(out):
             try:
                 self._send_flat_chunk(chunk, send)
             except flat_wire.FlatWireUnencodable as e:
                 # a field value the flat layout cannot carry: THIS
-                # chunk rides the validated typed fallback (already-
-                # sent chunks stay sent — chunking is FIFO-safe)
-                logger.debug("3PC outbox: flat encode fell back (%s)", e)
-                self._flush_typed(chunk, send)
+                # chunk's votes leave one by one, in queue order, with
+                # no stamp (already-sent chunks stay sent — chunking
+                # is FIFO-safe; encoding fails before anything of the
+                # chunk is sent)
+                logger.debug("3PC outbox: flat encode refused (%s); "
+                             "chunk sent per message", e)
+                for m in chunk:
+                    send(m)
 
     def _chunks(self, out: List):
         estimate = self.size_model.estimate
@@ -266,19 +260,3 @@ class ThreePCOutbox:
                 model.note_commits(len(payload), count)
             elif kind == flat_wire.KIND_PREPREPARE:
                 model.note_preprepares(len(payload), count, digests)
-
-    # --------------------------------------------- typed-object fallback
-
-    def _flush_typed(self, out: List, send) -> None:
-        for chunk in self._chunks(out):
-            if len(chunk) == 1:
-                # bare single-vote sends carry no stamp — the context
-                # is advisory and the envelope kinds are its carriers
-                send(chunk[0])
-            else:
-                stamp = self._next_stamp()
-                send(ThreePCBatch(
-                    messages=chunk,
-                    traceCtx=stamp.as_list() if stamp else None))
-                self._note_send(stamp, len(chunk), 0)
-                self.flushed_batches += 1

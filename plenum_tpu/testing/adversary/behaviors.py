@@ -17,7 +17,7 @@ from typing import List, Optional, Tuple
 
 from plenum_tpu.common.messages.node_messages import (
     CatchupRep, Commit, ConsistencyProof, MessageRep, NewView,
-    PrePrepare, Prepare, Propagate, PropagateBatch)
+    PrePrepare, Prepare, Propagate)
 
 logger = logging.getLogger(__name__)
 
@@ -186,12 +186,6 @@ class TamperedPropagate(Behavior):
                 (msg.request or {}).get("reqId")))
             return [(Propagate(request=self._tamper(msg.request),
                                senderClient=msg.senderClient), dst)]
-        if isinstance(msg, PropagateBatch):
-            self.record("tampered propagate batch n={}".format(
-                len(msg.requests)))
-            return [(PropagateBatch(
-                requests=[self._tamper(r) for r in msg.requests],
-                clients=list(msg.clients)), dst)]
         return None
 
 

@@ -106,24 +106,6 @@ class Delay(Processor):
         return True
 
 
-def _unwrap_envelope(message):
-    """Constituent typed messages of a coalesced wire envelope
-    (THREE_PC_BATCH or a flat-wire FLAT_WIRE payload), or None when
-    `message` is not one. Local imports: the sim network must stay
-    importable without the full message schema module loaded first."""
-    from plenum_tpu.common.messages.node_messages import (
-        FlatBatch, ThreePCBatch)
-    if isinstance(message, ThreePCBatch):
-        return list(message.messages)
-    if isinstance(message, FlatBatch):
-        from plenum_tpu.common.serializers import flat_wire
-        # malformed / all-entries-invalid envelopes deliver WHOLE so
-        # the receiving node does the judging, exactly like real
-        # transport — the policy is single-sourced next to the codec
-        return flat_wire.unwrap_for_tap(message.payload)
-    return None
-
-
 class SimNetwork:
     def __init__(self, timer: MockTimer, random: Optional[SimRandom] = None,
                  serialize_deserialize: Callable[[Any], Any] = None,
@@ -222,15 +204,17 @@ class SimNetwork:
             else:
                 dsts = list(dst)
             # fault injection needs per-message wire granularity: while
-            # processors are installed, coalesced envelopes (typed
-            # THREE_PC_BATCH and flat FLAT_WIRE alike) unwrap into
-            # their constituent votes so drop/delay/stash/tap filters
-            # (and per-message latency draws) behave exactly as on the
-            # legacy per-message wire. Uninstrumented pools keep the
+            # processors are installed, flat envelopes unwrap into
+            # their constituent messages so drop/delay/stash/tap
+            # filters (and per-message latency draws) behave exactly
+            # as on the per-message wire. Uninstrumented pools keep the
             # envelope whole — one delivery per peer per flush.
             messages = [message]
             if self.processors:
-                inner = _unwrap_envelope(message)
+                # (lazy import: the sim network stays importable
+                # without the message schema and codec loaded)
+                from plenum_tpu.common.serializers import flat_wire
+                inner = flat_wire.unwrap_for_tap(message)
                 if inner is not None:
                     messages = inner
             for d in dsts:

@@ -1,8 +1,9 @@
-"""Batched-vs-per-message 3PC equivalence.
+"""Columnar-vs-per-message 3PC equivalence.
 
-The columnar intake (`process_prepare_batch` / `process_commit_batch`
-/ `process_preprepare_batch` + the coalesced THREE_PC_BATCH wire) is a
-pure dataflow refactor: for ANY inbound message stream — stragglers,
+The columnar intake (`process_prepare_columns` /
+`process_commit_columns` / `process_preprepare_batch`, fed by the flat
+envelope) is a pure dataflow refactor of the per-message wire: for ANY
+inbound message stream — stragglers,
 duplicates, conflicting digests from the PR-1 adversary, wrong
 instances, future views, watermark strays, a view change mid-batch —
 the replica must end in the SAME observable state as a reference
@@ -17,8 +18,9 @@ Rungs:
   other replays the same messages one by one through the stashing
   router (the per-message wire's exact delivery path).
 * e2e — two full 4-node sim pools running the identical deterministic
-  workload, THREE_PC_BATCH_WIRE on vs off: byte-equal ledger + state
-  roots and identical ordered txn sequence at drain.
+  workload, one on the flat wire and one held to the per-message wire
+  by a pass-through tap on every bus: byte-equal ledger + state roots
+  and identical ordered txn sequence at drain.
 """
 import random
 
@@ -29,6 +31,7 @@ from plenum_tpu.common.messages.internal_messages import (
     NewViewAccepted, RaisedSuspicion, ViewChangeStarted)
 from plenum_tpu.common.messages.node_messages import (
     Commit, PrePrepare, Prepare)
+from plenum_tpu.common.serializers import flat_wire as fw
 from tests.test_3pc_verdicts import (
     VALIDATORS, KnownSetExecutor, make_pp, make_replica)
 
@@ -54,20 +57,27 @@ def make_commit_for(pp, frm_view=None):
                   ppSeqNo=pp.ppSeqNo)
 
 
-def feed_columnar(replica, envelopes):
-    """The Node._process_three_pc_batch routing: one sender's envelope
-    split phase-major into the columnar intake."""
+def feed_flat(replica, envelopes):
+    """The wire-accurate flat feed: each sender envelope is ENCODED to
+    flat bytes, parsed, and routed exactly as Node._process_flat_batch
+    routes sections (PPs materialized through the stasher, vote columns
+    straight into process_*_columns)."""
     o = replica.ordering
     for frm, msgs in envelopes:
         pps = [m for m in msgs if isinstance(m, PrePrepare)]
         prepares = [m for m in msgs if isinstance(m, Prepare)]
         commits = [m for m in msgs if isinstance(m, Commit)]
-        if pps:
-            o.process_preprepare_batch(pps, frm)
-        if prepares:
-            o.process_prepare_batch(prepares, frm)
-        if commits:
-            o.process_commit_batch(commits, frm)
+        env = fw.parse_envelope(fw.encode_three_pc(pps, prepares,
+                                                   commits))
+        for sec in env.sections:
+            if sec.kind == fw.KIND_PREPREPARE:
+                batch = [sec.materialize(i) for i in range(sec.n)]
+                o.process_preprepare_batch(
+                    [m for m in batch if m is not None], frm)
+            elif sec.kind == fw.KIND_PREPARE:
+                o.process_prepare_columns(sec, frm)
+            elif sec.kind == fw.KIND_COMMIT:
+                o.process_commit_columns(sec, frm)
 
 
 def feed_per_message(replica, envelopes):
@@ -187,12 +197,14 @@ def gen_stream(rng, n_batches=4, reqs_per_batch=3):
 
 # ------------------------------------------------------------------ unit
 
-@pytest.mark.parametrize("seed", range(12))
+# seeds 0-11 run through the same feed in tests/test_flat_wire.py
+# (test_flat_intake_equals_per_message_randomized): no stream twice
+@pytest.mark.parametrize("seed", range(12, 24))
 def test_columnar_equals_per_message_randomized(seed):
     rng = random.Random(seed)
     envelopes, known = gen_stream(rng)
     (ra, sus_a), (rb, sus_b) = build_pair(known)
-    feed_columnar(ra, envelopes)
+    feed_flat(ra, envelopes)
     feed_per_message(rb, envelopes)
     assert snapshot(ra, sus_a) == snapshot(rb, sus_b)
     # the stream actually ordered something (vacuous equality guard)
@@ -210,7 +222,7 @@ def test_columnar_equals_per_message_across_view_change(seed):
     envelopes, known = gen_stream(rng)
     cut = rng.randint(1, len(envelopes) - 1)
     (ra, sus_a), (rb, sus_b) = build_pair(known)
-    for replica, feed in ((ra, feed_columnar), (rb, feed_per_message)):
+    for replica, feed in ((ra, feed_flat), (rb, feed_per_message)):
         feed(replica, envelopes[:cut])
         replica.internal_bus.send(ViewChangeStarted(view_no=1))
         replica.data.primary_name = "Beta"
@@ -228,7 +240,7 @@ def test_columnar_batch_with_only_garbage_is_noop():
     (ra, sus_a), (rb, sus_b) = build_pair([])
     junk = [("Gamma", [Commit(instId=5, viewNo=0, ppSeqNo=1),
                        Commit(instId=0, viewNo=0, ppSeqNo=0)])]
-    feed_columnar(ra, junk)
+    feed_flat(ra, junk)
     feed_per_message(rb, junk)
     assert snapshot(ra, sus_a) == snapshot(rb, sus_b)
     assert not ra.ordering.commits
@@ -236,14 +248,16 @@ def test_columnar_batch_with_only_garbage_is_noop():
 
 # ------------------------------------------------------------------- e2e
 
-def _run_pool(batch_wire: bool, n_reqs: int = 24, flat_wire: bool = None,
+def _run_pool(n_reqs: int = 24, per_message: bool = False,
               pipeline: bool = None):
     """One deterministic 4-node sim pool ordering n_reqs NYMs;
     → (domain_root, audit_root, state_root, ordered txn sequence).
-    flat_wire pins Config.FLAT_WIRE (None = the class default) — the
-    flat-codec A/B in tests/test_flat_wire.py reuses this harness;
-    pipeline pins Config.PIPELINE_ENABLED the same way (the pipeline
-    on/off determinism A/B in tests/test_pipeline.py)."""
+    per_message=True installs a pass-through tap (the adversary's
+    benign `Behavior` base) on every node's bus: no option selects a
+    wire, the senders read `network.has_tap`, and a tap that changes
+    nothing puts outbox and propagator on the reference wire.
+    pipeline pins Config.PIPELINE_ENABLED (None = the class default;
+    the pipeline on/off determinism A/B in tests/test_pipeline.py)."""
     from plenum_tpu.common.constants import NYM, TARGET_NYM, VERKEY
     from plenum_tpu.common.txn_util import get_payload_data
     from plenum_tpu.crypto.signer import SimpleSigner
@@ -264,15 +278,16 @@ def _run_pool(batch_wire: bool, n_reqs: int = 24, flat_wire: bool = None,
     # any remaining root drift is a real equivalence bug.
     net = SimNetwork(timer, DefaultSimRandom(77),
                      min_latency=0.003, max_latency=0.003)
-    overrides = dict(Max3PCBatchSize=5, Max3PCBatchWait=0.2,
-                     THREE_PC_BATCH_WIRE=batch_wire)
-    if flat_wire is not None:
-        overrides["FLAT_WIRE"] = flat_wire
+    overrides = dict(Max3PCBatchSize=5, Max3PCBatchWait=0.2)
     if pipeline is not None:
         overrides["PIPELINE_ENABLED"] = pipeline
     conf = Config(**overrides)
     nodes = [Node(name, names, timer, net.create_peer(name), config=conf)
              for name in names]
+    if per_message:
+        from plenum_tpu.testing.adversary.behaviors import Behavior
+        for n in nodes:
+            n.replica.install_network_tap(Behavior())
     signer = SimpleSigner(seed=b"\x31" * 32)
     for i in range(n_reqs):
         dest = "col-%06d" % i + "x" * 12
@@ -290,6 +305,9 @@ def _run_pool(batch_wire: bool, n_reqs: int = 24, flat_wire: bool = None,
         if all(n.domain_ledger.size >= n_reqs for n in nodes):
             break
     assert all(n.domain_ledger.size == n_reqs for n in nodes)
+    # the pool really ran on the wire asked for
+    assert all((n._outbox_3pc.flushed_batches == 0) == per_message
+               for n in nodes)
     node = nodes[0]
     # all nodes agree internally first
     assert len({n.domain_ledger.root_hash for n in nodes}) == 1
@@ -321,8 +339,8 @@ class _CommitDroppingTap:
 
 def test_incoming_envelopes_unwrap_for_network_tap():
     """The receive-side mirror of the outbox's send-side tap degrade:
-    honest (untapped) peers coalesce their votes into THREE_PC_BATCH
-    envelopes, and a per-type tap on the RECEIVING node must still see
+    honest (untapped) peers coalesce their votes into flat envelopes,
+    and a per-type tap on the RECEIVING node must still see
     (and be able to drop) the inner votes — an envelope passed through
     whole would smuggle every vote past the fault injector. A tap
     dropping every Commit starves the tapped node's commit quorum
@@ -363,7 +381,7 @@ def test_incoming_envelopes_unwrap_for_network_tap():
     # untapped nodes reach commit quorum without the tapped node
     assert all(n.domain_ledger.size == 5 for n in nodes[:3])
     # the tap saw per-type votes, never a whole envelope...
-    assert "THREE_PC_BATCH" not in tap.seen
+    assert "FlatBatch" not in tap.seen
     assert "Prepare" in tap.seen and "Commit" in tap.seen
     # ...and the drop BIT: with every peer Commit eaten the tapped
     # node can never reach its commit quorum
@@ -372,12 +390,13 @@ def test_incoming_envelopes_unwrap_for_network_tap():
 
 @pytest.mark.slow
 def test_wire_modes_order_identically_e2e():
-    """Full-node rung: the coalesced THREE_PC_BATCH wire and the legacy
-    per-message wire drain the identical deterministic workload to
-    byte-equal ledger roots, state root and ordered sequence."""
-    on = _run_pool(batch_wire=True)
-    off = _run_pool(batch_wire=False)
-    assert on[3] == off[3]          # same txns in the same order
-    assert on[0] == off[0]          # domain ledger root, byte-equal
-    assert on[1] == off[1]          # audit ledger root (same batching)
-    assert on[2] == off[2]          # committed state root
+    """Full-node rung: the flat wire and the per-message wire (every
+    bus under a pass-through tap) drain the identical deterministic
+    workload under FIXED sim latency to byte-equal ledger roots, state
+    root and ordered sequence."""
+    flat = _run_pool()
+    single = _run_pool(per_message=True)
+    assert flat[3] == single[3]     # same txns in the same order
+    assert flat[0] == single[0]     # domain ledger root, byte-equal
+    assert flat[1] == single[1]     # audit ledger root (same batching)
+    assert flat[2] == single[2]     # committed state root

@@ -2,9 +2,9 @@
 adversarial envelopes, and columnar-intake equivalence.
 
 The flat wire (common/serializers/flat_wire.py) is a pure dataflow
-refactor of the THREE_PC_BATCH / PROPAGATE_BATCH envelopes: for ANY
-valid vote stream the receiver must end in the SAME observable state
-as the typed-object wire — equal vote stores and counters, equal
+refactor of the per-message wire: for ANY valid vote stream the
+receiver must end in the SAME observable state as a per-message
+replay — equal vote stores and counters, equal
 stashes, equal suspicions, byte-equal executor roots (the PR-8
 equivalence methodology, extended to the byte level). Structurally
 invalid envelopes (truncation, corruption, over-length, version skew)
@@ -15,14 +15,13 @@ import random
 
 import pytest
 
-from plenum_tpu.common.config import Config
 from plenum_tpu.common.messages.message_factory import node_message_factory
 from plenum_tpu.common.messages.node_messages import (
-    Commit, FlatBatch, PrePrepare, Prepare, Propagate, PropagateBatch)
+    Commit, FlatBatch, PrePrepare, Prepare, Propagate)
 from plenum_tpu.common.serializers import flat_wire as fw
 from plenum_tpu.common.serializers.serializers import MsgPackSerializer
 from tests.test_columnar_3pc import (
-    _run_pool, build_pair, feed_per_message, gen_stream, snapshot)
+    build_pair, feed_flat, feed_per_message, gen_stream, snapshot)
 
 serializer = MsgPackSerializer()
 
@@ -190,8 +189,8 @@ def test_propagate_roundtrip_and_lazy_unpack():
     assert cols.client(0) == "cliA" and cols.client(1) == ""
     # the legacy rematerialization for fault-injection taps
     legacy = fw.to_legacy_messages(env)
-    assert legacy == [PropagateBatch(requests=reqs,
-                                     clients=["cliA", ""])]
+    assert legacy == [Propagate(request=reqs[0], senderClient="cliA"),
+                      Propagate(request=reqs[1], senderClient=None)]
     single = fw.encode_propagate_envelope(
         [serializer.serialize(reqs[0])], ["cliA"])
     assert fw.to_legacy_messages(single) == [
@@ -214,8 +213,7 @@ def test_outbox_chunks_flat_envelopes_under_size_budget():
             sent.append(msg)
 
     # small budget: ~640B/prepare seed → a handful per envelope
-    outbox = ThreePCOutbox(_Net(), msg_len_limit=8 * 1024 + 2048,
-                           flat_wire_enabled=True)
+    outbox = ThreePCOutbox(_Net(), msg_len_limit=8 * 1024 + 2048)
     votes = []
     for seq in range(1, 40):
         votes.append(Prepare(instId=0, viewNo=0, ppSeqNo=seq,
@@ -239,6 +237,40 @@ def test_outbox_chunks_flat_envelopes_under_size_budget():
     assert len(got) == len(votes)
 
 
+def test_unencodable_chunk_leaves_per_message_in_order():
+    """A vote the flat layout refuses (a ppSeqNo past u64) puts ITS
+    chunk on the per-message wire, single votes in queue order; the
+    chunks around it still leave as flat envelopes."""
+    from plenum_tpu.server.three_pc_outbox import ThreePCOutbox
+
+    sent = []
+
+    class _Net:
+        has_tap = False
+
+        def send(self, msg, dst=None):
+            sent.append(msg)
+
+    outbox = ThreePCOutbox(_Net())
+    outbox.BATCH_LIMIT = 3              # two chunks of three
+    odd = [Prepare(instId=0, viewNo=0, ppSeqNo=1, ppTime=1600000000,
+                   digest="ab" * 32, stateRootHash=B58_ROOT,
+                   txnRootHash=B58_ROOT),
+           Commit(instId=0, viewNo=0, ppSeqNo=1 << 64),
+           Commit(instId=0, viewNo=0, ppSeqNo=1)]
+    plain = [Commit(instId=0, viewNo=0, ppSeqNo=seq)
+             for seq in (2, 3, 4)]
+    with pytest.raises(fw.FlatWireUnencodable):
+        fw.encode_three_pc([], [], [odd[1]])
+    for v in odd + plain:
+        outbox.queue(v)
+    assert outbox.flush() == 6
+    assert sent[:3] == odd              # single votes, queue order
+    assert len(sent) == 4 and isinstance(sent[3], FlatBatch)
+    assert fw.to_legacy_messages(sent[3].payload) == plain
+    assert outbox.flushed_batches == 1
+
+
 def test_outbox_size_model_tracks_measured_bytes():
     """Satellite: the hand-tuned byte constants are gone — after one
     flat flush the per-vote estimates are measured EWMAs, and the
@@ -255,7 +287,7 @@ def test_outbox_size_model_tracks_measured_bytes():
 
     prev = set_seam_hub(TelemetryHub(name="test"))
     try:
-        outbox = ThreePCOutbox(_Net(), flat_wire_enabled=True)
+        outbox = ThreePCOutbox(_Net())
         seed_prepare = outbox.size_model.prepare
         seed_commit = outbox.size_model.commit
         flushes = 20
@@ -379,29 +411,6 @@ def test_bad_entry_costs_one_entry_not_the_envelope():
 
 
 # --------------------------------------------- columnar equivalence
-
-def feed_flat(replica, envelopes):
-    """The wire-accurate flat feed: each sender envelope is ENCODED to
-    flat bytes, parsed, and routed exactly as Node._process_flat_batch
-    routes sections (PPs materialized through the stasher, vote columns
-    straight into process_*_columns)."""
-    o = replica.ordering
-    for frm, msgs in envelopes:
-        pps = [m for m in msgs if isinstance(m, PrePrepare)]
-        prepares = [m for m in msgs if isinstance(m, Prepare)]
-        commits = [m for m in msgs if isinstance(m, Commit)]
-        env = fw.parse_envelope(fw.encode_three_pc(pps, prepares,
-                                                   commits))
-        for sec in env.sections:
-            if sec.kind == fw.KIND_PREPREPARE:
-                batch = [sec.materialize(i) for i in range(sec.n)]
-                o.process_preprepare_batch(
-                    [m for m in batch if m is not None], frm)
-            elif sec.kind == fw.KIND_PREPARE:
-                o.process_prepare_columns(sec, frm)
-            elif sec.kind == fw.KIND_COMMIT:
-                o.process_commit_columns(sec, frm)
-
 
 @pytest.mark.parametrize("seed", range(12))
 def test_flat_intake_equals_per_message_randomized(seed):
@@ -541,7 +550,7 @@ def test_outbox_size_model_not_double_counted_on_split():
 
     prev = set_seam_hub(TelemetryHub(name="t"))
     try:
-        outbox = ThreePCOutbox(_Net(), flat_wire_enabled=True)
+        outbox = ThreePCOutbox(_Net())
         outbox._size_budget = 2048      # force a split
         n_votes = 24
         for seq in range(1, n_votes + 1):
@@ -565,22 +574,21 @@ def test_outbox_size_model_not_double_counted_on_split():
 
 # ----------------------------------------------- propagate equivalence
 
-def _make_propagator(name="Beta"):
+def _make_propagator(name="Beta", tapped=False):
     from plenum_tpu.consensus.quorums import Quorums
     from plenum_tpu.server.propagator import Propagator
 
     sent, forwarded = [], []
 
     class _Net:
-        has_tap = False
+        has_tap = tapped
 
         def send(self, msg, dst=None):
             sent.append(msg)
 
     prop = Propagator(name, Quorums(4), _Net(),
                       forward_handler=forwarded.append,
-                      forward_batch_handler=forwarded.extend,
-                      flat_wire_enabled=True)
+                      forward_batch_handler=forwarded.extend)
     return prop, sent, forwarded
 
 
@@ -593,7 +601,7 @@ def _propagate_payloads(n=5):
     return out
 
 
-def test_propagate_columns_equal_batch_intake():
+def test_propagate_columns_equal_per_message_intake():
     payloads = _propagate_payloads()
     pa, _, fwd_a = _make_propagator()
     pb, _, fwd_b = _make_propagator()
@@ -603,9 +611,9 @@ def test_propagate_columns_equal_batch_intake():
         cols = fw.parse_envelope(fw.encode_propagate_envelope(
             raws, clients)).sections[0]
         pa.process_propagate_columns(cols, frm)
-        pb.process_propagate_batch(
-            PropagateBatch(requests=[dict(p) for p in payloads],
-                           clients=list(clients)), frm)
+        for p, client in zip(payloads, clients):
+            pb.process_propagate(
+                Propagate(request=dict(p), senderClient=client), frm)
     assert [r.key for r in fwd_a] == [r.key for r in fwd_b]
     assert len(fwd_a) == len(payloads)
     ka = {k: (s.propagates, s.finalised, s.forwarded)
@@ -640,6 +648,20 @@ def test_propagator_flat_flush_packs_once():
 
 
 # ------------------------------------------------------- tap interplay
+
+def test_tapped_propagator_sends_single_propagates():
+    """Send-side fault-injection contract: while the bus has a tap, n
+    queued requests leave as n Propagates in queue order and no
+    envelope of any kind."""
+    prop, sent, _ = _make_propagator(tapped=True)
+    from plenum_tpu.common.request import Request
+    payloads = _propagate_payloads(4)
+    for i, p in enumerate(payloads):
+        prop.propagate(Request.from_dict(dict(p)), "cli-%d" % i)
+    assert prop.flush() == 4
+    assert sent == [Propagate(request=p, senderClient="cli-%d" % i)
+                    for i, p in enumerate(payloads)]
+
 
 def test_flat_envelopes_unwrap_before_bus_tap():
     """Receive-side fault-injection contract: a per-type tap on the
@@ -704,22 +726,6 @@ def test_budget_has_serialize_and_parse_stages():
     assert stage_of("wire_pack", "propagate") == "serialize"
     assert stage_of("wire_parse", "3pc") == "parse"
     assert stage_of("prepare_batch", "3pc") == "3pc"
-
-
-# ----------------------------------------------------------------- e2e
-
-@pytest.mark.slow
-def test_flat_and_typed_wire_order_identically_e2e():
-    """Full-node rung (acceptance): the flat codec and the typed-object
-    fallback drain the identical deterministic workload under FIXED sim
-    latency to byte-equal ledger roots, state root and ordered
-    sequence."""
-    flat = _run_pool(batch_wire=True, flat_wire=True)
-    typed = _run_pool(batch_wire=True, flat_wire=False)
-    assert flat[3] == typed[3]          # same txns in the same order
-    assert flat[0] == typed[0]          # domain ledger root, byte-equal
-    assert flat[1] == typed[1]          # audit ledger root
-    assert flat[2] == typed[2]          # committed state root
 
 
 # ===================================================== trace context (v2)
@@ -815,20 +821,6 @@ def test_trace_section_payload_truncation_is_structural():
     stamped = _prop_envelope(trace=_stamp())
     with pytest.raises(fw.FlatWireError):
         fw.parse_envelope(stamped[:-5])
-
-
-def test_typed_fallback_stamp_from_wire():
-    st = fw.TraceStamp("Gamma", 3, 1.25, 9.5)
-    back = fw.TraceStamp.from_wire(st.as_list())
-    assert (back.origin, back.seq, back.perf_ts, back.wall_ts) \
-        == ("Gamma", 3, 1.25, 9.5)
-    for junk in (None, "junk", [], ["a", 1, 2.0], ["a", 1, 2.0, 3.0, 4],
-                 ["x" * 100, 1, 0.0, 0.0], ["a", -1, 0.0, 0.0],
-                 ["a", 1 << 64, 0.0, 0.0],
-                 ["a", 1, float("nan"), 0.0],
-                 ["a", 1, 0.0, float("inf")],
-                 ["a", "not-a-seq", 0.0, 0.0]):
-        assert fw.TraceStamp.from_wire(junk) is None, junk
 
 
 def test_three_pc_envelope_carries_stamp_alongside_votes():

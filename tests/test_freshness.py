@@ -115,14 +115,13 @@ def test_freshness_monitor_votes_vc_when_primary_shirks(pool):
     state signatures go stale, and the pool moves to view 1 (reference
     freshness_monitor_service.py)."""
     from plenum_tpu.common.messages.node_messages import (
-        FlatBatch, PrePrepare, ThreePCBatch)
+        FlatBatch, PrePrepare)
     from plenum_tpu.common.serializers import flat_wire
     nodes, timer = pool
     primary = nodes[0].master_primary_name
     # the primary's PRE-PREPAREs vanish at every receiver: no batches
     # ordered, so no freshness updates — but the primary stays connected.
-    # Votes ride coalesced envelopes on the default wire (flat FLAT_WIRE
-    # or typed THREE_PC_BATCH), so the filter strips PrePrepares INSIDE
+    # Votes ride flat envelopes, so the filter strips PrePrepares INSIDE
     # the primary's envelopes too
     for n in nodes:
         orig = n.network.process_incoming
@@ -133,7 +132,7 @@ def test_freshness_monitor_votes_vc_when_primary_shirks(pool):
                     return None
                 if isinstance(msg, FlatBatch):
                     # unwrap, strip ONLY the PRE-PREPAREs, and deliver
-                    # the rest at its legacy granularity — propagates
+                    # the rest one message at a time — propagates
                     # must keep flowing (the primary is alive, just
                     # shirking freshness batches)
                     result = None
@@ -141,12 +140,6 @@ def test_freshness_monitor_votes_vc_when_primary_shirks(pool):
                         if not isinstance(m, PrePrepare):
                             result = orig(m, frm)
                     return result
-                if isinstance(msg, ThreePCBatch):
-                    kept = [m for m in msg.messages
-                            if not isinstance(m, PrePrepare)]
-                    if not kept:
-                        return None
-                    msg = ThreePCBatch(messages=kept)
             return orig(msg, frm)
         n.network.process_incoming = dropping
     # stale threshold = 3 * FRESHNESS = 90s; give it time to trip + VC

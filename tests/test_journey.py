@@ -97,18 +97,6 @@ def test_journeys_complete_on_flat_wire():
         assert link["samples"] >= 1 and link["delay_ms"] >= 0.0
 
 
-def test_journeys_complete_on_typed_fallback():
-    """FLAT_WIRE=False: the stamp rides the typed THREE_PC_BATCH /
-    PROPAGATE ``traceCtx`` field instead of a KIND_TRACE section —
-    journeys must come out just as complete."""
-    nodes, _ = run_traced_pool(
-        n_reqs=3, conf=traced_conf(FLAT_WIRE=False))
-    report = journey.journeys_from_tracers(pool_tracers(nodes))
-    assert_complete_report(report, 3)
-    assert not report["degraded"]
-    assert report["links"]
-
-
 def test_roots_byte_equal_with_trace_context_on_and_off():
     """The whole plane is advisory: identical seeds must produce
     byte-identical ledger and state roots with stamps on vs off."""

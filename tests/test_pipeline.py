@@ -188,17 +188,16 @@ def test_exec_fanout_sizing():
 def test_ordering_intake_owner_guard():
     """bind_owner_thread turns the ownership convention into a hard
     RuntimeError: 3PC intake off the prod thread must never count."""
-    from tests.test_3pc_verdicts import make_replica
+    from tests.test_3pc_verdicts import make_pp, make_replica
     replica = make_replica("Beta")
     o = replica.ordering
     o.bind_owner_thread(threading.get_ident())
-    o.process_commit_batch([], "Gamma")         # owner thread: fine
+    o.process_preprepare_batch([], "Gamma")     # owner thread: fine
     errs = []
 
     def off_thread():
         try:
-            o.process_commit_batch(
-                [Commit(instId=0, viewNo=0, ppSeqNo=1)], "Gamma")
+            o.process_preprepare_batch([make_pp()], "Alpha")
         except RuntimeError as e:
             errs.append(e)
 
@@ -216,10 +215,8 @@ def test_pipeline_on_off_byte_equal_roots():
     """The headline contract: a pipelined pool and a serial pool drain
     the identical workload to byte-equal domain/audit/state roots and
     the same ordered sequence."""
-    on = _run_pool(batch_wire=True, n_reqs=12, flat_wire=True,
-                   pipeline=True)
-    off = _run_pool(batch_wire=True, n_reqs=12, flat_wire=True,
-                    pipeline=False)
+    on = _run_pool(n_reqs=12, pipeline=True)
+    off = _run_pool(n_reqs=12, pipeline=False)
     assert on == off
 
 
@@ -297,7 +294,7 @@ def _run_adversarial_pool(pipeline, seed, n_reqs=10, sanitizer=None):
     net = SimNetwork(timer, DefaultSimRandom(77),
                      min_latency=0.003, max_latency=0.003)
     conf = Config(Max3PCBatchSize=5, Max3PCBatchWait=0.2,
-                  FLAT_WIRE=True, PIPELINE_ENABLED=pipeline,
+                  PIPELINE_ENABLED=pipeline,
                   SANITIZER_ENABLED=sanitizer)
     nodes = [Node(name, names, timer, net.create_peer(name), config=conf)
              for name in names]
@@ -375,7 +372,7 @@ def test_view_change_drains_pipeline_mid_stream():
     timer.set_time(1600000000)
     net = SimNetwork(timer, DefaultSimRandom(7))
     conf = Config(Max3PCBatchSize=5, Max3PCBatchWait=0.2,
-                  FLAT_WIRE=True, PIPELINE_ENABLED=True)
+                  PIPELINE_ENABLED=True)
     nodes = [Node(name, names, timer, net.create_peer(name), config=conf)
              for name in names]
     node = nodes[0]

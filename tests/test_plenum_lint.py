@@ -543,11 +543,12 @@ PT008_GOOD = """
             return self._data.quorums.prepare.is_reached(
                 self._prepare_vote_count.get(key, 0))
 
-        def process_prepare_batch(self, prepares, frm):
+        def process_preprepare_batch(self, pps, frm):
             # ONE loop per inbound wire batch is the columnar design,
             # not the quadratic shape — batch handlers are exempt
-            for p in prepares:
-                self._add_prepare_vote((p.viewNo, p.ppSeqNo), frm, p)
+            for pp in pps:
+                for digest in pp.reqIdr:
+                    self._note_digest(digest, frm)
 
         def _gc_below(self, seq):
             # non-handler housekeeping may walk the stores
@@ -711,13 +712,13 @@ def test_pt010_out_of_scope_layers_unchecked():
 
 
 def test_pt010_tree_has_only_justified_baseline_entries():
-    # the typed-fallback / tap-degrade paths are baselined with
-    # justifications; nothing NEW may appear
+    # the untrusted client-batch unwrap is baselined with its
+    # justification; nothing NEW may appear
     new, baselined, _ = run_analysis(
         [os.path.join(REPO, "plenum_tpu")], select=["PT010"],
         baseline_path=os.path.join(REPO, "lint_baseline.json"))
     assert new == []
-    assert len(baselined) == 2
+    assert len(baselined) == 1
 
 
 # --------------------------------------------------------------- PT011
@@ -1506,11 +1507,6 @@ PT015_ROOT_PARSES = """
 PT015_PARSE_DEF = """
     def decode_trace_stamp(raw):
         return None
-
-    class TraceStamp:
-        @classmethod
-        def from_wire(cls, raw):
-            return None
 """
 
 # the shipped shape: parsing confined to an observability seam no
@@ -1541,11 +1537,11 @@ def test_pt015_fires_on_helper_reached_from_root(tmp_path):
     """The parse doesn't have to sit IN the root — any function the
     consensus closure reaches is inside the boundary."""
     helper = """
-        from plenum_tpu.network.flat_wire import TraceStamp
+        from plenum_tpu.network.flat_wire import decode_trace_stamp
 
         class BatchTagger:
             def tag(self, raw):
-                return TraceStamp.from_wire(raw)
+                return decode_trace_stamp(raw)
     """
     root = """
         from plenum_tpu.server.batch_tagger import BatchTagger
