@@ -100,7 +100,7 @@ def main(args, cell, procs, workdir) -> int:
             this, daemon, procs, workdir, seed, args.seconds, args.tiny,
             base_port + (i % 20) * 16)
         rec = run.finish_pool(pool, daemon, plan, ops, args.seconds, False,
-                              deadline)
+                              deadline, this.traffic)
         got = run.judge(rec, daemon, None, args.tiny)
         values = dict(got["values"], daemon_faults=0)
         line = {kind: value, "seed": seed,

@@ -12,6 +12,7 @@ spec["arg"] the argument, spec["quantity"] what is made of it:
                      in the busiest node's spans: every node plans the
                      same 3PC batches, so one node's are the pool's
   busiest_median     the median of the argument over those spans
+  busiest_min        the smallest of the argument over those spans
   sum_per_write      the sum of the argument, all nodes, per valid
                      write confirmed in the window
 
@@ -51,6 +52,8 @@ def read(spec, run):
         return None
     if what == "busiest_median":
         return statistics.median(a[spec["arg"]] for a in found)
+    if what == "busiest_min":
+        return min(a[spec["arg"]] for a in found)
     if what == "busiest_ratio_pct":
         base = sum(a[spec["over"]] for a in found)
         return 100.0 * sum(a[spec["arg"]] for a in found) / base \

@@ -80,17 +80,63 @@ class Cell:
 
 # ------------------------------------------------------------ one run
 
+def warm_count(traffic_file: dict) -> int:
+    """Operations a traffic file asks set-up to put through the pool
+    before the window: `warm_up_bursts` bursts of its own `burst`
+    (write-whole.json, owners-whole.json; the older files ask for none
+    and run as they always did)."""
+    return int(traffic_file.get("warm_up_bursts", 0)) \
+        * int(traffic_file["params"].get("burst", 0))
+
+
 def make_ops(seed: int, plan: dict, traffic_file: dict, genesis=None):
+    """The stream in the order it is used: the probe (the first valid
+    write), the warm-up bursts, the window's operations."""
     count = plan["max_ops"] if plan["kind"] == "closed" \
         else len(plan["due"])
-    made = operations.make(seed, count + 1, traffic_file["operations"],
-                           genesis)
+    made = operations.make(seed, count + 1 + warm_count(traffic_file),
+                           traffic_file["operations"], genesis)
     return [Op(req, Client.wire(req), valid) for req, valid in made]
 
 
+async def warm_bursts(client, ops, burst: int, deadline: float) -> None:
+    """Set-up: each burst released at once, as the window releases one,
+    and waited for until every node has said its last word on every
+    operation of it, so that the window starts on a pool that has
+    applied, ordered and answered batches of the window's own size and
+    whose primary's queue holds what the traffic's arithmetic leaves
+    there (nothing, for whole-batch traffic)."""
+    for lo in range(0, len(ops), burst):
+        released = ops[lo:lo + burst]
+        now = time.perf_counter()
+        for op in released:
+            op.due = now
+            client.send(op)
+        await window.drain(client, released, deadline)
+        still = sum(1 for op in released if not client.settled(op))
+        if still:
+            raise RuntimeError("the pool never settled the warm-up "
+                               "burst (%d open)" % still)
+
+
+def daemon_counters(daemon) -> dict:
+    """The running daemon's counters that readers/daemon_stats.py takes
+    off its final line, as they stand now (the daemon answers a stats
+    frame at once, never behind a batch)."""
+    from plenum_tpu.crypto.remote_verifier import RemoteVerifier
+    rv = RemoteVerifier(("127.0.0.1", daemon.info["port"]), timeout=10)
+    try:
+        stats = rv.daemon_stats()
+    finally:
+        rv.close()
+    return {k: stats[k] for k in ("device_items", "device_launches",
+                                  "host_items")}
+
+
 async def drive(pool, daemon, ops, plan, seconds, traced, marks_out,
-                deadline):
-    """Connect, probe, window, drain → the window's record."""
+                deadline, traffic_file):
+    """Connect, probe, warm-up bursts, window, drain → the window's
+    record."""
     client = Client(pool.names, pool.f)
     for op in ops:
         client.register(op)
@@ -102,8 +148,19 @@ async def drive(pool, daemon, ops, plan, seconds, traced, marks_out,
         await window.probe(client, probe_op, deadline)
         log("probe write ordered")
         rest = [op for op in ops if op is not probe_op]
+        n_warm = warm_count(traffic_file)
+        warm, rest = rest[:n_warm], rest[n_warm:]
+        if warm:
+            await warm_bursts(client, warm,
+                              int(traffic_file["params"]["burst"]), deadline)
+            log("%d warm-up operations settled" % len(warm))
+            # the daemon's counter metrics start at the window, as every
+            # other reading does: what it has verified so far is warm-up
+            daemon.warm.update(daemon_counters(daemon))
         before = {"reports": pool.wait_reports(
-            len(pool.genesis_domain_txns()) + 1, time.monotonic() + 10),
+            len(pool.genesis_domain_txns()) + 1 + sum(
+                1 for op in warm if op.valid and op.answers),
+            time.monotonic() + 10),
             "cpu_s": pool.cpu_seconds()}
         marks = []
         if traced:
@@ -124,6 +181,7 @@ async def drive(pool, daemon, ops, plan, seconds, traced, marks_out,
         rec["drain_s"] = await window.drain(
             client, rec["released"], time.monotonic() + DRAIN_BUDGET_S)
         rec["probe_op"] = probe_op
+        rec["warm_ops"] = warm
         rec["stray"] = client.stray
         rec["dead_links"] = client.dead_links()
         return rec
@@ -157,19 +215,21 @@ def start_pool(cell, daemon, procs, workdir, seed, seconds, tiny, base_port,
     return pool, plan, ops
 
 
-def finish_pool(pool, daemon, plan, ops, seconds, traced, deadline):
-    """Window, drain, the nodes' last reports, nodes stopped → everything
-    the readers and the check need. The daemon is left running."""
+def finish_pool(pool, daemon, plan, ops, seconds, traced, deadline,
+                traffic_file):
+    """Warm-up bursts if the traffic file asks for them, window, drain,
+    the nodes' last reports, nodes stopped → everything the readers and
+    the check need. The daemon is left running."""
     marks = {}
     try:
         rec = asyncio.run(drive(pool, daemon, ops, plan, seconds, traced,
-                                marks, deadline))
+                                marks, deadline, traffic_file))
     except BaseException:
         pool.log_tails()
         raise
     rec["marks"] = marks
     released = rec["released"]
-    valid = [op for op in released if op.valid]
+    valid = [op for op in rec["warm_ops"] + released if op.valid]
     want = len(pool.genesis_domain_txns()) + 1 + sum(
         1 for op in valid if op.answers)
     rec["reports_after"] = pool.wait_reports(want, time.monotonic() + 30)
@@ -208,7 +268,7 @@ def gathered(rec, daemon_stats, side, daemon) -> dict:
 
 def judge(rec, daemon, daemon_stats, tiny):
     pool = rec["pool"]
-    ops = [rec["probe_op"]] + rec["released"]
+    ops = [rec["probe_op"]] + rec["warm_ops"] + rec["released"]
     obs = check.Observed(pool.names, pool.f, ops, rec["reports_after"],
                          daemon.info, daemon_stats,
                          ran_dry=rec["ran_dry"], tiny=tiny)
@@ -324,7 +384,7 @@ def single(args, cell, procs, workdir) -> int:
     log("daemon warm: first launch %.1fs, steady %.3fs" % (
         daemon.warm["first_launch_s"], daemon.warm["steady_launch_s"]))
     rec = finish_pool(pool, daemon, plan, ops, args.seconds, traced,
-                      deadline)
+                      deadline, cell.traffic)
     daemon_stats, side = daemon.stop()
     got = judge(rec, daemon, daemon_stats, args.tiny)
     units = units_of(cell)
