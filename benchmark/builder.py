@@ -23,7 +23,7 @@ import time
 import check
 import controls
 import stats
-from pool import in_thread, log, native_modules, tail
+from pool import Ports, in_thread, log, native_modules, tail
 
 
 def burst_times(rec):
@@ -86,7 +86,7 @@ def main(args, cell, procs, workdir) -> int:
         items = [("seed", int(s)) for s in args.seeds.split(",")]
     else:
         items = [("period_s", float(p)) for p in args.sweep.split(",")]
-    base_port = 19000 + (os.getpid() % 40) * 320
+    ports = Ports(2 * cell.config["nodes"])
     records = []
     all_ok = True
     for i, (kind, value) in enumerate(items):
@@ -98,7 +98,7 @@ def main(args, cell, procs, workdir) -> int:
         deadline = time.monotonic() + 300
         pool, plan, ops = run.start_pool(
             this, daemon, procs, workdir, seed, args.seconds, args.tiny,
-            base_port + (i % 20) * 16)
+            ports)
         rec = run.finish_pool(pool, daemon, plan, ops, args.seconds, False,
                               deadline, this.traffic)
         got = run.judge(rec, daemon, None, args.tiny)

@@ -2,9 +2,13 @@
 node processes through the operator's start script, and their logs and
 reports. Copied from chip_smoke.py's pool phase (PR 22), which ran on
 the chip; this process never initialises a JAX backend."""
+import ctypes
+import errno
 import json
 import os
+import re
 import signal
+import socket
 import subprocess
 import sys
 import threading
@@ -40,31 +44,213 @@ def tail(path, n=30) -> str:
         return ""
 
 
+def last_line(path) -> str:
+    lines = [ln for ln in tail(path, 5).splitlines() if ln.strip()]
+    return lines[-1].strip() if lines else ""
+
+
+# ------------------------------------------------------------ processes
+
+_PR_SET_PDEATHSIG = 1
+try:
+    _prctl = ctypes.CDLL(None, use_errno=True).prctl
+except (OSError, AttributeError):    # not Linux: children are not tied
+    _prctl = None
+
+
+def die_with_parent():
+    """→ a `preexec_fn`: the kernel kills the child when the thread that
+    started it ends, so the daemon (which holds the chip) and the nodes
+    (which hold ports) go with a run.py that is killed at its limit.
+    Only the main thread starts processes: a parent-death signal follows
+    the starting THREAD, not the process."""
+    parent = os.getpid()
+
+    def tie():
+        if _prctl is not None:
+            _prctl(_PR_SET_PDEATHSIG, signal.SIGKILL)
+            if os.getppid() != parent:     # it died before the call
+                os._exit(1)
+    return tie
+
+
+def why_alive(pid: int) -> str:
+    """What the kernel says of a process that will not end: its state,
+    and where its threads sleep, as far as this host lets one read it
+    (/proc/<pid>/task/<tid>/stack or wchan; neither, on the chip host)."""
+    out = []
+    try:
+        with open("/proc/%d/status" % pid) as f:
+            out += [ln.strip() for ln in f
+                    if ln.split(":")[0] in ("State", "Threads", "SigBlk",
+                                            "SigIgn", "SigCgt")]
+        asleep = {}
+        for tid in os.listdir("/proc/%d/task" % pid):
+            for name in ("stack", "wchan"):
+                try:
+                    with open("/proc/%d/task/%s/%s" % (pid, tid, name)) as f:
+                        where = " ".join(f.read().split())[:300]
+                except OSError:
+                    continue
+                if where and where != "0":
+                    asleep[where] = asleep.get(where, 0) + 1
+                    break
+        out += ["%d thread(s) in %s" % (n, where) for where, n in sorted(
+            asleep.items(), key=lambda kv: -kv[1])[:8]] \
+            or ["no thread's kernel stack or wchan can be read here"]
+    except OSError as e:
+        out.append("gone: %s" % e)
+    return "\n".join(out)
+
+
 class Procs:
     """Every process the run starts, so that all of them are stopped and
-    waited for whatever happens."""
+    waited for whatever happens. `killed` names each one that did not end
+    on its signal within STOP_WAIT_S, with the seconds it was given."""
+
+    STOP_WAIT_S = 20
 
     def __init__(self):
-        self.items = []
+        self.items = []       # (proc, signal, name, dumps its stacks)
+        self.killed = []
 
-    def add(self, proc, sig=signal.SIGTERM):
-        self.items.append((proc, sig))
+    def add(self, proc, sig=signal.SIGTERM, name=None, dumps_stacks=False):
+        """`dumps_stacks`: the process writes every thread's stack to its
+        standard error on SIGQUIT (daemon_entry.py arms faulthandler)."""
+        self.items.append((proc, sig, name or "pid %d" % proc.pid,
+                           dumps_stacks))
         return proc
 
     def stop(self, procs=None):
-        chosen = [(p, s) for p, s in self.items
-                  if procs is None or p in procs]
-        for proc, sig in chosen:
+        chosen = [item for item in self.items
+                  if procs is None or item[0] in procs]
+        t0 = time.monotonic()
+        for proc, sig, _name, _dumps in chosen:
             if proc.poll() is None:
                 proc.send_signal(sig)
-        for proc, _sig in chosen:
+        for item in chosen:
             try:
-                proc.wait(timeout=20)
+                item[0].wait(timeout=max(
+                    0.0, t0 + self.STOP_WAIT_S - time.monotonic()))
             except subprocess.TimeoutExpired:
-                proc.kill()
-                proc.wait()
-        self.items = [(p, s) for p, s in self.items
-                      if (p, s) not in chosen]
+                self._kill(*item, waited=time.monotonic() - t0)
+        self.items = [item for item in self.items if item not in chosen]
+
+    def _kill(self, proc, sig, name, dumps_stacks, waited: float) -> None:
+        log("%s (pid %d) has not ended %.1fs after signal %d\n%s" % (
+            name, proc.pid, waited, sig, why_alive(proc.pid)))
+        if dumps_stacks:
+            proc.send_signal(signal.SIGQUIT)
+            try:
+                proc.wait(timeout=1)      # a second to write them
+            except subprocess.TimeoutExpired:
+                pass
+            waited += 1
+        proc.kill()
+        proc.wait()
+        log("%s killed" % name)
+        self.killed.append({"name": name, "waited_s": round(waited, 1)})
+
+
+# ---------------------------------------------------------------- ports
+
+LEGACY_PORTS = (19000, 31800)     # where the bases lay until PR 36
+
+
+def ephemeral_range():
+    """The range the kernel draws a connection's local port from, or
+    None where it cannot be read."""
+    try:
+        with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+            lo, hi = (int(x) for x in f.read().split())
+        return lo, hi
+    except (OSError, ValueError):
+        return None
+
+
+def port_zone(span: int):
+    """→ (lo, hi): where a pool's first port may lie, outside the range
+    the kernel draws local ports from, so that no connection of the
+    nodes, the daemon or anything else on the host can sit on a port a
+    node will bind. Below that range if there is room, else above it,
+    else (a host whose range covers everything, or none readable) where
+    the bases lay before: the probe and the one fresh start are then
+    what is left."""
+    eph = ephemeral_range()
+    if eph is not None:
+        lo, hi = eph
+        for zone in ((LEGACY_PORTS[0], lo), (hi + 1, 65536), (10000, lo)):
+            if zone[1] - zone[0] >= 40 * span:
+                return zone[0], zone[1] - span
+    return LEGACY_PORTS[0], LEGACY_PORTS[1] - span
+
+
+def port_holders(port: int) -> str:
+    """Who has `port` as its local port, from /proc/net/tcp (the chip
+    host need not have `ss`): state and peer of each socket."""
+    states = {"01": "ESTABLISHED", "02": "SYN_SENT", "06": "TIME_WAIT",
+              "08": "CLOSE_WAIT", "0A": "LISTEN"}
+    found = []
+    for path in ("/proc/net/tcp", "/proc/net/tcp6"):
+        try:
+            with open(path) as f:
+                rows = f.read().splitlines()[1:]
+        except OSError:
+            continue
+        for row in rows:
+            cols = row.split()
+            if len(cols) > 7 and int(cols[1].rsplit(":", 1)[1], 16) == port:
+                found.append("%s local %s peer %s %s uid %s inode %s" % (
+                    os.path.basename(path), cols[1], cols[2],
+                    states.get(cols[3], cols[3]), cols[7], cols[9]))
+    return "; ".join(found) or "nothing in /proc/net/tcp"
+
+
+def busy_ports(base: int, count: int):
+    """The ports of base..base+count-1 that cannot be bound now, each
+    tried the way a node will bind it (asyncio.start_server: 127.0.0.1,
+    SO_REUSEADDR) and closed again."""
+    busy = []
+    for port in range(base, base + count):
+        s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+        try:
+            s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+            s.bind(("127.0.0.1", port))
+        except OSError as e:
+            if e.errno != errno.EADDRINUSE:
+                raise
+            busy.append(port)
+        finally:
+            s.close()
+    return busy
+
+
+class Ports:
+    """Bases for fresh pools: each shown free port by port before it is
+    handed out, none handed out twice. `stepped` holds the ports found
+    busy, for the result line's notes."""
+
+    def __init__(self, count: int):
+        self.count = count
+        self.lo, self.hi = port_zone(count)
+        slots = max(1, (self.hi - self.lo) // 320)
+        self.next = self.lo + (os.getpid() % slots) * 320
+        self.stepped = []
+
+    def base(self) -> int:
+        for _ in range(200):
+            base = self.next
+            if base > self.hi:
+                base = self.lo
+            self.next = base + self.count
+            busy = busy_ports(base, self.count)
+            if not busy:
+                return base
+            for port in busy:
+                log("port %d is taken: %s" % (port, port_holders(port)))
+            self.stepped += busy
+        raise RuntimeError("no %d free ports in a row in %d-%d" % (
+            self.count, self.lo, self.hi))
 
 
 def native_modules() -> dict:
@@ -138,7 +324,9 @@ class Daemon:
         with open(os.path.join(self.dir, "daemon.out"), "w") as out, \
                 open(os.path.join(self.dir, "daemon.err"), "w") as err:
             self.proc = self.procs.add(subprocess.Popen(
-                cmd, cwd=ROOT, env=env, stdout=out, stderr=err))
+                cmd, cwd=ROOT, env=env, stdout=out, stderr=err,
+                preexec_fn=die_with_parent()),
+                name="daemon", dumps_stacks=True)
 
     def wait_ready(self, timeout: float) -> dict:
         from plenum_tpu.server.verify_daemon import wait_ready
@@ -178,11 +366,37 @@ class Daemon:
         from plenum_tpu.common.config import Config
         return Config.VERIFY_DAEMON_BUCKET
 
+    def log_tail(self, n=30) -> None:
+        log("---- tail of daemon.err ----\n%s" % tail(
+            os.path.join(self.dir, "daemon.err"), n))
+
     def signal_profile(self, start: bool) -> None:
         self.proc.send_signal(signal.SIGUSR1 if start else signal.SIGUSR2)
 
+    def stats_now(self, timeout=10) -> dict:
+        """The running daemon's stats(), the dict its final line holds
+        (it answers a stats frame at once, never behind a batch)."""
+        from plenum_tpu.crypto.remote_verifier import RemoteVerifier
+        rv = RemoteVerifier(("127.0.0.1", self.info["port"]),
+                            timeout=timeout)
+        try:
+            return rv.daemon_stats()
+        finally:
+            rv.close()
+
     def stop(self):
-        """Clean stop → (final stats line or None, side file or {})."""
+        """Stop → (stats or None, side file or {}). The stats are the
+        final line of a daemon that ended by itself. One that had to be
+        killed prints none: then they are what it answered when asked
+        just before the stop (every node is down by then, so nothing
+        is verified in between), and its stacks are logged. The side
+        file is there from the moment the profiler's bracket closed."""
+        try:
+            asked = self.stats_now() if self.proc.poll() is None else None
+        except Exception as e:   # a daemon that no longer answers
+            log("the daemon gave no stats before its stop: %r" % e)
+            asked = None
+        killed = len(self.procs.killed)
         self.procs.stop([self.proc])
         stats = None
         for line in reversed(tail(os.path.join(self.dir, "daemon.out"),
@@ -190,6 +404,12 @@ class Daemon:
             if line.startswith("{"):
                 stats = json.loads(line)
                 break
+        if len(self.procs.killed) > killed:
+            self.log_tail(120)
+            if stats is None and asked is not None:
+                log("no final stats line: the stats are those the daemon "
+                    "gave before its stop; its span dump is missing")
+                stats = asked
         try:
             with open(self.side_file) as f:
                 side = json.load(f)
@@ -268,11 +488,26 @@ class Pool:
                     [sys.executable, entry, "--name", name,
                      "--base-dir", self.base_dir],
                     cwd=ROOT, env=env, stdout=out,
-                    stderr=subprocess.STDOUT), sig=signal.SIGINT)
+                    stderr=subprocess.STDOUT,
+                    preexec_fn=die_with_parent()),
+                    sig=signal.SIGINT, name=name)
 
     def dead_nodes(self):
         return [n for n, p in self.node_procs.items()
                 if p.poll() is not None]
+
+    def last_word(self, name: str) -> str:
+        return last_line(os.path.join(self.base_dir, name + ".out"))
+
+    def failed_bind(self, name: str):
+        """The port a dead node could not bind (its last word is the
+        OSError of asyncio's create_server; 0 where it names none), or
+        None."""
+        word = self.last_word(name)
+        if "Errno 98" not in word and "ddress already in use" not in word:
+            return None
+        m = re.search(r"\('127\.0\.0\.1', (\d+)\)", word)
+        return int(m.group(1)) if m else 0
 
     def reports(self) -> dict:
         """Each node's newest validator-info dump, as far as present."""
@@ -316,8 +551,8 @@ class Pool:
     def stop(self) -> None:
         self.procs.stop(list(self.node_procs.values()))
 
-    def log_tails(self) -> None:
-        for name in self.names:
+    def log_tails(self, names=None) -> None:
+        for name in names or self.names:
             log("---- tail of %s.out ----\n%s" % (
                 name, tail(os.path.join(self.base_dir, name + ".out"))))
 

@@ -24,6 +24,7 @@ import shutil  # noqa: E402
 import signal  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import traceback  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -37,15 +38,27 @@ import trace_reduce  # noqa: E402
 import window  # noqa: E402
 from client import Client, Op  # noqa: E402
 from pool import (  # noqa: E402
-    Daemon, Pool, Procs, in_thread, jax_backend_untouched, log,
-    native_modules, tail)
+    Daemon, Pool, Ports, Procs, ephemeral_range, in_thread,
+    jax_backend_untouched, log, last_line, native_modules)
 
 SETUP_BUDGET_S = 1100     # a first run compiles: 1200 s in all
 DRAIN_BUDGET_S = 60
+SECOND_TRACE_BY_S = 150   # a traced run this young may be made again
 
 
 class NoResult(Exception):
-    """The run cannot give a result line (no chip, no program)."""
+    """The run cannot give a result line (no chip, no program, a
+    process that died in set-up): its one line is the run's last word."""
+
+
+class DiedInSetup(Exception):
+    """A process the run started ended before the window opened."""
+
+    def __init__(self, name: str, word: str, port=None):
+        super().__init__("%s died in set-up: %s" % (
+            name, word or "no last word"))
+        self.name = name
+        self.port = port      # the port it could not bind, if that is why
 
 
 def load_json(path):
@@ -121,16 +134,71 @@ async def warm_bursts(client, ops, burst: int, deadline: float) -> None:
 
 def daemon_counters(daemon) -> dict:
     """The running daemon's counters that readers/daemon_stats.py takes
-    off its final line, as they stand now (the daemon answers a stats
-    frame at once, never behind a batch)."""
-    from plenum_tpu.crypto.remote_verifier import RemoteVerifier
-    rv = RemoteVerifier(("127.0.0.1", daemon.info["port"]), timeout=10)
-    try:
-        stats = rv.daemon_stats()
-    finally:
-        rv.close()
+    off its final line, as they stand now."""
+    stats = daemon.stats_now()
     return {k: stats[k] for k in ("device_items", "device_launches",
                                   "host_items")}
+
+
+async def watch(pool, daemon) -> None:
+    """Set-up's watcher: twice a second, has a node or the daemon
+    ended? The loops it runs beside (Client.connect, window.probe,
+    warm_bursts) wait on the set-up budget alone."""
+    while True:
+        dead = pool.dead_nodes()
+        if dead:
+            raise DiedInSetup(dead[0], pool.last_word(dead[0]),
+                              pool.failed_bind(dead[0]))
+        if daemon.proc.poll() is not None:
+            raise DiedInSetup("the daemon", last_line(
+                os.path.join(daemon.dir, "daemon.err")))
+        await asyncio.sleep(0.5)
+
+
+async def watched(setup, pool, daemon):
+    """Set-up raced against the watcher → set-up's result. The watcher
+    is gone before this returns: nothing runs beside the window."""
+    tasks = [asyncio.ensure_future(setup),
+             asyncio.ensure_future(watch(pool, daemon))]
+    try:
+        await asyncio.wait(tasks, return_when=asyncio.FIRST_COMPLETED)
+    finally:
+        for task in tasks:
+            task.cancel()
+        await asyncio.gather(*tasks, return_exceptions=True)
+    # the watcher ends only by raising; a death wins over whatever the
+    # set-up made of it
+    for task in reversed(tasks):
+        if not task.cancelled() and task.exception() is not None:
+            raise task.exception()
+    return tasks[0].result()
+
+
+async def set_up(client, pool, daemon, ops, deadline, traffic_file):
+    """Connect, probe, warm-up bursts, the nodes' reports → what the
+    window starts from."""
+    await client.connect(pool.base_dir, deadline)
+    log("client connected to %d nodes" % len(pool.names))
+    # the first valid write is the probe; the window's start there
+    probe_op = next(op for op in ops if op.valid)
+    await window.probe(client, probe_op, deadline)
+    log("probe write ordered")
+    rest = [op for op in ops if op is not probe_op]
+    n_warm = warm_count(traffic_file)
+    warm, rest = rest[:n_warm], rest[n_warm:]
+    if warm:
+        await warm_bursts(client, warm,
+                          int(traffic_file["params"]["burst"]), deadline)
+        log("%d warm-up operations settled" % len(warm))
+        # the daemon's counter metrics start at the window, as every
+        # other reading does: what it has verified so far is warm-up
+        daemon.warm.update(daemon_counters(daemon))
+    before = {"reports": pool.wait_reports(
+        len(pool.genesis_domain_txns()) + 1 + sum(
+            1 for op in warm if op.valid and op.answers),
+        time.monotonic() + 10),
+        "cpu_s": pool.cpu_seconds()}
+    return probe_op, warm, rest, before
 
 
 async def drive(pool, daemon, ops, plan, seconds, traced, marks_out,
@@ -141,27 +209,9 @@ async def drive(pool, daemon, ops, plan, seconds, traced, marks_out,
     for op in ops:
         client.register(op)
     try:
-        await client.connect(pool.base_dir, deadline)
-        log("client connected to %d nodes" % len(pool.names))
-        # the first valid write is the probe; the window's start there
-        probe_op = next(op for op in ops if op.valid)
-        await window.probe(client, probe_op, deadline)
-        log("probe write ordered")
-        rest = [op for op in ops if op is not probe_op]
-        n_warm = warm_count(traffic_file)
-        warm, rest = rest[:n_warm], rest[n_warm:]
-        if warm:
-            await warm_bursts(client, warm,
-                              int(traffic_file["params"]["burst"]), deadline)
-            log("%d warm-up operations settled" % len(warm))
-            # the daemon's counter metrics start at the window, as every
-            # other reading does: what it has verified so far is warm-up
-            daemon.warm.update(daemon_counters(daemon))
-        before = {"reports": pool.wait_reports(
-            len(pool.genesis_domain_txns()) + 1 + sum(
-                1 for op in warm if op.valid and op.answers),
-            time.monotonic() + 10),
-            "cpu_s": pool.cpu_seconds()}
+        probe_op, warm, rest, before = await watched(
+            set_up(client, pool, daemon, ops, deadline, traffic_file),
+            pool, daemon)
         marks = []
         if traced:
             half = min(2.5, seconds / 4.0)
@@ -189,29 +239,35 @@ async def drive(pool, daemon, ops, plan, seconds, traced, marks_out,
         client.close()
 
 
-def new_pool(cell, procs, workdir, seed, tiny, base_port):
+def new_pool(cell, procs, workdir, seed, tiny, ports):
     """A fresh pool's files: keys, and the two genesis files with the
-    configuration's identities. They need no daemon, so `single` makes
-    them while the daemon starts."""
+    configuration's identities, on 2n ports that `ports` has just shown
+    free. They need no daemon, so `single` makes them while the daemon
+    starts."""
     base_dir = tempfile.mkdtemp(prefix="pool_", dir=workdir)
-    pool = Pool(procs, base_dir, cell.config, tiny, base_port)
+    pool = Pool(procs, base_dir, cell.config, tiny, ports.base())
     pool.generate(seed)
     log("pool generated: %d domain genesis txns" % len(
         pool.genesis_domain_txns()))
     return pool
 
 
-def start_pool(cell, daemon, procs, workdir, seed, seconds, tiny, base_port,
-               pool=None):
+def start_pool(cell, daemon, procs, workdir, seed, seconds, tiny, ports,
+               pool=None, made=None):
     """A fresh pool beside the daemon (which may still be warming up):
     generated unless it is handed in, configured, its nodes started,
-    and the window's operations signed → (pool, plan, ops)."""
-    pool = pool or new_pool(cell, procs, workdir, seed, tiny, base_port)
+    and the window's operations signed (or, handed in as `made`, the
+    same requests with nothing sent yet) → (pool, plan, ops)."""
+    pool = pool or new_pool(cell, procs, workdir, seed, tiny, ports)
     pool.write_config(daemon.info["port"])
     pool.start_nodes()
-    plan = generators.plan(cell.traffic, seed, seconds)
-    ops = make_ops(seed, plan, cell.traffic, cell.config.get("genesis"))
-    log("%d operations signed" % len(ops))
+    if made:
+        plan, ops = made[0], [Op(op.request, op.wire, op.valid)
+                              for op in made[1]]
+    else:
+        plan = generators.plan(cell.traffic, seed, seconds)
+        ops = make_ops(seed, plan, cell.traffic, cell.config.get("genesis"))
+        log("%d operations signed" % len(ops))
     return pool, plan, ops
 
 
@@ -224,6 +280,8 @@ def finish_pool(pool, daemon, plan, ops, seconds, traced, deadline,
     try:
         rec = asyncio.run(drive(pool, daemon, ops, plan, seconds, traced,
                                 marks, deadline, traffic_file))
+    except DiedInSetup:
+        raise               # the caller logs the one tail that matters
     except BaseException:
         pool.log_tails()
         raise
@@ -289,7 +347,7 @@ def judge(rec, daemon, daemon_stats, tiny):
 
 
 def result_line(rec, metrics, units, daemon, side, got, device_extra,
-                breakdown=None) -> dict:
+                breakdown=None, notes=None) -> dict:
     released = rec["released"]
     valid = [op for op in released if op.valid]
     device = dict((daemon.info or {}).get("device") or {})
@@ -316,6 +374,10 @@ def result_line(rec, metrics, units, daemon, side, got, device_extra,
     line["device"].update(device_extra or {})
     if breakdown:
         line["breakdown"] = breakdown
+    if notes:
+        # what the harness had to put right on the way (README.md); a
+        # run with nothing to say has no such key
+        line["notes"] = notes
     line["compared"] = check.table(got["values"])
     return line
 
@@ -351,12 +413,13 @@ def daemon_ready(daemon, cell, tiny):
     try:
         info = daemon.wait_ready(timeout=300)
     except RuntimeError:
+        daemon.log_tail(15)
         raise NoResult("the verify daemon did not start: no accelerator "
-                       "here, or another process holds it\n"
-                       + tail(os.path.join(daemon.dir, "daemon.err"), 15))
+                       "here, or another process holds it")
     device = info.get("device") or {}
-    log("host cores %s; daemon device %s; compile cache %s" % (
-        os.cpu_count(), json.dumps(device), info.get("compile_cache")))
+    log("host cores %s; local ports %s; daemon device %s; compile cache %s"
+        % (os.cpu_count(), ephemeral_range(), json.dumps(device),
+           info.get("compile_cache")))
     if not tiny and (device.get("platform") != "tpu"
                      or (device.get("count") or 0) < cell.chips):
         raise NoResult("the cell asks for %d tpu chip(s), the daemon got "
@@ -364,34 +427,89 @@ def daemon_ready(daemon, cell, tiny):
     return daemon
 
 
-def single(args, cell, procs, workdir) -> int:
+def pool_through_setup(cell, daemon, procs, workdir, args, ports, notes,
+                       traced, deadline, pool=None, between=None):
+    """start_pool, `between` (once: `attempt` joins the daemon's warm-up
+    there, which ran beside the node starts) and finish_pool, with the
+    one fault of set-up that is the harness's own put right once: a node
+    whose last word is a failed bind (its port was free when `ports`
+    tried it and taken when the node did) is started again with all the
+    others on a fresh base dir and fresh ports, the same seed, requests
+    and plan. Any other death before the window, or a second failed
+    bind, ends the run."""
+    made = None
+    while True:
+        pool, plan, ops = start_pool(
+            cell, daemon, procs, workdir, args.seed, args.seconds,
+            args.tiny, ports, pool, made)
+        if between is not None:
+            between()
+            between = None
+        try:
+            return finish_pool(pool, daemon, plan, ops, args.seconds,
+                               traced, deadline, cell.traffic)
+        except DiedInSetup as death:
+            if death.name in pool.names:
+                pool.log_tails([death.name])
+            else:
+                daemon.log_tail()
+            pool.stop()
+            if death.port is None or "setup_restarts" in notes:
+                raise NoResult(str(death))
+            log("%s could not bind port %d: one fresh start of the pool"
+                % (death.name, death.port))
+            notes["setup_restarts"] = 1
+            notes["bind_failed_port"] = death.port
+            made, pool = (plan, ops), None
+
+
+def trace_is_missing(run, tiny) -> bool:
+    """A traced run has to have read a device trace. The CPU rehearsal
+    has no device plane to read: there the closed bracket and the
+    profiler's file are what a second attempt is decided on."""
+    if not tiny:
+        return not run["cache"].get("device_trace")
+    from readers import device_trace
+    return not ("stop" in device_trace.bracket_of(run)
+                and trace_reduce.newest_xplane(run["profile_dir"]))
+
+
+def attempt(args, cell, procs, workdir, notes):
+    """Daemon, pool, window, stop, check → the result line, or None
+    where a traced run has read no device trace."""
     traced = bool(args.trace)
     deadline = time.monotonic() + SETUP_BUDGET_S
-    natives = native_modules()
-    log("native modules %s" % json.dumps(natives))
-    base_port = 19000 + (os.getpid() % 40) * 320
+    ports = Ports(2 * cell.config["nodes"])
     daemon = launch_daemon(cell, procs, workdir, args.tiny, traced)
-    pool = new_pool(cell, procs, workdir, args.seed, args.tiny, base_port)
+    pool = new_pool(cell, procs, workdir, args.seed, args.tiny, ports)
     daemon_ready(daemon, cell, args.tiny)
     warm_thread, warm_box = in_thread(daemon.warm_up, args.seed,
                                       SETUP_BUDGET_S)
-    pool, plan, ops = start_pool(cell, daemon, procs, workdir, args.seed,
-                                 args.seconds, args.tiny, base_port, pool)
-    warm_thread.join()
-    if "error" in warm_box:
-        log(tail(os.path.join(workdir, "daemon.err"), 30))
-        raise warm_box["error"]
-    log("daemon warm: first launch %.1fs, steady %.3fs" % (
-        daemon.warm["first_launch_s"], daemon.warm["steady_launch_s"]))
-    rec = finish_pool(pool, daemon, plan, ops, args.seconds, traced,
-                      deadline, cell.traffic)
+
+    def warm_joined():
+        warm_thread.join()
+        if "error" in warm_box:
+            daemon.log_tail()
+            raise warm_box["error"]
+        log("daemon warm: first launch %.1fs, steady %.3fs" % (
+            daemon.warm["first_launch_s"], daemon.warm["steady_launch_s"]))
+
+    rec = pool_through_setup(cell, daemon, procs, workdir, args, ports,
+                             notes, traced, deadline, pool, warm_joined)
     daemon_stats, side = daemon.stop()
-    got = judge(rec, daemon, daemon_stats, args.tiny)
+    if args.keep:
+        keep(args.keep, workdir, rec["pool"], daemon)
     units = units_of(cell)
     device_extra, breakdown = {}, None
     if traced:
         run = gathered(rec, daemon_stats, side, daemon)
         metrics = read_metrics(cell, "per_layer", run)
+        if trace_is_missing(run, args.tiny):
+            log("no device trace: bracket %s, profiler's file %s" % (
+                side.get("profile"),
+                trace_reduce.newest_xplane(daemon.profile_dir)))
+            daemon.log_tail(40)
+            return None
         trace = run["cache"].get("device_trace")
         if trace:
             device_extra = {"busy_s": trace["busy_s"],
@@ -399,16 +517,44 @@ def single(args, cell, procs, workdir) -> int:
             breakdown = trace.get("breakdown")
     else:
         metrics = read_metrics(cell, "end_to_end", rec)
-    if args.keep:
-        keep(args.keep, workdir, pool, daemon)
+    got = judge(rec, daemon, daemon_stats, args.tiny)
     print_checks(got)
-    line = result_line(rec, metrics, units, daemon, side, got, device_extra,
-                       breakdown)
-    if traced and not device_extra and not args.tiny:
-        log("the traced run read no device trace")
-        return 1
-    print(json.dumps(line), flush=True)
-    return 0
+    if ports.stepped:
+        notes["ports_busy"] = ports.stepped
+    if procs.killed:
+        notes["killed"] = procs.killed
+        if traced:
+            notes["not_read"] = sorted(
+                m["name"] for m in cell.metrics("per_layer")
+                if m["name"] not in metrics)
+    return result_line(rec, metrics, units, daemon, side, got, device_extra,
+                       breakdown, notes)
+
+
+def single(args, cell, procs, workdir) -> int:
+    """One attempt; a second, in a traced run that read no device trace
+    while the run is young enough for two to end inside the run's limit
+    (a fresh daemon, a fresh pool, the same seed)."""
+    natives = native_modules()
+    log("native modules %s" % json.dumps(natives))
+    notes = {}
+    for n in (1, 2):
+        adir = os.path.join(workdir, "attempt%d" % n)
+        os.mkdir(adir)
+        line = attempt(args, cell, procs, adir, notes)
+        if line is not None:
+            print(json.dumps(line), flush=True)
+            return 0
+        age = time.perf_counter() - T_PROCESS
+        if n == 2 or age > SECOND_TRACE_BY_S:
+            raise NoResult(
+                "the traced run read no device trace (attempt %d, %.0f s "
+                "after the start%s)" % (n, age, "" if n == 2 else
+                                        ": too late for a second"))
+        log("the traced run read no device trace: a second attempt")
+        procs.stop()
+        notes["traced_attempts"] = 2
+        shutil.rmtree(adir, ignore_errors=True)
 
 
 def keep(dest, workdir, pool, daemon) -> None:
@@ -447,37 +593,55 @@ def main() -> int:
                     help="builder: copy logs and traces here")
     args = ap.parse_args()
     if not os.path.isdir(os.path.join(ROOT, "plenum_tpu")):
-        print("plenum_tpu/ is not beside benchmark/: nothing to measure",
-              file=sys.stderr)
+        print("no result: plenum_tpu/ is not beside benchmark/: nothing to "
+              "measure", file=sys.stderr)
         return 2
     procs = Procs()
     signal.signal(signal.SIGTERM, lambda s, f: sys.exit(143))
     workdir = tempfile.mkdtemp(prefix="plenum_bench_")
+    code, why = 3, None
     try:
-        cell = Cell(args.workload)
-        if args.seconds is None:
-            args.seconds = float(cell.bench["run_seconds"])
-        if args.tiny:
-            for key, value in cell.traffic.get("tiny", {}).items():
-                cell.traffic["params"][key] = value
-            if "genesis" in cell.config["tiny"]:
-                cell.config["genesis"] = cell.config["tiny"]["genesis"]
-        if operations.uses_genesis(cell.traffic["operations"]) \
-                and not cell.config.get("genesis"):
-            raise NoResult(
-                "traffic %r signs with the deployment's identities and "
-                "configuration %r states no genesis" % (
-                    cell.entry["traffic"], cell.entry["config"]))
-        if args.seeds or args.sweep:
-            import builder
-            return builder.main(args, cell, procs, workdir)
-        return single(args, cell, procs, workdir)
-    except NoResult as e:
-        print("no result: %s" % e, file=sys.stderr)
-        return 3
+        code = run_cell(args, procs, workdir)
+    except (NoResult, RuntimeError) as e:
+        # RuntimeError: the harness's own word for a set-up that did not
+        # get there (a node never came up, a burst never settled); the
+        # pool's log tails are on standard error by now
+        why = " ".join(str(e).split())
+    except Exception as e:
+        traceback.print_exc()
+        why = "uncaught %s: %s" % (type(e).__name__,
+                                   " ".join(str(e).split()))
+    except SystemExit as e:      # the SIGTERM handler's
+        code, why = e.code, "ended by SIGTERM %.0f s after the start" % (
+            time.perf_counter() - T_PROCESS)
     finally:
+        # everything stopped and logged before the last word is said
         procs.stop()
         shutil.rmtree(workdir, ignore_errors=True)
+        if why is not None:
+            print("no result: %s" % why, file=sys.stderr, flush=True)
+    return code
+
+
+def run_cell(args, procs, workdir) -> int:
+    cell = Cell(args.workload)
+    if args.seconds is None:
+        args.seconds = float(cell.bench["run_seconds"])
+    if args.tiny:
+        for key, value in cell.traffic.get("tiny", {}).items():
+            cell.traffic["params"][key] = value
+        if "genesis" in cell.config["tiny"]:
+            cell.config["genesis"] = cell.config["tiny"]["genesis"]
+    if operations.uses_genesis(cell.traffic["operations"]) \
+            and not cell.config.get("genesis"):
+        raise NoResult(
+            "traffic %r signs with the deployment's identities and "
+            "configuration %r states no genesis" % (
+                cell.entry["traffic"], cell.entry["config"]))
+    if args.seeds or args.sweep:
+        import builder
+        return builder.main(args, cell, procs, workdir)
+    return single(args, cell, procs, workdir)
 
 
 if __name__ == "__main__":
