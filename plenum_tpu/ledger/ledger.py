@@ -142,35 +142,19 @@ class Ledger:
         return txns
 
     def appendTxns(self, txns: List[dict]) -> Tuple[Tuple[int, int], List[dict]]:
-        """Stage txns: extend the shadow tree, track uncommitted root.
-        Returns ((start, end), txns)."""
-        return self.stage_txns_collect(self.stage_txns_dispatch(txns))
-
-    def stage_txns_dispatch(self, txns: List[dict]):
-        """Async half of appendTxns: serialize the batch and LAUNCH the
-        leaf-hash computation (ONE seam dispatch, device-backed above
-        the TreeHasher threshold) without syncing the digests — the
-        fused per-3PC-batch dispatch overlaps the MPT pending-apply
-        under this launch. No other staging may touch this ledger
-        between dispatch and collect (the executor stages one batch at
-        a time per ledger)."""
+        """Stage txns: serialize the batch, hash its leaves in ONE seam
+        call (device-backed above the TreeHasher threshold where the
+        hasher has a batch backend) and merge them into the shadow
+        frontier (O(b log n) cheap host work). Returns ((start, end),
+        txns)."""
         if self.uncommittedTree is None:
             self.uncommittedTree = self.tree.copy_shadow()
         serialize = self.serialize_for_tree
         serialized_all = [serialize(txn) for txn in txns]
-        return (txns, serialized_all,
-                self.hasher.hash_leaves_dispatch(serialized_all))
-
-    def stage_txns_collect(self, staged) -> Tuple[Tuple[int, int],
-                                                  List[dict]]:
-        """Blocking half of appendTxns: collect the launched leaf
-        hashes and merge them into the shadow frontier (O(b log n)
-        cheap host work)."""
-        txns, serialized_all, handle = staged
         first = self.uncommitted_size + 1
         shadow_append = self.uncommittedTree._append_hash
         blob_append = self._uncommitted_blobs.append
-        leaf_hashes = self.hasher.hash_leaves_collect(handle)
+        leaf_hashes = self.hasher.hash_leaves(serialized_all)
         for serialized, leaf_hash in zip(serialized_all, leaf_hashes):
             shadow_append(leaf_hash, want_path=False)
             blob_append((serialized, leaf_hash))

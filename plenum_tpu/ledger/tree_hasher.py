@@ -38,26 +38,6 @@ class TreeHasher:
             return self._batch_backend.leaf_hashes(datas)
         return [self.hash_leaf(d) for d in datas]
 
-    def hash_leaves_dispatch(self, datas: Sequence[bytes]):
-        """Launch-only half of hash_leaves: above the device threshold
-        the batch is padded and LAUNCHED without syncing the result, so
-        the caller overlaps independent host work (the fused per-3PC-
-        batch dispatch) before hash_leaves_collect. On the scalar floor
-        the digests are computed eagerly and the handle just carries
-        them — dispatch+collect is then exactly hash_leaves."""
-        if (self._batch_backend is not None
-                and len(datas) >= self._batch_threshold
-                and hasattr(self._batch_backend, "leaf_hashes_dispatch")):
-            return ("device", self._batch_backend.leaf_hashes_dispatch(
-                datas))
-        return ("host", [self.hash_leaf(d) for d in datas])
-
-    def hash_leaves_collect(self, handle) -> List[bytes]:
-        kind, payload = handle
-        if kind == "device":
-            return self._batch_backend.leaf_hashes_collect(payload)
-        return payload
-
     def hash_node_pairs(self, pairs: Sequence[Tuple[bytes, bytes]]) -> List[bytes]:
         if (self._batch_backend is not None
                 and len(pairs) >= self._batch_threshold):

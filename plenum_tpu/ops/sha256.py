@@ -342,27 +342,11 @@ def sha256_node_pairs_array(pairs: np.ndarray) -> np.ndarray:
 
 def sha256_many(msgs: Sequence[bytes]) -> List[bytes]:
     """Batched SHA-256 over arbitrary same-or-mixed-length messages."""
-    return sha256_many_collect(sha256_many_dispatch(msgs))
-
-
-def sha256_many_dispatch(msgs: Sequence[bytes]):
-    """Async half of sha256_many: host padding + device LAUNCH, no
-    result sync — the returned handle's digests are still in flight, so
-    the caller can overlap independent host work (the fused per-3PC-
-    batch dispatch overlaps the MPT pending-apply under this launch)
-    before sha256_many_collect pulls the bytes."""
     if not msgs:
-        return None
-    words, nvalid, nblocks = pad_messages(msgs)
-    return sha256_blocks_routed(jnp.asarray(words), jnp.asarray(nvalid),
-                                nblocks)
-
-
-def sha256_many_collect(handle) -> List[bytes]:
-    """Blocking half: digests of a sha256_many_dispatch launch."""
-    if handle is None:
         return []
-    return digests_to_bytes(np.asarray(handle))
+    words, nvalid, nblocks = pad_messages(msgs)
+    return digests_to_bytes(np.asarray(sha256_blocks_routed(
+        jnp.asarray(words), jnp.asarray(nvalid), nblocks)))
 
 
 class JaxSha256Backend:
@@ -370,13 +354,6 @@ class JaxSha256Backend:
 
     def leaf_hashes(self, datas: Sequence[bytes]) -> List[bytes]:
         return sha256_many([b"\x00" + d for d in datas])
-
-    def leaf_hashes_dispatch(self, datas: Sequence[bytes]):
-        """Launch-only half of leaf_hashes (fused-dispatch seam)."""
-        return sha256_many_dispatch([b"\x00" + d for d in datas])
-
-    def leaf_hashes_collect(self, handle) -> List[bytes]:
-        return sha256_many_collect(handle)
 
     def node_hashes(self, pairs: Sequence[Tuple[bytes, bytes]]) -> List[bytes]:
         return sha256_many([b"\x01" + l + r for l, r in pairs])

@@ -20,11 +20,11 @@ budget line by line:
 * ``lane_apply`` — the per-request validate→apply stream in batch
   order (the canonical schedule every schedule must be byte-equal to);
   validation reads are dict hits against pending-buffer + read window.
-* ``hash_resolve`` — ONE merged hash resolution for every state the
-  batch wrote (``flush_states_merged``: per-state bulk structural
-  merge, then all states' dirty nodes hashed in shared level-wise
-  SHA3 dispatches), overlapped with the ledger leaf-hash launches and
-  the verifier-hub kick inside the fused device window.
+* ``hash_resolve`` — the ledger leaf hashes and ONE hash resolution
+  for every state the batch wrote, inside the fused window beside the
+  verifier-hub kick, on the host on every platform: leaves through the
+  ledger's hasher (hashlib on a CPU-backend node) and each written
+  state's pending buffer through one host-trie ``set_many``.
 
 Lane assignment is a pure function of the ordered batch — every honest
 node partitions identically — and the applied state is a function of
@@ -89,11 +89,12 @@ class NodeBatchExecutor(BatchExecutor):
             (lambda d, r, s: None)
         # pipeline execution fan-out (set_exec_map); None = serial
         self._exec_map = None
-        # fused per-3PC-batch device dispatch (Config.FUSED_BATCH_
-        # DISPATCH): the batch's ledger leaf-hash launch, a verifier-hub
-        # kick, and the MPT pending-apply share ONE overlapped device
-        # window per applied batch instead of serialized round trips.
-        # device_kick() flushes whatever verify generation is queued
+        # fused per-3PC-batch window (Config.FUSED_BATCH_DISPATCH): the
+        # batch's ledger leaf hashes, a verifier-hub kick and every
+        # written state's flush through the host trie share ONE window
+        # per applied batch (the fused_dispatch span) instead of
+        # serialized round trips: _stage_and_resolve. device_kick()
+        # flushes whatever verify generation is queued
         # (CoalescingVerifierHub) into that same window.
         self._fused = fused_dispatch
         self._device_kick = device_kick
@@ -267,37 +268,33 @@ class NodeBatchExecutor(BatchExecutor):
     def _stage_and_resolve(self, staged: Dict[int, List[dict]], state,
                            ledger, n_valid: int, pp_digest: str) -> str:
         """hash_resolve sub-stage: stage every ledger group's txns and
-        resolve every written state's dirty trie nodes in ONE merged
-        level-wise pass, all inside the fused device window."""
+        resolve every written state's dirty trie nodes. The fused window
+        kicks the verifier hub's queued generation, then stages each
+        group's leaves and flushes each written state's pending buffer
+        through the host trie in one ``set_many``, whatever engine is
+        attached: on the CPU backend the engine's bulk merge is pure
+        Python and its level hashes XLA on the CPU, and no cell runs
+        an accelerator node's window. Same write set, same roots."""
         if self._fused and staged:
-            # FUSED per-batch device window: launch every ledger group's
-            # leaf-hash dispatch, kick the verifier hub's queued
-            # generation into the same window, run the merged MPT
-            # pending-resolve (per-state bulk structural merge + shared
-            # level-wise hash dispatches across ALL written states)
-            # WHILE those launches are in flight, then collect the
-            # staged hashes — one overlapped round trip where the
-            # per-message path serialized them. Results are
-            # bit-identical: the streams touch disjoint structures and
-            # each collect point is unchanged.
             with self.telemetry.timer(TM.STAGE_DISPATCH_MS), \
                     self.tracer.span(
                     "fused_dispatch", CAT_DEVICE, key=pp_digest or None,
-                    groups=len(staged), batch_size=n_valid):
-                in_flight = [
-                    (lid, self.db.get_ledger(lid).stage_txns_dispatch(
-                        txns))
-                    for lid, txns in staged.items()]
+                    groups=len(staged), batch_size=n_valid) as sp:
                 if self._device_kick is not None:
                     self._device_kick()
-                state_root = self._resolve_states(staged, state, ledger)
-                for lid, handle in in_flight:
-                    self.db.get_ledger(lid).stage_txns_collect(handle)
-        else:
-            for lid, txns in staged.items():
-                self.db.get_ledger(lid).appendTxns(txns)
-            state_root = self._resolve_states(staged, state, ledger)
-        return state_root
+                for lid, txns in staged.items():
+                    self.db.get_ledger(lid).appendTxns(txns)
+                    written = self.db.get_state(lid)
+                    if written is not None:
+                        written.flush_on_host()
+                if self.tracer.enabled:
+                    # the writes the host route served: what
+                    # exec_host_apply_pct reads against batch_size
+                    sp.add(host_applied=n_valid)
+            return ledger.hashToStr(state.headHash) if state else ""
+        for lid, txns in staged.items():
+            self.db.get_ledger(lid).appendTxns(txns)
+        return self._resolve_states(staged, state, ledger)
 
     def set_exec_map(self, fn) -> None:
         """Install the pipeline's execution fan-out: an

@@ -56,6 +56,15 @@ of hash dispatches per applied 3PC batch:
    "auto"); on CPU hosts hashlib beats per-level dispatch overhead at
    MPT node counts.
 
+Where a serving 3PC batch's hash work runs: on the host on every
+platform. The executor's fused window (server/executor.py
+``_stage_and_resolve``) flushes each written state's buffer through
+the host trie in one ``set_many`` (``PruningState.flush_on_host``) and
+never through this engine, whose bulk merge is pure Python and whose
+level hashes are XLA on a CPU-backend node. The engine serves the
+non-fused executor path (``Config.FUSED_BATCH_DISPATCH`` = False) and
+any flush outside a batch's window.
+
 Both halves are byte-equal to ``apply_batch`` (and to the host trie):
 the MPT is content-canonical, so the bulk merge and the per-key walk
 produce the identical tree for the identical final mapping.

@@ -19,6 +19,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+from plenum_tpu.common.config import Config
 from plenum_tpu.common.constants import (
     AUDIT_LEDGER_ID, CONFIG_LEDGER_ID, DATA, DOMAIN_LEDGER_ID, NODE, NYM,
     POOL_LEDGER_ID, ROLE, STEWARD, TARGET_NYM, TRUSTEE, VERKEY)
@@ -233,7 +234,7 @@ def test_read_window_dropped_on_flush_and_revert():
 
 # --------------------------------------------------- executor stacks
 
-def build_stack(lanes, n_base=60, lane_min=2):
+def build_stack(lanes, n_base=60, lane_min=2, fused=True):
     dm = NodeBootstrap.init_storage()
     wm, _rm = NodeBootstrap.init_managers(dm)
     state = dm.get_state(DOMAIN_LEDGER_ID)
@@ -248,7 +249,7 @@ def build_stack(lanes, n_base=60, lane_min=2):
     store = {}
     rejects = []
     executor = NodeBatchExecutor(
-        wm, store.get, lanes=lanes, lane_min=lane_min,
+        wm, store.get, lanes=lanes, lane_min=lane_min, fused_dispatch=fused,
         on_request_rejected=lambda d, r, s: rejects.append((d, r, s)))
     return dm, wm, executor, store, rejects
 
@@ -319,13 +320,15 @@ def _adversarial_batch(rng, i0):
     return reqs
 
 
-def test_lanes_vs_serial_randomized_adversarial_equivalence():
+@pytest.mark.parametrize("fused", [True, False])
+def test_lanes_vs_serial_randomized_adversarial_equivalence(fused):
     """The headline gate: identical digest streams through the lane
     executor and the serial executor give byte-equal roots after every
     batch, commit, and mid-stream view-change revert — and identical
-    reject streams (same digests, same seq numbers)."""
+    reject streams (same digests, same seq numbers). In the fused
+    window (the host trie) and through the engine's merged resolve."""
     from plenum_tpu.common.messages.node_messages import Ordered
-    stacks = {mode: build_stack(mode) for mode in (True, False)}
+    stacks = {mode: build_stack(mode, fused=fused) for mode in (True, False)}
     rng_master = random.Random(1234)
     pp_time = TS + 10
     applied = []
@@ -524,6 +527,66 @@ def test_exec_substage_spans_and_lane_telemetry():
     assert hists[TM.EXEC_LANES_PER_BATCH]["count"] == 1
     assert hists[TM.EXEC_CONFLICT_PCT]["count"] == 1
     assert snap["counters"][TM.EXEC_SERIAL_FALLBACK] == 1  # the NODE txn
+
+
+@pytest.mark.parametrize("accelerator,policy", [
+    (False, "auto"), (True, "auto"), (True, True), (False, False)])
+def test_fused_window_applies_on_the_host_on_every_platform(
+        monkeypatch, accelerator, policy):
+    """Whatever the platform and EXEC_MERGED_DEVICE_HASH, the fused
+    window flushes every written state (domain and pool here) through
+    flush_on_host and never reaches begin_apply, and fused_dispatch
+    carries host_applied (the batch's valid writes) while armed; the
+    roots are the engine's merged resolve's (the non-fused path, level
+    hashes pinned to the device), which merges through begin_apply."""
+    from plenum_tpu.observability.tracing import Tracer
+    from plenum_tpu.ops import mesh
+    from plenum_tpu.state.device_state import DeviceStateEngine
+    monkeypatch.setattr(mesh, "is_accelerator", lambda: accelerator)
+    calls = {"begin_apply": 0, "flush_on_host": 0}
+    for cls, name in ((DeviceStateEngine, "begin_apply"),
+                      (PruningState, "flush_on_host")):
+        def counted(self, *args, _name=name, _fn=getattr(cls, name)):
+            calls[_name] += 1
+            return _fn(self, *args)
+        monkeypatch.setattr(cls, name, counted)
+    batch = [nym_req(i, "route%d" % i, verkey="~r%d" % i)
+             for i in range(20)]
+    batch += [node_req(100 + i, "N%d" % i, author="trustee1")
+              for i in range(9)]
+    batch.append(nym_req(200, "refused", author="nobody", role=TRUSTEE))
+    roots = {}
+    for fused in (True, False):
+        monkeypatch.setattr(Config, "EXEC_MERGED_DEVICE_HASH",
+                            policy if fused else True)
+        dm, wm, executor, store, rejects = build_stack(lanes=True,
+                                                       fused=fused)
+        # the JAX leaf seam is attached only where the backend is an
+        # accelerator: hashlib on every CPU-backend node
+        assert (dm.get_ledger(DOMAIN_LEDGER_ID).hasher._batch_backend
+                is not None) == accelerator
+        executor.tracer = Tracer(name="X", capacity=4096)
+        digests = []
+        for req in batch:
+            store[req.digest] = req
+            digests.append(req.digest)
+        for k in calls:
+            calls[k] = 0
+        executor.apply_batch(digests, DOMAIN_LEDGER_ID, TS + 60)
+        roots[fused] = all_roots(dm)
+        args = [a for _k, name, _c, _t0, _t1, _key, a
+                in executor.tracer.spans() if name == "fused_dispatch"]
+        assert len(rejects) == 1
+        if fused:
+            assert args == [dict(args[0], batch_size=29, host_applied=29)]
+            assert calls == {"begin_apply": 0, "flush_on_host": 2}
+        else:
+            # the engine merges the domain state's 20 keys; the pool
+            # state's one key is under STATE_DEVICE_BATCH_MIN and takes
+            # the host trie inside the engine's flush
+            assert args == []
+            assert calls == {"begin_apply": 1, "flush_on_host": 0}
+    assert roots[True] == roots[False]
 
 
 def test_lane_min_gates_planning():

@@ -22,11 +22,13 @@ import test_control  # noqa: E402
 import traffic  # noqa: E402
 from client import Op, reply_body  # noqa: E402
 from operations import nym_rewrite_owners  # noqa: E402
+from operations import nym_write_authors  # noqa: E402
 from operations.nym_write_authors import authors  # noqa: E402
 from reference import pool as ref  # noqa: E402
 
 from plenum_tpu.common.config import Config  # noqa: E402
-from plenum_tpu.common.constants import DOMAIN_LEDGER_ID, NYM  # noqa: E402
+from plenum_tpu.common.constants import (  # noqa: E402
+    AUDIT_LEDGER_ID, DOMAIN_LEDGER_ID, NYM)
 from plenum_tpu.common.messages.node_messages import (  # noqa: E402
     Ordered, Propagate, Reply, RequestNack)
 from plenum_tpu.common.request import Request  # noqa: E402
@@ -142,19 +144,24 @@ def apply_requests(executor, store, reqs, pp_time):
     return executor.apply_batch(digests, DOMAIN_LEDGER_ID, pp_time)
 
 
-PATHS = [(engine, lanes, fused, native)
+PATHS = [(engine, lanes, fused, native, "auto")
          for engine in (False, True) for lanes in (False, True)
-         for fused in (False, True) for native in (True, False)]
+         for fused in (False, True) for native in (True, False)] + [
+    (True, lanes, False, native, True)
+    for lanes in (False, True) for native in (True, False)]
 
 
-@pytest.mark.parametrize("engine,lanes,fused,native", PATHS)
+@pytest.mark.parametrize("engine,lanes,fused,native,device_hash", PATHS)
 def test_three_batches_reach_the_references_roots(
-        monkeypatch, engine, lanes, fused, native):
+        monkeypatch, engine, lanes, fused, native, device_hash):
     """STATE_DEVICE_ENGINE x EXEC_LANES x FUSED_BATCH_DISPATCH over the
-    native and the Python trie: whichever of the trie's update paths
-    (trie.py _update, mpt_c.c set_many, device_state.py _DeferredTrie
-    and _bulk_merge) a batch of rewrites takes, the uncommitted and
-    the committed roots are Replay's."""
+    native and the Python trie, and the engine's merged resolve with
+    its level hashes pinned to the device dispatches
+    (EXEC_MERGED_DEVICE_HASH = True): whichever of the trie's update
+    paths (trie.py _update, mpt_c.c set_many, device_state.py
+    _DeferredTrie and _bulk_merge) a batch of rewrites takes, the
+    uncommitted and the committed roots are Replay's."""
+    monkeypatch.setattr(Config, "EXEC_MERGED_DEVICE_HASH", device_hash)
     if not native:
         monkeypatch.setattr(pruning_state, "_TrieBackend", Trie)
     elif pruning_state._TrieBackend is Trie:
@@ -197,6 +204,86 @@ def test_three_batches_reach_the_references_roots(
     for did, record in replay.records.items():
         got, _seq, _time = handler.get_nym_details(did)
         assert got == record, did
+
+
+# ----------------------- the fused window's route: host against engines
+
+def thousand_writes(seed):
+    """A 3PC batch of 1,000 valid writes: 600 creations of fresh DIDs
+    by role-less genesis identities and 400 owners' rewrites of their
+    own genesis NYMs (zipfian, several writes of one key), interleaved
+    three to two."""
+    create = [r for r, ok in nym_write_authors.make(
+        seed, 620, MIX, {"identities": IDENTITIES}, first_req_id=10 ** 6)
+        if ok][:600]
+    rewrite = [r for r, ok in made(seed, 420) if ok][:400]
+    return [req for i in range(200)
+            for req in create[3 * i:3 * i + 3] + rewrite[2 * i:2 * i + 2]]
+
+
+def route_run(monkeypatch, fused, lanes, seed=2147483911):
+    """The batch through NodeBatchExecutor, in the fused window
+    (`fused`) or through the engine's merged resolve with its level
+    hashes pinned to the device dispatches: apply, revert, apply again,
+    commit. A genesis DID that no write names is deleted from the state
+    while the 500th request applies (no handler deletes, so the delete
+    is planted). → (the roots after each step, the fused_dispatch
+    spans' arguments)."""
+    from plenum_tpu.observability.tracing import Tracer
+    monkeypatch.setattr(Config, "EXEC_MERGED_DEVICE_HASH", True)
+    dm = NodeBootstrap.init_storage(config=Config(STATE_DEVICE_ENGINE=True))
+    wm, _rm = NodeBootstrap.init_managers(dm)
+    NodeBootstrap.load_genesis(wm, genesis(seed))
+    reqs = thousand_writes(seed)
+    named = {r["operation"]["dest"] for r in reqs}
+    gone = next(t["txn"]["data"]["dest"] for t in genesis(seed)[1:]
+                if t["txn"]["data"]["dest"] not in named)
+    state = dm.get_state(DOMAIN_LEDGER_ID)
+    apply = wm.apply_request_deferred
+    at = Request.from_dict(dict(reqs[499])).digest
+
+    def apply_and_delete(request, *args):
+        out = apply(request, *args)
+        if request.digest == at:
+            state.remove(nym_to_state_key(gone))
+        return out
+
+    wm.apply_request_deferred = apply_and_delete
+    store = {}
+    executor = NodeBatchExecutor(wm, store.get, lanes=lanes,
+                                 fused_dispatch=fused)
+    executor.tracer = Tracer(name="X", capacity=4096)
+    roots = [apply_requests(executor, store, reqs, TS)]
+    assert executor.revert_unordered_batches() == 1
+    roots.append(state.headHash)
+    roots.append(apply_requests(executor, store, reqs, TS))
+    executor.commit_batch(ordered(1, roots[-1], TS))
+    ledgers = [dm.get_ledger(lid) for lid in (DOMAIN_LEDGER_ID,
+                                              AUDIT_LEDGER_ID)]
+    roots.append([str(lg.root_hash) for lg in ledgers]
+                 + [state.committedHeadHash, state.get(
+                     nym_to_state_key(gone)), ledgers[0].size])
+    args = [a for _k, name, _c, _t0, _t1, _key, a
+            in executor.tracer.spans() if name == "fused_dispatch"]
+    return roots, args
+
+
+@pytest.mark.parametrize("lanes", [True, False])
+def test_fused_window_applies_on_the_host_byte_equal_to_the_engines(
+        monkeypatch, lanes):
+    """The fused window serves a batch of 1,000 creations, rewrites and
+    a delete on the host (hashlib leaves, one set_many) with the engines
+    attached and their level hashes pinned to the device, and every
+    root (state, txn, audit) after apply, revert, re-apply and commit
+    is the engine's merged resolve's."""
+    host_roots, host_args = route_run(monkeypatch, True, lanes)
+    device_roots, device_args = route_run(monkeypatch, False, lanes)
+    assert host_roots == device_roots
+    assert host_roots[-1][3] is None               # the delete landed
+    assert host_roots[-1][4] == IDENTITIES + 1 + 1000
+    assert [a["host_applied"] for a in host_args] == [1000, 1000]
+    assert [a["batch_size"] for a in host_args] == [1000, 1000]
+    assert device_args == []
 
 
 # ------------------------------------------------------------ the lanes
