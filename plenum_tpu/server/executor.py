@@ -282,15 +282,23 @@ class NodeBatchExecutor(BatchExecutor):
                     groups=len(staged), batch_size=n_valid) as sp:
                 if self._device_kick is not None:
                     self._device_kick()
+                nodes = framed = 0
                 for lid, txns in staged.items():
                     self.db.get_ledger(lid).appendTxns(txns)
                     written = self.db.get_state(lid)
                     if written is not None:
-                        written.flush_on_host()
+                        wrote = written.flush_on_host()
+                        if wrote is not None:
+                            nodes += wrote[0]
+                            framed += wrote[1]
                 if self.tracer.enabled:
-                    # the writes the host route served: what
-                    # exec_host_apply_pct reads against batch_size
-                    sp.add(host_applied=n_valid)
+                    # the writes the host route served, what
+                    # exec_host_apply_pct reads against batch_size; the
+                    # trie nodes the flush wrote and how many went out
+                    # in batch frames: exec_trie_nodes_per_write and
+                    # exec_trie_framed_pct
+                    sp.add(host_applied=n_valid, trie_nodes=nodes,
+                           trie_nodes_framed=framed)
             return ledger.hashToStr(state.headHash) if state else ""
         for lid, txns in staged.items():
             self.db.get_ledger(lid).appendTxns(txns)

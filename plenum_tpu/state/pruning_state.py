@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import logging
 from abc import ABC, abstractmethod
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 from plenum_tpu.common.serializers.base58 import b58encode
 from plenum_tpu.state.device_state import CorruptStateError
@@ -220,7 +220,7 @@ class PruningState(State):
         handle.state = self
         return handle
 
-    def flush_on_host(self) -> None:
+    def flush_on_host(self) -> Optional[Tuple[int, int]]:
         """Flush the whole pending buffer through the host trie in ONE
         `set_many` (`NativeTrie`; key by key on the Python trie),
         whatever engine is attached: a serving batch's fused window
@@ -228,25 +228,30 @@ class PruningState(State):
         A 1,000-key flush is 36 ms through `set_many` where the
         engine's bulk merge and resolve through XLA on the CPU backend
         take 246 ms; the root and every node reachable from it are the
-        same (the trie is content-canonical)."""
+        same (the trie is content-canonical). → (trie nodes written to
+        the store, of them in batch frames); None where the Python trie
+        wrote them and did not count."""
         self._read_window = None
         pending, self._pending = self._pending, {}
-        if pending:
-            self._host_apply_pairs(pending)
+        if not pending:
+            return 0, 0
+        return self._host_apply_pairs(pending)
 
-    def _host_apply_pairs(self, pending: dict) -> None:
+    def _host_apply_pairs(self, pending: dict
+                          ) -> Optional[Tuple[int, int]]:
         """A popped pending buffer through the host trie (the host
         route, and the fallback of an engine failure mid-flush): same
-        write set, same final root."""
+        write set, same final root. → what `NativeTrie.set_many`
+        returns; None on the Python trie."""
         set_many = getattr(self._trie, "set_many", None)
         if set_many is not None:
-            set_many(list(pending.items()))
-            return
+            return set_many(list(pending.items()))
         for k, v in pending.items():
             if v:
                 self._trie.set(k, v)
             else:
                 self._trie.delete(k)
+        return None
 
     def get(self, key: bytes, isCommitted: bool = True) -> Optional[bytes]:
         if isCommitted:

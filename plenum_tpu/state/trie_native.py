@@ -6,12 +6,20 @@ work; this wrapper keeps the durable-KV contract identical to the
 Python trie: every node created by an operation is written through to
 the KV store before the call returns, and on a node miss (fresh process
 over an existing store) the C side hydrates lazily through a callback
-into the same KV. Roots are bit-identical to the Python implementation
-(cross-checked in tests/test_mpt_native.py) — they are consensus state.
+into the same KV. The nodes of one operation reach the store in one
+`setBatch`, which a durable store writes as one atomic frame with one
+flush; a frame torn by a crash is dropped whole on reopen, and that
+loses nothing a committed root names, because the trie is
+content-addressed and the committed root is stored only at commit
+(`PruningState.commit`). Roots are bit-identical to the Python
+implementation (cross-checked in tests/test_mpt_native.py) — they are
+consensus state.
 """
 from __future__ import annotations
 
-from typing import List, Optional
+from bisect import bisect_right
+from itertools import accumulate
+from typing import List, Optional, Tuple
 
 from plenum_tpu.native import try_load_ext
 
@@ -23,6 +31,26 @@ if _mpt is None:
     raise ImportError("native MPT unavailable or disabled")
 
 BLANK_ROOT = _mpt.blank_root()
+
+# The most bytes of kvlog records (8-byte header, key, blob) one frame
+# carries. A drain past it goes out as consecutive frames: a genesis
+# load drains every node of its trie at once (about 150,000 for 100,005
+# NYMs, ten times that at 1,000,000), and the cap bounds the packed
+# buffer a frame is built in, far under the 4 GiB a frame's u32 length
+# can state.
+_FRAME_BYTES = 64 << 20
+
+
+def _frames(nodes) -> list:
+    """`nodes` [(hash, blob)] cut into consecutive slices of at most
+    _FRAME_BYTES of records each (a single larger node goes alone)."""
+    ends = list(accumulate(8 + len(h) + len(b) for h, b in nodes))
+    frames, start, base = [], 0, 0
+    while start < len(nodes):
+        stop = max(bisect_right(ends, base + _FRAME_BYTES), start + 1)
+        frames.append(nodes[start:stop])
+        start, base = stop, ends[stop - 1]
+    return frames
 
 
 class NativeTrie:
@@ -43,23 +71,29 @@ class NativeTrie:
 
     # ---------------------------------------------------------- write
 
-    def _flush(self):
-        put = self._store.put
-        for h, blob in _mpt.drain(self._h):
-            put(h, blob)
+    def _flush(self) -> Tuple[int, int]:
+        """Write the operation's new nodes through, one store batch per
+        frame → (nodes written, of them in batch frames)."""
+        nodes = _mpt.drain(self._h)
+        framed = 0
+        for frame in _frames(nodes):
+            self._store.setBatch(frame)
+            framed += len(frame)
+        return len(nodes), framed
 
     def set(self, key: bytes, value: bytes):
         self.root_hash = _mpt.set(self._h, self.root_hash, bytes(key),
                                   bytes(value))
         self._flush()
 
-    def set_many(self, pairs):
+    def set_many(self, pairs) -> Tuple[int, int]:
         """Batched set (empty value deletes): one deferred-hash C pass —
         path nodes shared by the batch hash once, not once per key.
-        Only the final root is a readable snapshot."""
+        Only the final root is a readable snapshot. → (nodes written,
+        of them in batch frames)."""
         self.root_hash = _mpt.set_many(self._h, self.root_hash,
                                        list(pairs))
-        self._flush()
+        return self._flush()
 
     def delete(self, key: bytes):
         self.root_hash = _mpt.delete(self._h, self.root_hash, bytes(key))

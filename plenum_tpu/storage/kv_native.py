@@ -4,9 +4,12 @@ reference storage/kv_store_rocksdb.py:15).
 
 Same .kvlog on-disk format as KeyValueStorageFile, so the two backends
 open each other's files; unlike the Python backend, VALUES STAY ON
-DISK — only the C index (key bytes + offsets) is resident. A sorted
-key cache on the Python side provides ordered iteration; it is rebuilt
-from the C index snapshot on open and maintained incrementally after.
+DISK — only the C index (key bytes + offsets) is resident. A key cache
+on the Python side answers membership and ordered iteration: keys
+written since the last ordered read wait in a plain set (one C-level
+add per put, one bulk update per batch frame) and join the sorted view
+at the next `iterator()`; on open every key of the C index snapshot
+starts there.
 """
 from __future__ import annotations
 
@@ -21,6 +24,8 @@ except ImportError:            # soft dep: stdlib fallback
     from plenum_tpu.utils.sorted_fallback import SortedSet
 
 from plenum_tpu.storage.kv_store import KeyValueStorage, to_bytes
+
+_REC = struct.Struct("<II")     # record header: key length, value length
 
 _lib = None
 
@@ -82,7 +87,10 @@ class KeyValueStorageNative(KeyValueStorage):
         if not self._db:
             raise IOError("kvlog open failed: {}".format(self._path))
         self._closed = False
-        self._keys = SortedSet(self._snapshot_keys())
+        # live keys = _keys (sorted view) | _fresh (written since the
+        # last ordered read); a key may sit in both until the merge
+        self._keys = SortedSet()
+        self._fresh = set(self._snapshot_keys())
 
     def _handle(self):
         """The C engine dereferences the handle unchecked — a NULL from
@@ -112,7 +120,7 @@ class KeyValueStorageNative(KeyValueStorage):
         if self._lib.kv_put(self._handle(), key, len(key), value,
                             len(value)) != 0:
             raise IOError("kvlog put failed")
-        self._keys.add(key)
+        self._fresh.add(key)
 
     def get(self, key) -> bytes:
         key = to_bytes(key)
@@ -130,7 +138,7 @@ class KeyValueStorageNative(KeyValueStorage):
 
     def get_or_none(self, key):
         key = to_bytes(key)
-        if key not in self._keys:
+        if key not in self._fresh and key not in self._keys:
             return None
         return self.get(key)
 
@@ -138,36 +146,33 @@ class KeyValueStorageNative(KeyValueStorage):
         key = to_bytes(key)
         if self._lib.kv_remove(self._handle(), key, len(key)) != 0:
             raise IOError("kvlog remove failed")
+        self._forget(key)
+
+    def _forget(self, key: bytes):
+        self._fresh.discard(key)
         self._keys.discard(key)
 
-    def _apply_packed(self, parts, ordered_ops):
-        """ordered_ops = [(key, is_put)] in BATCH ORDER — the key cache
-        must see remove-then-put of one key end live, like the engine."""
+    def _write_frame(self, parts):
+        """One FFI call and one flush: the packed records applied by the
+        engine as a single atomic batch frame."""
         packed = b"".join(parts)
         if self._lib.kv_apply_packed(self._handle(), packed,
                                      len(packed)) != 0:
             raise IOError("kvlog batch failed")
-        for key, is_put in ordered_ops:
-            if is_put:
-                self._keys.add(key)
-            else:
-                self._keys.discard(key)
 
     def setBatch(self, batch: Iterable[Tuple]):
-        """One FFI call: records packed host-side into the wire format,
-        applied by the engine as a single atomic batch frame."""
-        parts, ops = [], []
-        for key, value in batch:
-            key, value = to_bytes(key), to_bytes(value)
-            parts.append(struct.pack("<II", len(key), len(value)))
-            parts.append(key)
-            parts.append(value)
-            ops.append((key, True))
-        self._apply_packed(parts, ops)
+        """Records packed host-side into the wire format and written as
+        one frame; its keys join the key cache in one bulk update."""
+        pairs = [(to_bytes(k), to_bytes(v)) for k, v in batch]
+        pack = _REC.pack
+        self._write_frame([pack(len(k), len(v)) + k + v for k, v in pairs])
+        self._fresh.update([k for k, _ in pairs])
 
     def do_ops_in_batch(self, batch: Iterable[Tuple]):
         """batch of ('put', key, value) / ('remove', key) — one atomic
-        on-disk frame, like setBatch."""
+        on-disk frame, like setBatch. The key cache sees the ops IN
+        BATCH ORDER, so remove-then-put of one key ends live, like the
+        engine."""
         parts, ops = [], []
         for op, key, *rest in batch:
             key = to_bytes(key)
@@ -183,12 +188,20 @@ class KeyValueStorageNative(KeyValueStorage):
                 ops.append((key, False))
             else:
                 raise ValueError("unknown batch op {}".format(op))
-        self._apply_packed(parts, ops)
+        self._write_frame(parts)
+        for key, is_put in ops:
+            if is_put:
+                self._fresh.add(key)
+            else:
+                self._forget(key)
 
     def iterator(self, start=None, end=None,
                  include_value=True) -> Iterator:
         start = to_bytes(start) if start is not None else None
         end = to_bytes(end) if end is not None else None
+        if self._fresh:
+            self._keys.update(self._fresh)
+            self._fresh = set()
         keys = list(self._keys.irange(start, end))
         if include_value:
             # materialized snapshot, like the file backend: mutations
@@ -219,6 +232,7 @@ class KeyValueStorageNative(KeyValueStorage):
             os.unlink(self._path)
         self._db = self._lib.kv_open(self._path.encode())
         self._keys = SortedSet()
+        self._fresh = set()
 
     def close(self):
         if not self._closed:

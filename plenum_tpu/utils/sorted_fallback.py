@@ -6,7 +6,7 @@ unchanged (its amortized splits beat plain `insort` on huge
 collections); when absent — some deployment images ship without it —
 these fallbacks keep the runtime/storage layers importable with the
 same semantics for the small API surface actually exercised here
-(add/discard/pop/irange/items). O(n) inserts are acceptable at the
+(add/update/discard/pop/irange/items). O(n) inserts are acceptable at the
 sizes involved: stash replay queues and KV iteration indexes."""
 import bisect
 from typing import Any, Callable, Iterable, Optional
@@ -50,6 +50,10 @@ class SortedSet:
         if key not in self._set:
             self._set.add(key)
             bisect.insort(self._keys, key)
+
+    def update(self, iterable: Iterable) -> None:
+        self._set.update(iterable)
+        self._keys = sorted(self._set)
 
     def discard(self, key: Any) -> None:
         if key in self._set:

@@ -589,6 +589,41 @@ def test_fused_window_applies_on_the_host_on_every_platform(
     assert roots[True] == roots[False]
 
 
+def test_fused_dispatch_counts_the_trie_nodes_its_flush_framed(
+        monkeypatch):
+    """An armed fused_dispatch span carries trie_nodes, the nodes its
+    state flush wrote to the store, and trie_nodes_framed, those of them
+    that went out in batch frames: equal, and equal to what the stores
+    were handed through setBatch in that window; unarmed, none."""
+    from plenum_tpu.observability.tracing import Tracer
+    pytest.importorskip("plenum_tpu.state.trie_native")
+    handed = []
+
+    def counted(self, batch, _fn=KeyValueStorageInMemory.setBatch):
+        batch = list(batch)
+        handed.append(len(batch))
+        return _fn(self, batch)
+    monkeypatch.setattr(KeyValueStorageInMemory, "setBatch", counted)
+    for armed in (True, False):
+        dm, wm, executor, store, _rejects = build_stack(lanes=True)
+        executor.tracer = Tracer(name="X", capacity=4096, armed=armed)
+        digests = []
+        for i in range(40):
+            req = nym_req(i, "framed%d" % i, verkey="~f%d" % i)
+            store[req.digest] = req
+            digests.append(req.digest)
+        handed.clear()
+        executor.apply_batch(digests, DOMAIN_LEDGER_ID, TS + 60)
+        args = [a for _k, name, _c, _t0, _t1, _key, a
+                in executor.tracer.spans() if name == "fused_dispatch"]
+        if armed:
+            assert len(args) == 1
+            assert args[0]["trie_nodes"] == \
+                args[0]["trie_nodes_framed"] == sum(handed) > 40
+        else:
+            assert args == [] and sum(handed) > 40
+
+
 def test_lane_min_gates_planning():
     dm, wm, executor, store, _r = build_stack(lanes=True, lane_min=50)
     from plenum_tpu.observability.telemetry import TM, TelemetryHub

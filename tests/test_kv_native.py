@@ -227,3 +227,114 @@ def test_node_restart_e2e_on_native_store(mock_timer, tmp_path):
     assert delta2.domain_ledger.size == 4       # caught up the suffix
     assert delta2.domain_ledger.root_hash == \
         nodes[0].domain_ledger.root_hash
+
+
+def _mixed_history(kv, model):
+    """Single puts, batch frames, removes and ordered batches, with an
+    ordered read between them, so that live keys sit both in the sorted
+    view and among the keys written since; `model` follows along."""
+    def put(k, v):
+        kv.put(k, v)
+        model[k] = v
+
+    def frame(pairs):
+        kv.setBatch(pairs)
+        model.update(pairs)
+
+    for i in range(0, 40, 3):
+        put(b"p-%03d" % i, b"single-%d" % i)
+    frame([(b"f-%03d" % i, b"frame-%d" % i) for i in range(30)])
+    assert [k for k, _ in kv.iterator()] == sorted(model)   # merge here
+    frame([(b"f-%03d" % i, b"again-%d" % i) for i in range(20, 50)])
+    frame([(b"p-003", b"from-a-frame")])
+    for k in (b"f-000", b"p-006", b"f-045", b"absent"):
+        kv.remove(k)
+        model.pop(k, None)
+    put(b"f-000", b"back")                  # removed, then put again
+    kv.do_ops_in_batch([("remove", b"f-001"), ("put", b"f-001", b"new"),
+                        ("put", b"f-002", b"gone"), ("remove", b"f-002"),
+                        ("put", b"q-000", b"batch-put")])
+    model[b"f-001"] = b"new"
+    model.pop(b"f-002")
+    model[b"q-000"] = b"batch-put"
+
+
+@pytest.mark.parametrize("check", [
+    "iteration", "get_or_none", "remove_then_put", "snapshot", "reopen"])
+def test_key_index_after_frames_puts_and_removes(tdir, check):
+    """The key cache after batch frames mixed with single puts and
+    removes reads as the store's contents in every way a caller can
+    look: ordered iteration and ranges, get_or_none, an ordered batch's
+    last op per key, a materialised iterator, and a reopened store."""
+    kv = KeyValueStorageNative(tdir, "k-" + check)
+    model = {}
+    _mixed_history(kv, model)
+    live = sorted(model)
+    if check == "iteration":
+        assert list(kv.iterator()) == [(k, model[k]) for k in live]
+        assert list(kv.iterator(start=b"f-020", end=b"p-999",
+                                include_value=False)) == \
+            [k for k in live if b"f-020" <= k <= b"p-999"]
+        assert len(kv) == len(model)
+    elif check == "get_or_none":
+        for k in live:
+            assert kv.get_or_none(k) == model[k]
+        for k in (b"f-002", b"f-045", b"p-006", b"absent", b"f-999"):
+            assert kv.get_or_none(k) is None
+    elif check == "remove_then_put":
+        assert kv.get(b"f-001") == b"new"
+        assert kv.get(b"f-000") == b"back"
+        with pytest.raises(KeyError):
+            kv.get(b"f-002")
+        kv.do_ops_in_batch([("remove", b"q-000"), ("put", b"q-000", b"x")])
+        kv.setBatch([(b"q-001", b"y")])
+        kv.do_ops_in_batch([("put", b"q-001", b"z"), ("remove", b"q-001")])
+        assert list(kv.iterator(start=b"q", include_value=False)) == \
+            [b"q-000"]
+    elif check == "snapshot":
+        it = kv.iterator()
+        kv.setBatch([(b"a-new", b"1")])
+        kv.remove(live[0])
+        kv.put(b"z-new", b"2")
+        assert list(it) == [(k, model[k]) for k in live]
+    else:
+        kv.close()
+        kv2 = KeyValueStorageNative(tdir, "k-" + check)
+        assert list(kv2.iterator()) == [(k, model[k]) for k in live]
+        assert kv2.get_or_none(b"f-045") is None
+        assert kv2.get_or_none(b"q-000") == b"batch-put"
+        kv = kv2
+    kv.close()
+
+
+def test_frame_is_one_ffi_call_and_put_one_record(tdir):
+    """A batch reaches the engine in one call that writes one frame; a
+    single put stays one record written and flushed before it returns
+    (another handle on the file reads it at once)."""
+    kv = KeyValueStorageNative(tdir, "calls")
+    calls = []
+
+    class Counting:
+        def __init__(self, lib):
+            self._lib = lib
+
+        def __getattr__(self, name):
+            fn = getattr(self._lib, name)
+            if not name.startswith(("kv_put", "kv_apply_packed")):
+                return fn
+
+            def counted(*args):
+                calls.append(name)
+                return fn(*args)
+            return counted
+
+    kv._lib = Counting(kv._lib)
+    kv.setBatch([(b"n-%04d" % i, b"v" * 90) for i in range(1000)])
+    assert calls == ["kv_apply_packed"]
+    kv.put(b"one", b"record")
+    assert calls == ["kv_apply_packed", "kv_put"]
+    reader = KeyValueStorageFile(tdir, "calls", read_only=True)
+    assert reader.get(b"one") == b"record"
+    assert reader.size == 1001
+    reader.close()
+    kv.close()

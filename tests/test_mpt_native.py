@@ -200,3 +200,165 @@ def test_pruning_state_uses_native_backend():
     assert st.get(b"did:x", isCommitted=True) is None
     st.commit()
     assert st.get(b"did:x", isCommitted=True) == b"{}"
+
+
+# ------------------------------------------ write-through as batch frames
+
+def _nym_pairs(lo, hi):
+    """NYM-shaped writes: 32-byte keys, values of about 190 bytes."""
+    return [(hashlib.sha256(b"did-%d" % i).digest(),
+             b'{"identifier":"%064d","verkey":"~%040d","seqNo":%d}'
+             % (i, i, i) + b"x" * 70) for i in range(lo, hi)]
+
+
+def _log_records(path):
+    """The kvlog file, record by record: [('frame', n_records) |
+    ('record', key)] in file order (tests/test_kv_native.py's format)."""
+    import struct
+    data = open(path, "rb").read()
+    out, pos = [], 0
+    while pos + 8 <= len(data):
+        klen, vlen = struct.unpack_from("<II", data, pos)
+        if klen == 0xFFFFFFFE:
+            body, n, p = data[pos + 8:pos + 8 + vlen], 0, 0
+            while p < len(body):
+                kl, vl = struct.unpack_from("<II", body, p)
+                p += 8 + kl + (0 if vl == 0xFFFFFFFF else vl)
+                n += 1
+            out.append(("frame", n))
+            pos += 8 + vlen
+        else:
+            out.append(("record", data[pos + 8:pos + 8 + klen]))
+            pos += 8 + klen + (0 if vlen == 0xFFFFFFFF else vlen)
+    return out
+
+
+@pytest.fixture
+def native_kv():
+    from plenum_tpu.storage import kv_native
+    if not kv_native.available():
+        pytest.skip("no C compiler for the native kvlog engine")
+    return kv_native.KeyValueStorageNative
+
+
+def test_set_many_reaches_the_store_as_one_frame(tdir, native_kv):
+    """1,000 keys through set_many: every new node in ONE batch frame
+    (the file's own framing says so), counted as written and framed;
+    a fresh trie over the reopened store reads the same root and every
+    value."""
+    import os
+    kv = native_kv(tdir, "state")
+    trie = NativeTrie(kv)
+    pairs = _nym_pairs(0, 1000)
+    nodes, framed = trie.set_many(pairs)
+    assert nodes == framed == len(kv) > 1000
+    assert _log_records(os.path.join(tdir, "state.kvlog")) == \
+        [("frame", nodes)]
+    trie.set(b"one-more", b"value")          # a per-key set: one frame
+    assert _log_records(os.path.join(tdir, "state.kvlog"))[1][0] == "frame"
+    root = trie.root_hash
+    kv.close()
+    kv2 = native_kv(tdir, "state")
+    fresh = NativeTrie(kv2, root)
+    assert fresh.root_hash == root
+    for k, v in pairs + [(b"one-more", b"value")]:
+        assert fresh.get(k) == v
+    kv2.close()
+
+
+def test_flush_torn_inside_its_frame_is_lost_whole(tdir, native_kv):
+    """A crash inside a flush's frame: on reopen the whole flush is
+    gone (not one of its nodes is left) and the committed root, put
+    after the earlier flush, still reads every key."""
+    import os
+    from plenum_tpu.state.pruning_state import PruningState
+    path = os.path.join(tdir, "torn.kvlog")
+    kv = native_kv(tdir, "torn")
+    st = PruningState(kv)
+    committed = _nym_pairs(0, 300)
+    for k, v in committed:
+        st.set(k, v)
+    st.commit()
+    root, size, count = st.committedHeadHash, os.path.getsize(path), len(kv)
+    for k, v in _nym_pairs(300, 1300):
+        st.set(k, v)
+    head = st.headHash                        # the flush: one frame
+    assert _log_records(path)[-1] == ("frame", len(kv) - count)
+    kv.close()
+    with open(path, "r+b") as f:              # torn half-way through it
+        f.truncate(size + (os.path.getsize(path) - size) // 2)
+    kv2 = native_kv(tdir, "torn")
+    assert len(kv2) == count and os.path.getsize(path) == size
+    assert kv2.get_or_none(head) is None
+    st2 = PruningState(kv2)
+    assert st2.committedHeadHash == root
+    for k, v in committed:
+        assert st2.get(k) == v
+    kv2.close()
+
+
+def _store(kind, tdir):
+    from plenum_tpu.storage.kv_file import KeyValueStorageFile
+    if kind == "memory":
+        return KeyValueStorageInMemory()
+    if kind == "file":
+        return KeyValueStorageFile(tdir, "nodes")
+    from plenum_tpu.storage import kv_native
+    if not kv_native.available():
+        pytest.skip("no C compiler for the native kvlog engine")
+    return kv_native.KeyValueStorageNative(tdir, "nodes")
+
+
+@pytest.mark.parametrize("kind", ["memory", "file", "native"])
+def test_framed_write_through_equals_per_record(tdir, kind):
+    """Per-key sets, deletes and set_many over each store: the root and
+    every node the store holds are byte-equal to the per-record path
+    (the C trie's drain put node by node)."""
+    from plenum_tpu.native import load_ext
+    mpt = load_ext("mpt_c")
+    ref_kv = KeyValueStorageInMemory()
+    ref_h, ref_root = mpt.new(None), mpt.blank_root()
+    kv = _store(kind, tdir)
+    trie = NativeTrie(kv)
+
+    def per_record():
+        for h, blob in mpt.drain(ref_h):
+            ref_kv.put(h, blob)
+
+    for k, v in _nym_pairs(0, 40):
+        trie.set(k, v)
+        ref_root = mpt.set(ref_h, ref_root, k, v)
+        per_record()
+    batch = _nym_pairs(40, 700) + [(k, b"") for k, _ in _nym_pairs(0, 10)]
+    trie.set_many(batch)
+    ref_root = mpt.set_many(ref_h, ref_root, batch)
+    per_record()
+    for k, _ in _nym_pairs(10, 15):
+        trie.delete(k)
+        ref_root = mpt.delete(ref_h, ref_root, k)
+        per_record()
+    assert trie.root_hash == ref_root
+    assert dict(kv.iterator()) == dict(ref_kv.iterator())
+    kv.close()
+
+
+def test_drain_past_the_frame_cap_goes_out_in_consecutive_frames(
+        tdir, native_kv, monkeypatch):
+    """A drain larger than the cap is several frames, each within it,
+    together every node; the reopened store reads every value."""
+    import os
+    monkeypatch.setattr(trie_native, "_FRAME_BYTES", 16 << 10)
+    kv = native_kv(tdir, "capped")
+    trie = NativeTrie(kv)
+    pairs = _nym_pairs(0, 500)
+    nodes, framed = trie.set_many(pairs)
+    assert nodes == framed == len(kv)
+    frames = _log_records(os.path.join(tdir, "capped.kvlog"))
+    assert len(frames) > 3 and {f[0] for f in frames} == {"frame"}
+    assert sum(n for _, n in frames) == nodes
+    assert os.path.getsize(os.path.join(tdir, "capped.kvlog")) <= \
+        len(frames) * (8 + (16 << 10))
+    root = trie.root_hash
+    kv.close()
+    fresh = NativeTrie(native_kv(tdir, "capped"), root)
+    assert all(fresh.get(k) == v for k, v in pairs)
