@@ -364,7 +364,10 @@ class Config:
     # cost is bench-gated to low single-digit percent on the ordering
     # hot path (bench.py tracing_overhead).
     TRACING_ENABLED = False
-    TRACING_BUFFER_SPANS = 1 << 16   # ring slots per node; newest kept
+    # ring slots per node; newest kept. A served node records a span
+    # per received frame (wire_rx) besides its ticks: a 30 s window of
+    # 2,000-write bursts on seven nodes is tens of thousands of records
+    TRACING_BUFFER_SPANS = 1 << 18
 
     # ---- journey plane (observability/journey.py): wire-carried trace
     # context. When on, flat envelopes ride as version 2 with an

@@ -50,6 +50,7 @@ from plenum_tpu.common.serializers.serializers import MsgPackSerializer
 from plenum_tpu.network.crypto_channel import (
     HandshakeError, Initiator, Responder, Session)
 from plenum_tpu.network.keys import NodeKeys
+from plenum_tpu.observability.tracing import CAT_WIRE, NullTracer
 
 logger = logging.getLogger(__name__)
 
@@ -129,7 +130,10 @@ class Connection:
         self.writer.write(len(data).to_bytes(4, "big") + data)
         self.bytes_out += len(data) + 4
 
-    async def read_frame(self, limit: int) -> Optional[bytes]:
+    async def read_sealed(self, limit: int) -> Optional[bytes]:
+        """The next frame as it came off the socket, still sealed; None
+        once the stream has ended. All of a read's waiting is here:
+        `session.decrypt` of what it returns is the frame's first work."""
         try:
             hdr = await self.reader.readexactly(4)
             n = int.from_bytes(hdr, "big")
@@ -140,7 +144,11 @@ class Connection:
             return None
         self.bytes_in += n + 4
         self.last_seen = time.monotonic()
-        return self.session.decrypt(data)
+        return data
+
+    async def read_frame(self, limit: int) -> Optional[bytes]:
+        data = await self.read_sealed(limit)
+        return None if data is None else self.session.decrypt(data)
 
     def close(self):
         self.alive = False
@@ -209,6 +217,7 @@ class StackBase:
         self.msg_len_limit = self.config.MSG_LEN_LIMIT
         from plenum_tpu.utils.metrics import NullMetricsCollector
         self.metrics = NullMetricsCollector()  # host node injects
+        self.tracer = NullTracer()             # host node injects
         # interception seam for fault-injection tooling
         # (testing/adversary): on_send(msg, dst) / on_incoming(msg, frm)
         # may rewrite, duplicate, or drop wire traffic; None =
@@ -251,6 +260,28 @@ class StackBase:
         raise NotImplementedError
 
     # --------------------------------------------------------- rx path
+
+    def _take_frame(self, payload: bytes, frm: str, conn: Connection) -> int:
+        """Take one opened frame in: → batch signatures checked."""
+        raise NotImplementedError
+
+    def _take_traced(self, sealed: bytes, frm: str, conn: Connection):
+        """Open and take in one frame inside a `wire_rx` span (the read
+        loop's armed branch): decrypt, decode, the batch signature, the
+        inner decodes and the appends to `rx` — never the wait for the
+        bytes, which `read_sealed` has done. Args: `messages` appended
+        to `rx`, `sigs` batch signatures checked, `bytes` of the sealed
+        frame, `cpu_us` the thread's CPU time across the span (its two
+        reads outside the wall clock's, as on `prod_tick`)."""
+        tracer = self.tracer
+        queued, c0 = len(self.rx), tracer.cpu_now()
+        t0 = tracer.now()
+        sigs = self._take_frame(conn.session.decrypt(sealed), frm, conn)
+        t1 = tracer.now()
+        tracer.complete("wire_rx", CAT_WIRE, t0, t1,
+                        messages=len(self.rx) - queued, sigs=sigs,
+                        bytes=len(sealed),
+                        cpu_us=round((tracer.cpu_now() - c0) * 1e6, 1))
 
     def _enqueue_wire(self, payload: bytes, frm: str):
         """Decode one wire frame (possibly a BATCH) into rx entries."""
@@ -394,52 +425,58 @@ class NodeStack(StackBase):
 
     async def _read_loop(self, conn: Connection, frm: str):
         while conn.alive:
-            payload = await conn.read_frame(self.msg_len_limit)
-            if payload is None:
+            sealed = await conn.read_sealed(self.msg_len_limit)
+            if sealed is None:
                 conn.close()
                 break
-            self._dispatch_frame(payload, frm, conn)
+            if self.tracer.enabled:
+                self._take_traced(sealed, frm, conn)
+            else:
+                self._take_frame(conn.session.decrypt(sealed), frm, conn)
         if self._incoming.get(frm) is conn:
             del self._incoming[frm]
 
-    def _dispatch_frame(self, payload: bytes, frm: str, conn: Connection):
+    def _take_frame(self, payload: bytes, frm: str, conn: Connection) -> int:
         if payload == b"pi":
             # liveness probe: answer on the same (incoming) stream
             try:
                 conn.send_frame(b"po")
             except Exception:
                 conn.close()
-            return
+            return 0
         if payload == b"po":
             remote = self.remotes.get(frm)
             if remote is not None:
                 remote.ping_sent_at = 0.0
-            return
-        self._unpack_wire(payload, frm)
+            return 0
+        return self._unpack_wire(payload, frm)
 
-    def _unpack_wire(self, payload: bytes, frm: str):
+    def _unpack_wire(self, payload: bytes, frm: str) -> int:
         self.metrics.add_event(_MN.TRANSPORT_BYTES_RECV, len(payload))
         with self.metrics.measure_time(_MN.WIRE_DECODE_TIME):
             return self._unpack_wire_inner(payload, frm)
 
-    def _unpack_wire_inner(self, payload: bytes, frm: str):
+    def _unpack_wire_inner(self, payload: bytes, frm: str) -> int:
+        """Decode one node frame into `rx` → batch signatures checked
+        (1 for a BATCH envelope, whatever the verdict; else 0)."""
         try:
             msg = serializer.deserialize(payload)
         except Exception:
             logger.warning("%s: undecodable frame from %s", self.name, frm)
-            return
+            return 0
         if not isinstance(msg, dict):
-            return
+            return 0
         if msg.get(OP_FIELD_NAME) == BATCH_OP:
             if not self._verify_batch_sig(msg, frm):
                 logger.warning("%s: bad batch signature from %s",
                                self.name, frm)
-                return
+                return 1
             for raw in msg.get("messages", []):
                 self._enqueue_wire(raw if isinstance(raw, bytes)
                                    else bytes(raw), frm)
-            return
+            return 1
         self.rx.append((msg, frm))
+        return 0
 
     def _verify_batch_sig(self, batch: dict, frm: str) -> bool:
         remote = self.remotes.get(frm)
@@ -528,11 +565,15 @@ class NodeStack(StackBase):
         """The dialed link mostly carries our sends; inbound on it is
         control traffic (pongs) or a peer answering on our link."""
         while conn.alive:
-            payload = await conn.read_frame(self.msg_len_limit)
-            if payload is None:
+            sealed = await conn.read_sealed(self.msg_len_limit)
+            if sealed is None:
                 conn.close()
                 break
-            self._dispatch_frame(payload, remote.name, conn)
+            if self.tracer.enabled:
+                self._take_traced(sealed, remote.name, conn)
+            else:
+                self._take_frame(conn.session.decrypt(sealed), remote.name,
+                                 conn)
         if remote.conn is conn:
             remote.conn = None
             self._emit_connecteds()
@@ -671,12 +712,19 @@ class ClientStack(StackBase):
         self._order.append(client_id)
         self._enforce_connection_limit()
         while conn.alive:
-            payload = await conn.read_frame(self.msg_len_limit)
-            if payload is None:
+            sealed = await conn.read_sealed(self.msg_len_limit)
+            if sealed is None:
                 conn.close()
                 break
-            self._enqueue_wire(payload, client_id)
+            if self.tracer.enabled:
+                self._take_traced(sealed, client_id, conn)
+            else:
+                self._enqueue_wire(conn.session.decrypt(sealed), client_id)
         self._clients.pop(client_id, None)
+
+    def _take_frame(self, payload: bytes, frm: str, conn: Connection) -> int:
+        self._enqueue_wire(payload, frm)
+        return 0
 
     def _enforce_connection_limit(self):
         limit = self.config.MAX_CONNECTED_CLIENTS_NUM

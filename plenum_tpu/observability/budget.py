@@ -21,8 +21,11 @@ stage:
                   to their own stage exactly once)
 * ``reply``     — reply construction + audit paths
 * ``transport`` — the prod tick's socket seams on a served node
-                  (server/networked_node.py): AEAD decrypt + parse of
-                  peer and client frames, encrypt + write of outboxes
+                  (server/networked_node.py): the drain of the peer and
+                  client messages the read loops decoded, encrypt +
+                  write of outboxes. The frames' AEAD open and decode
+                  are ``wire_rx`` spans (``wire`` category), recorded
+                  between ticks and in no stage here
 * ``untraced``  — what is left of a productive prod tick
                   (``prod_tick``) once every span inside it is taken
                   off: host work no stage span covers yet

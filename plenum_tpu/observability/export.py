@@ -238,13 +238,17 @@ def summarize(doc: dict) -> dict:
         names[e["name"]] = names.get(e["name"], 0) + 1
         if ph == "C":
             # counter tracks: keep the value envelope per series so the
-            # file-mode summary reports them instead of dropping them
-            for v in (e.get("args") or {}).values():
+            # file-mode summary reports them instead of dropping them; a
+            # record of several values (`cpu`) is one series each
+            args = e.get("args") or {}
+            for arg, v in args.items():
                 if not isinstance(v, (int, float)):
                     continue
-                cur = counters.get(e["name"])
+                series = e["name"] if len(args) == 1 \
+                    else "%s.%s" % (e["name"], arg)
+                cur = counters.get(series)
                 if cur is None:
-                    counters[e["name"]] = {
+                    counters[series] = {
                         "points": 1, "min": v, "max": v, "last": v}
                 else:
                     cur["points"] += 1

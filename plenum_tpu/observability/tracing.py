@@ -29,6 +29,13 @@ Design constraints (why this is not just `logging` with timestamps):
   production uses `perf_counter`, which is shared by every tracer in a
   process — so a sim pool's per-node buffers merge into one coherent
   pool-wide timeline with no clock alignment step.
+* CPU beside wall time. `cpu_now()` reads the calling thread's CPU
+  clock (`thread_time`, injectable like the wall clock), so a span that
+  samples it at both ends can carry `cpu_us` — the thread's CPU time
+  across the span; `sample_cpu()` records a `cpu` counter of
+  the thread's and the whole process's CPU seconds at most once per
+  CPU_SAMPLE_S, which splits the process's CPU into the loop's thread
+  and every other thread.
 * Dual clocks for cross-process alignment. `clock_pair()` samples the
   perf-counter AND an injectable wall clock in one call; the exporter
   records the pair as a `clock_sync` event at flush so FILE-mode
@@ -63,6 +70,10 @@ CAT_BLS = "bls"              # BLS share aggregation
 CAT_REPLY = "reply"          # reply construction + audit paths
 CAT_RECOVERY = "recovery"    # view change / catchup / breaker lifecycle
 CAT_TRANSPORT = "transport"  # socket rx/tx seams of the prod tick
+CAT_WIRE = "wire"            # frame receive path of the read loops
+
+# the shortest interval between two `cpu` counter samples (seconds)
+CPU_SAMPLE_S = 0.1
 
 Record = Tuple[str, str, str, float, Optional[float], Optional[str],
                Optional[dict]]
@@ -132,13 +143,19 @@ class NullTracer:
     def instant(self, name, cat="", key=None, **args) -> None:
         pass
 
-    def counter(self, name, value, cat="") -> None:
+    def counter(self, name, value=None, cat="", **values) -> None:
+        pass
+
+    def sample_cpu(self, every: float = CPU_SAMPLE_S) -> None:
         pass
 
     def complete(self, name, cat, t0, t1, key=None, **args) -> None:
         pass
 
     def now(self) -> float:
+        return 0.0
+
+    def cpu_now(self) -> float:
         return 0.0
 
     def clock_pair(self) -> Tuple[float, float]:
@@ -164,11 +181,13 @@ class Tracer:
     guard extra work test it)."""
 
     __slots__ = ("name", "armed", "enabled", "_capacity", "_buf", "_idx",
-                 "_written", "_clock", "_wall_clock", "_lock")
+                 "_written", "_clock", "_wall_clock", "_thread_clock",
+                 "_process_clock", "_cpu_sampled_at", "_lock")
 
     def __init__(self, name: str = "", capacity: int = 1 << 16,
                  clock=time.perf_counter, wall_clock=time.time,
-                 armed: bool = True):
+                 armed: bool = True, thread_clock=time.thread_time,
+                 process_clock=time.process_time):
         self.name = name
         self._capacity = max(1, int(capacity))
         self._buf: List[Optional[Record]] = []   # allocated by arm()
@@ -176,6 +195,9 @@ class Tracer:
         self._written = 0       # total records ever (>= buffered)
         self._clock = clock
         self._wall_clock = wall_clock
+        self._thread_clock = thread_clock
+        self._process_clock = process_clock
+        self._cpu_sampled_at = float("-inf")
         self._lock = threading.Lock()
         self.armed = self.enabled = False
         if armed:
@@ -215,13 +237,32 @@ class Tracer:
         t = self._clock()
         self._record(("i", name, cat, t, t, key, args or None))
 
-    def counter(self, name: str, value, cat: str = "") -> None:
+    def counter(self, name: str, value=None, cat: str = "",
+                **values) -> None:
         """Counter sample (queue depth, batch size) — rendered by
-        Perfetto as a stacked counter track."""
+        Perfetto as a stacked counter track. With keyword `values`
+        instead of one `value`, one record carries them all: one track
+        of several series."""
         if not self.armed:
             return
         self._record(("C", name, cat, self._clock(), None, None,
-                      {name: value}))
+                      values or {name: value}))
+
+    def sample_cpu(self, every: float = CPU_SAMPLE_S) -> None:
+        """A `cpu` counter sample — `thread_s`, the calling thread's CPU
+        seconds, and `process_s`, every thread's — unless the last one
+        is younger than `every` seconds on this tracer's clock. A loop
+        calls it every turn; the samples split the process's CPU into
+        its own thread and the rest. `every=0` takes one regardless:
+        the last, as a dump closes the record."""
+        if not self.armed:
+            return
+        t = self._clock()
+        if t - self._cpu_sampled_at < every:
+            return
+        self._cpu_sampled_at = t
+        self.counter("cpu", thread_s=self._thread_clock(),
+                     process_s=self._process_clock())
 
     def complete(self, name: str, cat: str, t0: float, t1: float,
                  key: Optional[str] = None, **args) -> None:
@@ -236,6 +277,14 @@ class Tracer:
     def now(self) -> float:
         """This tracer's clock, for `complete()`."""
         return self._clock()
+
+    def cpu_now(self) -> float:
+        """The calling thread's CPU seconds (`time.thread_time`), for a
+        span's `cpu_us`: read before the span's first wall-clock read
+        and after its last (a read is a system call, which should not
+        lengthen the span), the difference is the thread's CPU time
+        across it."""
+        return self._thread_clock()
 
     def clock_pair(self) -> Tuple[float, float]:
         """(perf_counter, wall) sampled back to back — the anchor pair

@@ -30,7 +30,7 @@ logger = logging.getLogger(__name__)
 
 
 def _no_clock() -> float:
-    """The prod tick's clock while its tracer is disarmed: no read."""
+    """The prod tick's clocks while its tracer is disarmed: no read."""
     return 0.0
 
 
@@ -200,14 +200,23 @@ class NetworkedNode(Prodable):
         """One tick (reference node.py:1037): rx quotas → consensus →
         timer → lifecycle → flush."""
         # flight recorder: the tick's envelope (prod_tick: its exclusive
-        # time is the work no stage span covers) and its three socket
-        # seams are recorded at the end, and only if the tick did
+        # time is the work no stage span covers; `cpu_us`, the thread's
+        # CPU time across it, read OUTSIDE the wall clock's two reads so
+        # that the CPU clock adds nothing to the duration) and its three
+        # socket seams are recorded at the end, and only if the tick did
         # something — a node polls a hundred times a second when idle,
-        # and those ticks would fill the ring with empty spans
+        # and those ticks would fill the ring with empty spans. Every
+        # turn, idle or not, may take the `cpu` counter sample (at most
+        # ten a second)
         tracer = self.node.tracer
         traced = tracer.enabled
-        now = tracer.now if traced else _no_clock
+        if traced:
+            tracer.sample_cpu()
+            now, cpu = tracer.now, tracer.cpu_now
+        else:
+            now = cpu = _no_clock
         pending_at_start = self._pending_auth
+        cpu_tick = cpu()
         t_tick = now()
         # harvest a landed verification batch before taking new work
         if self._pending_auth is not None and \
@@ -221,6 +230,10 @@ class NetworkedNode(Prodable):
         if self.nodestack.metrics is not metrics:
             self.nodestack.metrics = metrics
             self.clientstack.metrics = metrics
+        if self.nodestack.tracer is not tracer:
+            # the read loops' wire_rx spans go to the node's recorder
+            self.nodestack.tracer = tracer
+            self.clientstack.tracer = tracer
         t_rx = now()
         with metrics.measure_time(MetricsName.NODE_RX_TIME):
             c = from_nodes = self.nodestack.service(
@@ -247,8 +260,9 @@ class NetworkedNode(Prodable):
         if traced and (c or flushed or to_clients
                        or self._pending_auth is not pending_at_start):
             t_end = now()
+            cpu_us = round((cpu() - cpu_tick) * 1e6, 1)
             tracer.complete("prod_tick", CAT_TRANSPORT, t_tick, t_end,
-                            produced=c)
+                            produced=c, cpu_us=cpu_us)
             tracer.complete("node_rx", CAT_TRANSPORT, t_rx, t_client,
                             messages=from_nodes)
             tracer.complete("client_rx", CAT_TRANSPORT, t_client,

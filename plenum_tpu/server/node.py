@@ -1992,6 +1992,10 @@ class Node:
                 or not os.path.isdir(directory):
             return None
         from plenum_tpu.observability.export import export_chrome_trace
+        # a last `cpu` sample (from the loop's thread, which stops here):
+        # the samples then reach past whatever a reader's window ends at,
+        # however soon after it the node was stopped
+        self.tracer.sample_cpu(every=0)
         name = "".join(c for c in self.name if c.isalnum() or c in "_-")
         path = os.path.join(directory, "node_%s_spans.json" % name)
         t0 = time.perf_counter()

@@ -63,7 +63,9 @@ class MetricsName(IntEnum):
     TRANSPORT_BYTES_RECV = 52
     TRANSPORT_MSGS_RECV = 53
     WIRE_ENCODE_TIME = 54         # serialize+seal per outbox flush
-    WIRE_DECODE_TIME = 55         # open+decode per service() call
+    WIRE_DECODE_TIME = 55         # decode + batch signature check per
+                                  # node frame, in the read loop (after
+                                  # the AEAD open, which it leaves out)
     # ---- garbage collector (reference gc_trackers.py GcTimeTracker):
     # the three *_TIME names MUST stay consecutive — the tracker
     # indexes them as GC_GEN0_TIME + generation

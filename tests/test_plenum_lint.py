@@ -402,7 +402,7 @@ def test_pt005_config_values_constant_folding():
     values = load_config_values(
         os.path.join(REPO, "plenum_tpu", "common", "config.py"))
     assert "VERIFY_DAEMON_BUCKET" in values[4096]
-    assert "TRACING_BUFFER_SPANS" in values[1 << 16]   # 1 << 16 folded
+    assert "TRACING_BUFFER_SPANS" in values[1 << 18]   # 1 << 18 folded
     assert "MSG_LEN_LIMIT" in values[128 * 1024]       # 128 * 1024
 
 
